@@ -26,10 +26,11 @@ sys.path.insert(
 
 
 def main() -> None:
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
+    from pytorch_distributed_training_tutorials_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
 
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    enable_compile_cache()
 
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--mode", choices=["pp", "tp_sp"], default="pp")

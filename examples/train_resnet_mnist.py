@@ -24,10 +24,11 @@ sys.path.insert(
 
 
 def main() -> None:
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
+    from pytorch_distributed_training_tutorials_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
 
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    enable_compile_cache()
 
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--max_epochs", type=int, default=10)

@@ -78,9 +78,10 @@ def build_parser(launch_flags: bool = True) -> argparse.ArgumentParser:
                    help="Input batch size on each device (default: 32)")
     if launch_flags:
         p.add_argument("--nprocs", type=int, default=1,
-                       help="Processes to fork (1 = pure SPMD over local "
-                            "chips; >1 = multi-process world, the mp.spawn "
-                            "twin)")
+                       help="Processes to fork (1 = pure SPMD over all "
+                            "local chips; >1 = multi-process world, the "
+                            "mp.spawn twin — needs --platform cpu: a chip "
+                            "belongs to one process at a time)")
         p.add_argument("--platform", type=str, default=None,
                        help="Force a JAX platform in workers (e.g. 'cpu' for "
                             "the hardware-free multi-process harness)")

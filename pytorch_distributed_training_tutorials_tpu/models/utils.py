@@ -30,7 +30,7 @@ def model_flops_per_token(n_params_nonembed: int, d_model: int,
     ``compiled.cost_analysis()['flops']`` counts a ``lax.scan``/``while``
     body ONCE, not times its trip count, so it under-reports a
     ``scan_layers`` model by ~n_layers x (measured: 5.4 TF "executed" vs
-    52.8 TF analytic on the 24-layer 350m step — TRAIN_LLM_r05.md).
+    52.8 TF analytic on the 24-layer 350m step — round 5).
     Exclude ``tok_emb`` (a gather, not a matmul) from
     ``n_params_nonembed`` but keep ``lm_head`` (it IS a matmul — and
     stays one inside the fused blockwise loss).

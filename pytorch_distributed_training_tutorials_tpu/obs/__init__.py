@@ -7,7 +7,7 @@ CLAUDE.md prose. ``obs`` is that lore as library code, in four pillars:
 - :mod:`.metrics` — :class:`MetricsLogger`: typed step/epoch events, ring
   buffer + JSONL, process-0 gated, no per-step host sync;
 - :mod:`.trace` — :class:`StepReport`: trace-classified "where did the
-  step go" breakdowns (the PROFILE_r04 analysis as one call), fusion
+  step go" breakdowns (the round-4 profile analysis as one call), fusion
   classes HLO-verified so the ``convert_reduce_fusion`` misread cannot
   recur;
 - :mod:`.timing` — :class:`MinOfN` (stall flagging), :class:`DriftBracket`

@@ -42,7 +42,7 @@ class MetricsLogger:
         events gain ``tokens_per_sec`` and ``mfu`` derived from
         ``samples_per_sec`` (the analytic-FLOPs MFU convention —
         models.utils.model_flops_per_token, never cost_analysis on a
-        scanned model, TRAIN_LLM_r05.md).
+        scanned model, round 5).
     flight: optional :class:`..obs.flight.FlightRecorder`. Skip-step
         observations become ``step_skipped`` flight events AT DRAIN TIME
         — the skip flag already rides the batched fetch, so the recorder

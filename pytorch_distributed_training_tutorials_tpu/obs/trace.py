@@ -2,13 +2,13 @@
 
 :class:`StepReport` grows :func:`utils.profiling.device_op_durations` into a
 categorized breakdown — convolution / matmul / collectives split by kind /
-dynamic-update-slice / convert-copy / reduce / elementwise — the PROFILE_r04
+dynamic-update-slice / convert-copy / reduce / elementwise — the round-4 profile
 analysis as one library call instead of a hand-run script.
 
 The classifier exists because name-matching trace events is how round 2's
 "BatchNorm is ~60% of the step" misread happened: XLA fuses convolutions
 *with* the BN-stat reduces into fusions named ``convert_reduce_fusion``, so
-the fusion's display name is marketing, not truth (PROFILE_r04.md). Two
+the fusion's display name is marketing, not truth (a round-4 finding). Two
 defenses are built in:
 
 - pass the compiled module's HLO text (``compiled.as_text()``) and every
@@ -87,10 +87,15 @@ def base_name(op: str) -> str:
     return _SUFFIX.sub("", op)
 
 
-def is_wrapper(op: str) -> bool:
-    """True for events that *contain* leaf ops (counting them double-counts).
+_RENDEZVOUS_SPANS = (
+    "Rendezvous", "InvokeRendezvous", "Wait: ", "Wait for rendezvous",
+)
 
-    Three families, all observed in real traces:
+
+def is_wrapper(op: str) -> bool:
+    """True for events that overlap leaf ops (counting them double-counts).
+
+    Four families, all observed in real traces:
 
     - host-executor infra, C++-scoped names (``ThunkExecutor::Execute``,
       ``TfrtCpuExecutable::ExecuteHelper``, ``ThreadpoolListener::...``) —
@@ -98,9 +103,13 @@ def is_wrapper(op: str) -> bool:
     - XLA region wrappers: the module-level event (a bare ordinal like
       ``0``), ``jit_*`` program regions, ``while`` loop bodies, ``call``
       computation frames;
-    - profiler metadata lanes.
+    - profiler metadata lanes;
+    - the CPU runtime's collective rendezvous spans (``Rendezvous``,
+      ``InvokeRendezvous``, ``Wait: pending_threads=3/8``, ``Wait for
+      rendezvous callback``): they sit INSIDE the collective op's own
+      event, whose duration already covers them.
     """
-    if "::" in op:
+    if "::" in op or op.startswith(_RENDEZVOUS_SPANS):
         return True
     b = base_name(op)
     return (
@@ -192,7 +201,7 @@ def classify_hlo(hlo: str) -> dict[str, tuple[str, str]]:
     The ground-truth classifier: fusions are resolved through their
     ``calls=%computation`` body. Generalizes scripts/profile_step.py's
     ``parse_hlo`` with collectives split by kind and dynamic-update-slice
-    as its own class (the nn.scan layout lesson, TRAIN_LLM_r05.md).
+    as its own class (the nn.scan layout lesson, round 5).
     """
     comps: dict[str, str] = {}
     cur: str | None = None
@@ -270,8 +279,11 @@ class StepReport:
             if is_wrapper(op):
                 wrapper += us
                 continue
-            base = base_name(op)
-            known = hlo_info.get(op) or hlo_info.get(base)
+            # the CPU runtime also stamps "end: <op>" completion markers:
+            # they belong to <op>'s class
+            name = op.removeprefix("end: ")
+            base = base_name(name)
+            known = hlo_info.get(name) or hlo_info.get(base)
             if known is not None:
                 cat = known[0]
             else:
@@ -318,7 +330,7 @@ class StepReport:
         return self.by_category.get(category, 0.0) / self.total_us
 
     def render(self, top: int = 0) -> str:
-        """The "where did the step go" table, PROFILE_r04 style."""
+        """The "where did the step go" table."""
         lines = [
             f"device time: {self.total_us / 1e3:.2f} ms over "
             f"{self.steps} step(s) -> {self.step_us / 1e3:.3f} ms/step",

@@ -6,8 +6,7 @@ the same with ``optax.softmax_cross_entropy_with_integer_labels`` over the
 ``(B, S, V)`` lm_head output. At LM scale that tensor is the single largest
 activation of the train step (350m config, B=8, S=2048: 2 GiB of bf16
 logits plus the float32 softmax temps behind it) and every byte of it is
-memory-bound tail work — the matmuls feeding it are already near-roofline
-(TRAIN_LLM_r05.md). This module removes it with the same online-softmax
+memory-bound tail work. This module removes it with the same online-softmax
 decomposition :mod:`.flash_attention` uses for the (S, S) score matrix:
 
 - forward: one MXU pass per (row-block, vocab-block) tile of the lm_head
@@ -56,13 +55,20 @@ from pytorch_distributed_training_tutorials_tpu.utils.compat import (
 NEG_INF = float("-inf")  # plain float: no jax arrays at import time
 
 # Defaults sized for LM-head shapes (D ~ 1-4k, V ~ 32-256k): the VMEM
-# working set per tile is block_n*D (rows) + D*block_v (weights) +
-# block_n*block_v f32 (logits tile) + row scratch — ~6 MB at D=2048.
+# working set per call is the double-buffered block_n*D (rows) and
+# D*block_v (weights) blocks, the block_n*block_v f32 logits tile and the
+# row scratch — 8 MiB forward at D=2048 in bf16; the backward calls hold
+# more and shrink their blocks to fit (_fit_vmem).
 # block_n also sets the head-weight re-read factor (each row block streams
 # the whole W): HBM traffic for W is ceil(N / block_n) * |W|, so prefer
 # the largest block_n whose tiles still fit VMEM when tuning on-chip.
 DEFAULT_BLOCK_N = 512
 DEFAULT_BLOCK_V = 512
+
+# What the pipelined blocks plus the f32 accumulator of one call may hold.
+# Mosaic's scoped-VMEM limit on a v5e core is 16 MiB; the 4 MiB left over
+# is for the (block_n, block_v) f32 logits/dS tiles and the row vectors.
+_VMEM_BLOCK_BUDGET = 12 * 1024 * 1024
 
 
 def _clamp_block(b: int, dim: int, interpret: bool) -> int:
@@ -76,6 +82,30 @@ def _clamp_block(b: int, dim: int, interpret: bool) -> int:
     if not interpret:
         b = -(-b // 128) * 128
     return d8 if b >= d8 else b
+
+
+def _fit_vmem(bn: int, bv: int, d: int, itemsize: int, acc: str | None):
+    """Shrink (block_n, block_v) until one call's VMEM working set fits
+    :data:`_VMEM_BLOCK_BUDGET` (real-TPU path only — the interpreter has
+    no VMEM). Every call double-buffers a (bn, d) hidden block and a
+    (d, bv) head block; the backward calls add a double-buffered output
+    block and an f32 accumulator of the same shape on one side —
+    ``acc="n"`` for dh (rows), ``acc="v"`` for dW (vocab). At 512/512,
+    bf16 and d = 2048 that is 8 MiB forward but 16.8 MiB backward, so dh
+    runs 256-row blocks and dW 256-column blocks there; d <= 1024 keeps
+    the defaults. The accumulator side halves first (it weighs 3x), and a
+    block only halves while it stays a 128-multiple (the lane rule of
+    :func:`_clamp_block`)."""
+    per_n = d * (2 * itemsize + (2 * itemsize + 4 if acc == "n" else 0))
+    per_v = d * (2 * itemsize + (2 * itemsize + 4 if acc == "v" else 0))
+    while bn * per_n + bv * per_v > _VMEM_BLOCK_BUDGET:
+        if bn % 256 == 0 and (acc != "v" or bv % 256):
+            bn //= 2
+        elif bv % 256 == 0:
+            bv //= 2
+        else:
+            break
+    return bn, bv
 
 
 def _row8(vec, total):
@@ -219,12 +249,15 @@ def _dw_kernel(
         dw_ref[:] = acc_ref[:].astype(dw_ref.dtype)
 
 
-def _pad_inputs(h2, w, y, block_n, block_v, interpret):
-    """Shared padding/blocking for the forward and backward calls."""
-    n, _ = h2.shape
+def _pad_inputs(h2, w, y, block_n, block_v, interpret, acc=None):
+    """Shared padding/blocking for the forward and backward calls; ``acc``
+    names the side a backward call accumulates on (:func:`_fit_vmem`)."""
+    n, d = h2.shape
     v = w.shape[1]
     bn = _clamp_block(block_n, n, interpret)
     bv = _clamp_block(block_v, v, interpret)
+    if not interpret:
+        bn, bv = _fit_vmem(bn, bv, d, h2.dtype.itemsize, acc)
     pad_n = -n % bn
     pad_v = -v % bv
     hf = jnp.pad(h2, ((0, pad_n), (0, 0))) if pad_n else h2
@@ -267,16 +300,16 @@ def _fwd_impl(h2, w, y, block_n, block_v, interpret):
 
 
 def _bwd_impl(h2, w, y, lse, g, block_n, block_v, interpret):
-    """(dh, dW) via blockwise softmax recompute from the saved ``lse``."""
+    """(dh, dW) via blockwise softmax recompute from the saved ``lse``.
+    The two calls block independently: each shrinks the side it
+    accumulates on to fit VMEM (:func:`_fit_vmem`)."""
     n, d = h2.shape
     v = w.shape[1]
-    hf, wf, y8, bn, bv, np_, vp = _pad_inputs(
-        h2, w, y, block_n, block_v, interpret
-    )
-    n_n, n_v = np_ // bn, vp // bv
-    lse8 = _row8(lse, np_)
-    g8 = _row8(g.astype(jnp.float32), np_)
+    gf = g.astype(jnp.float32)
 
+    hf, wf, y8, bn, bv, np_, vp = _pad_inputs(
+        h2, w, y, block_n, block_v, interpret, acc="n"
+    )
     hspec = pl.BlockSpec(
         (bn, d), lambda i, j: (i, 0), memory_space=pltpu.VMEM
     )
@@ -288,17 +321,20 @@ def _bwd_impl(h2, w, y, lse, g, block_n, block_v, interpret):
     )
     dh = pl.pallas_call(
         functools.partial(
-            _dh_kernel, block_n=bn, block_v=bv, n_v=n_v, vocab=v
+            _dh_kernel, block_n=bn, block_v=bv, n_v=vp // bv, vocab=v
         ),
-        grid=(n_n, n_v),
+        grid=(np_ // bn, vp // bv),
         in_specs=[hspec, wspec, rowspec, rowspec, rowspec],
         out_specs=hspec,
         out_shape=jax.ShapeDtypeStruct((np_, d), hf.dtype),
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         interpret=interpret,
-    )(hf, wf, y8, lse8, g8)
+    )(hf, wf, y8, _row8(lse, np_), _row8(gf, np_))
 
     # transposed grid: outer over vocab blocks, inner accumulates rows
+    hf, wf, y8, bn, bv, np_, vp = _pad_inputs(
+        h2, w, y, block_n, block_v, interpret, acc="v"
+    )
     hspec_t = pl.BlockSpec(
         (bn, d), lambda vj, ri: (ri, 0), memory_space=pltpu.VMEM
     )
@@ -310,15 +346,15 @@ def _bwd_impl(h2, w, y, lse, g, block_n, block_v, interpret):
     )
     dw = pl.pallas_call(
         functools.partial(
-            _dw_kernel, block_n=bn, block_v=bv, n_n=n_n, vocab=v
+            _dw_kernel, block_n=bn, block_v=bv, n_n=np_ // bn, vocab=v
         ),
-        grid=(n_v, n_n),
+        grid=(vp // bv, np_ // bn),
         in_specs=[hspec_t, wspec_t, rowspec_t, rowspec_t, rowspec_t],
         out_specs=wspec_t,
         out_shape=jax.ShapeDtypeStruct((d, vp), wf.dtype),
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
         interpret=interpret,
-    )(hf, wf, y8, lse8, g8)
+    )(hf, wf, y8, _row8(lse, np_), _row8(gf, np_))
 
     return dh[:n], dw[:, :v]
 

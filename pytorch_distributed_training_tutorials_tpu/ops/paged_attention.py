@@ -11,14 +11,15 @@ regardless of how deep each slot actually is.
 This module is the vLLM PagedAttention design (SOSP '23 — the same paper
 ``serve/pages.py`` cites for the pool) fused the FlashAttention way
 (:mod:`.flash_attention` is the house online-softmax template): a Pallas
-kernel whose grid walks ``(batch row, kv head, logical page)`` with the
-page table and per-row ``cache_index`` as **scalar-prefetch** operands, so
-the K/V ``BlockSpec`` index_maps translate logical page -> physical pool
-page per grid step and the kernel only ever touches one ``(page_size, d)``
-tile at a time. Softmax runs as the streaming (m, l, acc) recurrence
-across pages; no dense window exists at any point — the compiled HLO for
-a kernel-path decode contains no ``(B, W, ...)`` gathered temporary
-(tests/test_serve.py pins the shape sweep, fused_loss-style).
+kernel whose grid walks ``(batch row, logical page)`` with the page table
+and per-row ``cache_index`` as **scalar-prefetch** operands, so the K/V
+``BlockSpec`` index_maps translate logical page -> physical pool page per
+grid step and the kernel only ever holds one page (every kv head's
+``(page_size, d)`` tile) at a time. Softmax runs as the streaming
+(m, l, acc) recurrence across pages; no dense window exists at any point
+— the compiled HLO for a kernel-path decode contains no ``(B, W, ...)``
+gathered temporary (tests/test_serve.py pins the shape sweep,
+fused_loss-style).
 
 Numerics contract: :func:`paged_attention` matches
 :func:`paged_attention_reference` — a pure-jnp restatement of the gather
@@ -89,9 +90,19 @@ def paged_attention(
     ``interpret=None`` auto-selects interpreter mode off-TPU, like every
     kernel in ops/. Returns (B, S, H, D) in ``q.dtype``.
 
-    Real-TPU tiling note: ``D`` (lane) wants a multiple of 128 and
-    ``page_size`` (sublane) a multiple of 8 for native Mosaic tiles —
-    the serving presets satisfy both; other geometries pad.
+    Real-TPU tiling note: the Mosaic lowering takes a block only when
+    each of its last two dims is the whole array dim or a multiple of
+    (8, 128), so no block may slice one head out of a ``(.., H, D)`` or
+    ``(.., KV, D)`` array. The kernel therefore sees q and the output as
+    ``(B, KV, S * grp, D)`` (one whole ``(S * grp, D)`` slab per kv head,
+    any grp and S) and each pool as ``(N_pages, page_size, KV * D)`` — a
+    free reshape whose page row is one lane-dense block; a grid step
+    fetches one whole page and serves every kv head from static lane
+    slices of it. That leaves ``page_size`` (sublane) a multiple of 8 and
+    the stored head width (``D``, or ``D // 2`` packed) a multiple of the
+    dtype's lane tile as the geometry the chip's compiler is known to
+    take; ``tests/test_chip_compile.py`` holds the serving presets'
+    shapes (D 128, page_size 64, KV 16 and 4, bf16/int8/int4).
     """
     if quant not in _QUANT_MODES:
         raise ValueError(f"quant must be one of {_QUANT_MODES}, got {quant!r}")
@@ -124,9 +135,10 @@ def paged_attention(
         if quant:
             ks_ref, vs_ref, o_ref, acc, m, l = rest
         else:
+            ks_ref = vs_ref = None
             o_ref, acc, m, l = rest
         bb = pl.program_id(0)
-        p = pl.program_id(2)
+        p = pl.program_id(1)
 
         @pl.when(p == 0)
         def _init():
@@ -141,102 +153,117 @@ def paged_attention(
         # (exp(-inf - shift) == 0.0), so skipping them is free AND exact
         live = jnp.logical_and(pid < n_pages, p * page_size <= depth + (s - 1))
 
+        def head_tile(ref, s_ref, hh):
+            """Head ``hh``'s (page_size, D) tile of the page, dequantized:
+            a static lane slice of the page's (page_size, KV * D) row."""
+            tile = ref[0, :, hh * d_store:(hh + 1) * d_store]
+            if not quant:
+                return tile
+            scale = s_ref[0, :, hh:hh + 1].astype(jnp.float32)
+            if quant == "int4":
+                # widen first: the v5e vector unit has no 8-bit shifts
+                # (Mosaic refuses arith.shrui on i8)
+                tile = unpack_int4(tile.astype(jnp.int32))
+            return (tile.astype(jnp.float32) * scale).astype(kv_dtype)
+
         @pl.when(live)
         def _page():
-            if quant == "int4":
-                kb = (
-                    unpack_int4(k_ref[0, :, 0, :]).astype(jnp.float32)
-                    * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-                ).astype(kv_dtype)
-                vb = (
-                    unpack_int4(v_ref[0, :, 0, :]).astype(jnp.float32)
-                    * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-                ).astype(kv_dtype)
-            elif quant == "int8":
-                kb = (
-                    k_ref[0, :, 0, :].astype(jnp.float32)
-                    * ks_ref[0, :, 0][:, None]
-                ).astype(kv_dtype)
-                vb = (
-                    v_ref[0, :, 0, :].astype(jnp.float32)
-                    * vs_ref[0, :, 0][:, None]
-                ).astype(kv_dtype)
-            else:
-                kb = k_ref[0, :, 0, :]
-                vb = v_ref[0, :, 0, :]
-            qb = q_ref[0].reshape(sg, d)
-            scores = jax.lax.dot_general(
-                qb.astype(score_dtype),
-                kb.astype(score_dtype),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * sm_scale
             # validity: global position t attends iff t <= pos + s_row
             # (row r of the (sg, page_size) tile is query s_row = r // grp)
             srow = jax.lax.broadcasted_iota(jnp.int32, (sg, page_size), 0)
             t = p * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, (sg, page_size), 1
             )
-            scores = jnp.where(t <= depth + srow // grp, scores, NEG_INF)
-            m_prev = m[:, :1]
-            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-            shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
-            pexp = jnp.exp(scores - shift)
-            corr = jnp.exp(m_prev - shift)
-            l[:, :1] = l[:, :1] * corr + pexp.sum(axis=-1, keepdims=True)
-            acc[:] = acc[:] * corr + jax.lax.dot(
-                pexp.astype(vb.dtype), vb, preferred_element_type=jnp.float32
-            )
-            m[:, :1] = m_new
+            valid = t <= depth + srow // grp
+            for hh in range(kv):  # static: one page fetch serves every head
+                kb = head_tile(k_ref, ks_ref, hh)
+                vb = head_tile(v_ref, vs_ref, hh)
+                scores = jax.lax.dot_general(
+                    q_ref[0, hh].astype(score_dtype),
+                    kb.astype(score_dtype),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * sm_scale
+                scores = jnp.where(valid, scores, NEG_INF)
+                m_prev = m[hh, :, :1]
+                m_new = jnp.maximum(
+                    m_prev, scores.max(axis=-1, keepdims=True)
+                )
+                shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
+                pexp = jnp.exp(scores - shift)
+                corr = jnp.exp(m_prev - shift)
+                l[hh, :, :1] = (
+                    l[hh, :, :1] * corr + pexp.sum(axis=-1, keepdims=True)
+                )
+                acc[hh] = acc[hh] * corr + jax.lax.dot(
+                    pexp.astype(vb.dtype), vb,
+                    preferred_element_type=jnp.float32,
+                )
+                m[hh, :, :1] = m_new
 
-        @pl.when(p == pl.num_programs(2) - 1)
+        @pl.when(p == pl.num_programs(1) - 1)
         def _flush():
-            lv = l[:, :1]
+            lv = l[:, :, :1]
             safe = jnp.where(lv == 0.0, 1.0, lv)  # all-parked row -> 0 out
-            o_ref[0] = (acc[:] / safe).reshape(s, grp, d).astype(o_ref.dtype)
+            o_ref[0] = (acc[:] / safe).astype(o_ref.dtype)
+
+    # Kernel-side layouts (see the tiling note): queries grouped per kv
+    # head, pools with (KV, D) flattened into one lane-dense page row.
+    qk = (
+        q.reshape(b, s, kv, grp, d)
+        .transpose(0, 2, 1, 3, 4)
+        .reshape(b, kv, sg, d)
+    )
+    row = kv * d_store
 
     # index_maps read the prefetched table: logical page p of row b lives
     # at pool page table[b, p] — sentinels clamp in-range for the FETCH
     # (the block must exist) and the kernel's `live` predicate masks them
-    def _pool_map(bb, hh, p, tbl, _pos):
-        return (jnp.minimum(tbl[bb, p], n_pages - 1), 0, hh, 0)
+    def _pool_map(bb, p, tbl, _pos):
+        return (jnp.minimum(tbl[bb, p], n_pages - 1), 0, 0)
 
-    def _pool_scale_map(bb, hh, p, tbl, _pos):
-        return (jnp.minimum(tbl[bb, p], n_pages - 1), 0, hh)
-
-    def _q_map(bb, hh, p, tbl, _pos):
-        return (bb, 0, hh, 0)
+    def _q_map(bb, p, tbl, _pos):
+        return (bb, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, s, grp, d), _q_map),
-        pl.BlockSpec((1, page_size, 1, d_store), _pool_map),
-        pl.BlockSpec((1, page_size, 1, d_store), _pool_map),
+        pl.BlockSpec((1, kv, sg, d), _q_map),
+        pl.BlockSpec((1, page_size, row), _pool_map),
+        pl.BlockSpec((1, page_size, row), _pool_map),
     ]
-    operands = [q, k_pool, v_pool]
+    operands = [
+        qk,
+        k_pool.reshape(n_pages, page_size, row),
+        v_pool.reshape(n_pages, page_size, row),
+    ]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, page_size, 1), _pool_scale_map),
-            pl.BlockSpec((1, page_size, 1), _pool_scale_map),
+            pl.BlockSpec((1, page_size, kv), _pool_map),
+            pl.BlockSpec((1, page_size, kv), _pool_map),
         ]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, p_cap),  # pages innermost: the online-softmax carry
+        grid=(b, p_cap),  # pages innermost: the online-softmax carry
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, s, grp, d), _q_map),
+        out_specs=pl.BlockSpec((1, kv, sg, d), _q_map),
         scratch_shapes=[
-            pltpu.VMEM((sg, d), jnp.float32),
-            pltpu.VMEM((sg, 128), jnp.float32),
-            pltpu.VMEM((sg, 128), jnp.float32),
+            pltpu.VMEM((kv, sg, d), jnp.float32),
+            pltpu.VMEM((kv, sg, 128), jnp.float32),
+            pltpu.VMEM((kv, sg, 128), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, sg, d), q.dtype),
         interpret=interpret,
     )(table, pos, *operands)
+    return (
+        out.reshape(b, kv, s, grp, d)
+        .transpose(0, 2, 1, 3, 4)
+        .reshape(b, s, h, d)
+    )
 
 
 def paged_attention_reference(

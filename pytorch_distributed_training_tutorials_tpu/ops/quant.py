@@ -95,9 +95,11 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     """Inverse of :func:`pack_int4`: uint8 ``(..., D/2)`` -> int8
     ``(..., D)``. Each nibble sign-extends through the two's-complement
     rule ``n >= 8 -> n - 16`` (branch-free ``jnp.where`` — values are
-    traced data)."""
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
+    traced data). Bytes already widened to int32 (the paged kernel: the
+    TPU vector unit has no 8-bit shifts) unpack to int32."""
+    out = jnp.int8 if packed.dtype == jnp.uint8 else packed.dtype
+    lo = (packed & 0xF).astype(out)
+    hi = ((packed >> 4) & 0xF).astype(out)
     ext = lambda n: jnp.where(n >= 8, n - 16, n)  # noqa: E731
     return jnp.concatenate([ext(lo), ext(hi)], axis=-1)
 
@@ -330,9 +332,8 @@ def int8_matmul_tp(
     else:
         raise ValueError(f"kind must be 'column' or 'row', got {kind!r}")
 
-    # checking off: pallas_call outputs carry no replication/varying-axes
-    # info for shard_map's static checker (check_rep/check_vma by jax
-    # version — utils.compat owns the drift)
+    # checking off: pallas_call outputs carry no varying-axes info for
+    # shard_map's static checker (check_vma)
     return shard_map_nocheck(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
     )(x, w.q, scale_row)

@@ -65,12 +65,20 @@ def init(
         "JAX_PROCESS_ID",
         "COORDINATOR_ADDRESS",
     ]
+    env_driven = any(k in os.environ for k in env_keys)
     # TPU pod metadata only counts as a topology signal when we're actually
     # going to run on TPU — a CPU-forced run (tests, notebooks) on a TPU VM
-    # must not try to rendezvous against the pod runtime.
+    # must not try to rendezvous against the pod runtime — and only when it
+    # names MORE than one host: a one-host TPU machine sets
+    # TPU_WORKER_HOSTNAMES too (to its own name), and there one process
+    # drives every local chip with nothing to rendezvous with.
     if not os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        env_keys += ["TPU_WORKER_HOSTNAMES", "MEGASCALE_COORDINATOR_ADDRESS"]
-    env_driven = any(k in os.environ for k in env_keys)
+        hosts = os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+        env_driven = (
+            env_driven
+            or len([h for h in hosts if h.strip()]) > 1
+            or "MEGASCALE_COORDINATOR_ADDRESS" in os.environ
+        )
     explicit = coordinator_address is not None or num_processes is not None
 
     if not explicit and not env_driven:

@@ -265,7 +265,7 @@ def load_hf_llama(
     — serve with ``dataclasses.replace(cfg, quantized=True)``.
     ``scan_layers`` (default: follow ``cfg.scan_layers``) stacks the L
     per-layer subtrees under ``layers/block/...`` with a leading layer
-    axis — the one-program layout (DECODE_r04.md) — stacking int8 leaves
+    axis — the one-program layout — stacking int8 leaves
     (4x smaller than float), never the float originals.
 
     ``strict=True`` (default) fails loud if the checkpoint contains
@@ -273,7 +273,7 @@ def load_hf_llama(
     checkpoints store ``*.bias`` tensors TransformerLM has no slot for;
     dropping them silently would serve wrong logits. ``materialize=True``
     returns device-resident jax arrays (host-numpy leaves re-upload on
-    every consuming launch — CLAUDE.md / DECODE_r04.md); pass ``False``
+    every consuming launch — CLAUDE.md); pass ``False``
     to keep host numpy for tree surgery before placement.
     """
     ckpt = HFCheckpoint(path)

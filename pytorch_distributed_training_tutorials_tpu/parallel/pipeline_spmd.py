@@ -103,8 +103,7 @@ def spmd_pipeline(
         return jax.lax.psum(out, stage_axis)
 
     # checking off: the hand-rolled ppermute schedule carries no
-    # replication/varying-axes info the static checker can follow
-    # (check_rep/check_vma by jax version — utils.compat owns the drift)
+    # varying-axes info the static checker (check_vma) can follow
     return shard_map_nocheck(
         local_schedule,
         mesh=mesh,

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from pytorch_distributed_training_tutorials_tpu.utils.compat import (
-    pcast_varying,
     shard_map_nocheck,
 )
 from pytorch_distributed_training_tutorials_tpu.parallel.mesh import (
@@ -150,9 +149,9 @@ def make_ring_attention(
     n = mesh.shape[seq_axis]
     spec = _qkv_spec(mesh, data_axis, seq_axis, model_axis)
 
-    # checking off: 0.4.x's check_rep cannot reconcile the fresh (o, l, m)
-    # scan carry with the ppermute-fed fold outputs (the vma-era fix is the
-    # pcast tag below; utils.compat owns both sides of the seam)
+    # checking off: the ring is an unrolled ppermute chain the checker
+    # cannot follow; the fresh (o, l, m) scan carry is tagged varying
+    # below so it matches the ppermute-fed fold outputs
     @partial(
         shard_map_nocheck,
         mesh=mesh,
@@ -174,9 +173,10 @@ def make_ring_attention(
         # iterations, including the varying-manual-axis tags the folded
         # (sharded) K/V blocks impart — mark the fresh state varying over
         # every mesh axis up front (the fold output's tag is the union of
-        # the carry's and the sharded operands'). Identity on jax without
-        # the vma machinery (utils.compat owns the version seam).
-        o, l, m = pcast_varying((o, l, m), mesh.axis_names)
+        # the carry's and the sharded operands').
+        o, l, m = jax.lax.pcast(
+            (o, l, m), tuple(mesh.axis_names), to="varying"
+        )
 
         k_t, v_t = kb, vb
         shift = [(j, (j + 1) % n) for j in range(n)]

@@ -26,10 +26,7 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exposes it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 
 from pytorch_distributed_training_tutorials_tpu.parallel.mesh import (

@@ -1,58 +1,19 @@
-"""jax API-drift shims: one module owns every version seam.
+"""``shard_map`` with the varying-mesh-axes check off: one spelling for the
+bodies the checker cannot follow.
 
-The toolchain pins jax 0.4.37 while parts of the codebase target the
-post-0.5 surface; each drift point below is a rename or addition that is
-semantically identical across the line, so a thin adapter keeps every call
-site on one spelling:
-
-- ``shard_map`` moved from ``jax.experimental.shard_map`` to the top level.
-- Its "skip the static output-replication check" flag was renamed
-  ``check_rep`` (0.4.x, replication bookkeeping) -> ``check_vma`` (>= 0.5,
-  varying-mesh-axes bookkeeping). Kernels whose outputs carry no such info
-  (``pallas_call`` results, hand-rolled collectives) must disable it under
-  either name.
-- ``jax.lax.pcast(..., to="varying")`` (the explicit varying-axes tag for
-  values entering a ``shard_map`` scan carry) does not exist before the vma
-  machinery did; on older jax there is nothing to tag and the identity is
-  the correct shim.
-
-No jax arrays are created at import time (CLAUDE.md import-purity rule) —
-``inspect.signature`` touches only Python metadata.
+No jax arrays are created at import time (CLAUDE.md import-purity rule).
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5 re-exports shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x: experimental only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_NOCHECK_KW = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
 
 
 def shard_map_nocheck(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication/varying-axes checking disabled,
-    whichever flag this jax spells it as. For bodies whose outputs carry no
-    replication info the checker can follow (``pallas_call`` custom calls,
-    unrolled ppermute rings)."""
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_NOCHECK_KW
+    """``jax.shard_map(..., check_vma=False)``. For bodies whose outputs
+    carry no varying-axes info the checker can follow (``pallas_call``
+    custom calls, unrolled ppermute rings)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
-
-
-def pcast_varying(tree, axis_names):
-    """Tag ``tree`` as varying over ``axis_names`` where jax has the vma
-    machinery (``jax.lax.pcast``, >= 0.6); identity on older jax, whose
-    shard_map carries no varying-axes tags to reconcile."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return tree
-    return pcast(tree, tuple(axis_names), to="varying")

@@ -2,10 +2,10 @@
 
 HISTORICAL NOTE (round 4): this sweep's original premise — a per-matmul
 "floor" at decode shapes — was wrong. Its 32-long chains divided a fixed
-~75-130 ms per-LAUNCH roundtrip, which is why every size/path/blocking
-"measured" ~2.5-3.5 ms: see scripts/launch_overhead_probe.py for the
-corrected methodology (fit wall = fixed + per_op * len over two chain
-lengths) and DECODE_r04.md for the full story. Kept because the RELATIVE
+per-LAUNCH roundtrip by their length, which is why every
+size/path/blocking "measured" the same: see
+scripts/launch_overhead_probe.py for the corrected methodology (fit
+wall = fixed + per_op * len over two chain lengths). Kept because the RELATIVE
 blocking comparison at fixed chain length is still valid (the fixed term
 cancels), just not the absolute per-op numbers.
 

@@ -1,11 +1,11 @@
 """Device-trace the 1.2B int8 serving decode and print the per-op table.
 
 This script answers "where does the decode step actually spend device
-time" the same way PROFILE_r04.md did for the train step: capture a
+time" the same way scripts/profile_step.py does for the train step: capture a
 jax.profiler trace of one compiled generate() call and classify
 on-device op durations with ``obs.StepReport`` (the same
 fusion-body-aware classifier every other trace consumer uses — no local
-name heuristics). Round-4 finding (DECODE_r04.md): the 1.2B decode
+name heuristics). Round-4 finding: the 1.2B decode
 executes ~3.6 ms/step on device; the original 2.7 tok/s receipt was
 numpy-leaf re-upload (fixed by utils.tree.device_materialize), not
 device time — this trace was the evidence (device busy 0.08 s inside a

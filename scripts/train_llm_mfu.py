@@ -1,9 +1,10 @@
-"""LM train-step MFU on the real chip — the TRAIN_LLM_r05 receipt.
+"""LM train-step MFU on the real chip — the TRAIN_LLM_r05.json receipt.
 
-The round-4 verdict: the framework's deepest asset is the transformer
-stack, yet the only measured training MFU was conv-bound ResNet (57%,
-architecture-capped). This script measures what fraction of the v5e's
-197 bf16 TFLOP/s a full `TransformerLM` train step achieves — the
+The framework's deepest asset is the transformer stack. This script
+measures what fraction of the chip's bf16 peak
+(``bench.lm_headline.PEAK_BF16_BY_DEVICE_KIND``; a device that is not in
+the table raises, so a CPU run reports nothing) a full `TransformerLM`
+train step achieves — the
 standard headline metric for a distributed-training framework — and
 sweeps the knobs that move it (remat, attention kernel + block sizes,
 batch, sequence length, the fused loss/optimizer tail).
@@ -26,10 +27,6 @@ Run on the real chip:
 CLI, ``python -m pytorch_distributed_training_tutorials_tpu.bench.lm_headline`` — 12-step chain;
 this sweep harness defaults to 8-step chains, ~1.5 MFU points more
 launch-amortization per row, fine for RELATIVE comparisons.)
-
-CPU smoke (tiny shapes, correctness of the harness only):
-
-    JAX_PLATFORMS=cpu python scripts/train_llm_mfu.py --preset smoke --steps 2
 """
 
 from __future__ import annotations
@@ -104,11 +101,12 @@ SWEEP = [
 
 
 def main() -> None:
-    args = parse()
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
+    from pytorch_distributed_training_tutorials_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
 
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    enable_compile_cache()
+    args = parse()
 
     results = []
     if args.sweep:

@@ -12,7 +12,7 @@ both directions on a fast CPU proxy (small MLP, data subset):
 
 The full-scale positive result (ResNet-18, 7 bench epochs -> 0.9961 with
 eval_loss 0.0132; signal=0.30 misses at 0.9867) is recorded in the
-``_synthetic_images`` docstring and in ``BENCH_r04.json``.
+``_synthetic_images`` docstring.
 """
 
 import jax.numpy as jnp

@@ -4,7 +4,7 @@ The kernel must be a drop-in ``attention_fn`` — same math as
 ``causal_attention`` (reference has no attention of its own; SURVEY.md
 section 5.7), different memory story. Interpreter mode runs the identical
 kernel code path on the CPU mesh (real-TPU perf/memory evidence lives in
-``FLASH_r04.md``, produced by ``scripts/flash_bench.py``).
+a report produced by ``scripts/flash_bench.py``).
 """
 
 import jax

@@ -334,7 +334,7 @@ def test_filter_logits_matches_sorted_reference(top_k, top_p):
 
 
 def test_filter_logits_compiles_without_full_vocab_sort():
-    """VERDICT r04 #5: at a real vocab the per-step O(V log V) sorts
+    """Round-4 review item: at a real vocab the per-step O(V log V) sorts
     rivaled the lm_head matmul. The filters must lower through lax.top_k
     (a partial top-k selection), never the sort primitive — asserted on
     the jaxpr, which is backend-independent (on CPU the TopK custom call

@@ -1,6 +1,6 @@
 """Real-data format readers: idx(.gz) MNIST and CIFAR-10 python pickles.
 
-Round-1 gap (VERDICT weak #3): the real parse paths (`_read_idx`, the CIFAR
+Round-1 gap: the real parse paths (`_read_idx`, the CIFAR
 pickle branch) were dead code in tests — only the synthetic surrogate ever
 ran. These tests write byte-exact fixture files in the standard formats
 (IDX magic/dims/payload per Yann LeCun's spec; CIFAR's pickled

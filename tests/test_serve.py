@@ -996,7 +996,10 @@ def test_adapter_prefix_keys_are_tenant_scoped(model_params):
     cache (their KV segments embed different weights); the same tenant
     re-running the prompt must. Tokens stay per-tenant deterministic."""
     model, params = model_params
-    bank = _lora_bank(model)
+    # factors large enough that each tenant's greedy stream leaves the
+    # base model's with a margin (at 0.05 a toy-model near-tie can hide
+    # a live delta)
+    bank = _lora_bank(model, scale=0.2)
     engine = ServeEngine(
         model, params, n_slots=1, tokens_per_launch=8, adapter_bank=bank,
         prefix_cache_bytes=16 * 1024 * 1024,
@@ -1075,7 +1078,8 @@ def test_adapter_refresh_picks_up_registrations(model_params):
     rng = np.random.Generator(np.random.PCG64(77))
     bank.register("late", jax.tree_util.tree_map(
         lambda leaf: jnp.asarray(
-            rng.standard_normal(leaf.shape) * 0.05, leaf.dtype
+            # 0.2, not 0.05: a margin over the toy model's near-ties
+            rng.standard_normal(leaf.shape) * 0.2, leaf.dtype
         ),
         bank.row_zeros(),
     ))

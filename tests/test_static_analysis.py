@@ -1244,7 +1244,7 @@ def test_unused_suppression_is_itself_suppressible():
     src = """
         import time
 
-        # graftcheck: disable=import-purity,unused-suppression -- fires only on the TPU host's sitecustomize
+        # graftcheck: disable=import-purity,unused-suppression -- fires only on the TPU host
         x = 1
     """
     findings = check(src)

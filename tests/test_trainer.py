@@ -316,10 +316,14 @@ def test_skip_step_elides_poisoned_update_and_continues():
         if i == 3:
             continue
         t_ref.state, _ = t_ref.train_step(t_ref.state, batch)
+    # float tolerance, not bitwise: the guarded step is a different
+    # compiled program and XLA:CPU may fuse it to a different last ulp
     for la, lb in zip(
         leaves(t_guard.state.params), leaves(t_ref.state.params)
     ):
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+        np.testing.assert_allclose(
+            np.asarray(la), np.asarray(lb), rtol=1e-5, atol=1e-7
+        )
 
 
 def test_skip_step_stamps_flight_event_on_batched_drain():
@@ -348,8 +352,8 @@ def test_skip_step_stamps_flight_event_on_batched_drain():
 
 def test_skip_step_guard_off_path_identical():
     """skip_nonfinite=True with NO faults changes nothing: params after a
-    full epoch are bitwise equal to the guard-off trainer and the skip
-    counter stays zero."""
+    full epoch equal the guard-off trainer's (float tolerance: two
+    compiled programs) and the skip counter stays zero."""
     leaves = jax.tree_util.tree_leaves
     t_a = _guard_trainer(skip_nonfinite=True)
     t_b = _guard_trainer()
@@ -358,7 +362,9 @@ def test_skip_step_guard_off_path_identical():
     for la, lb in zip(
         leaves(t_a.state.params), leaves(t_b.state.params)
     ):
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+        np.testing.assert_allclose(
+            np.asarray(la), np.asarray(lb), rtol=1e-5, atol=1e-7
+        )
     assert t_a.steps_skipped == 0
 
 
