@@ -1,0 +1,242 @@
+"""Plain reference: a pre-RMSNorm, rotary, grouped-query, SwiGLU decoder
+with an untied head, its loss, its gradients and AdamW, in float32
+``jax.numpy``.
+
+Written from the published description of the block (InternLM2 and Mistral
+share it: ``x + Attn(RMSNorm(x))``, ``x + SwiGLU(RMSNorm(x))``, rotary
+embedding in the half-split ``rotate_half`` convention of both models'
+public code, K/V heads shared by groups of query heads, no bias, a final
+RMSNorm and an untied output head). It imports nothing of the program and
+takes nothing the program has made: weights come from
+``benchmark/lib/weights.py`` in this file's own layout.
+
+Every matrix product runs at ``Precision.HIGHEST`` (on a TPU a float32
+product is otherwise one bfloat16 pass). One sequence at a time, layers
+under ``lax.scan`` with the block rematerialized, attention one K/V head at
+a time: it fits beside 7 GB of int8 weights.
+
+The controls of ``correct`` are this same code one precision lower:
+``precision="float8"`` (e4m3) or ``"int8"`` (dynamic absmax) rounds both
+operands of every linear layer, straight-through gradient, where the
+configuration states bfloat16 compute, and ``weight_bits=4`` rounds int8 weights to int4 where
+it states int8 weights.
+
+Parameter layout (``L`` layers stacked on the leading axis)::
+
+    embed (V, d)   final_norm (d,)   head (d, V)
+    layers: attn_norm (L, d)  wq (L, d, H*hd)  wk, wv (L, d, KV*hd)
+            wo (L, H*hd, d)   mlp_norm (L, d)
+            w_gate, w_up (L, d, ff)   w_down (L, ff, d)
+
+A serving weight is ``{"q": int8 (..., K, N), "scale": f32 (..., 1, N)}``
+in place of the float array, standing for ``q * scale``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class Shape:
+    """The sizes the equations need, under the published key names."""
+
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    intermediate_size: int
+    rope_theta: float
+    rms_norm_eps: float
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @classmethod
+    def from_config(cls, config: dict) -> "Shape":
+        return cls(**{f.name: config[f.name] for f in dataclasses.fields(cls)})
+
+
+def _fake_int8(x, axis):
+    """Round to 255 levels of the absmax along ``axis``; the gradient
+    passes straight through."""
+    s = jnp.maximum(jnp.max(jnp.abs(x), axis=axis, keepdims=True), 1e-30) / 127
+    return x + jax.lax.stop_gradient(jnp.round(x / s) * s - x)
+
+
+def _fake_fp8(x):
+    """Round to float8 (e4m3); the gradient passes straight through."""
+    low = x.astype(jnp.float8_e4m3fn).astype(x.dtype)
+    return x + jax.lax.stop_gradient(low - x)
+
+
+def _weight(w, weight_bits: int):
+    """A float array as it is; ``{"q", "scale"}`` as ``q * scale``, with
+    the int8 values rounded to ``weight_bits`` first where that is 4."""
+    if not isinstance(w, dict):
+        return w
+    q = w["q"].astype(jnp.float32)
+    if weight_bits == 4:
+        q = jnp.round(q * (7 / 127)) * (127 / 7)
+    return q * w["scale"]
+
+
+def _linear(x, w, precision: str, weight_bits: int):
+    w = _weight(w, weight_bits)
+    if precision == "int8":
+        x, w = _fake_int8(x, -1), _fake_int8(w, 0)
+    elif precision == "float8":
+        x, w = _fake_fp8(x), _fake_fp8(w)
+    elif precision != "float32":
+        raise ValueError(f"unknown precision {precision!r}")
+    return jnp.matmul(x, w, precision=HIGHEST)
+
+
+def rms_norm(x, scale, eps: float):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+
+
+def rope(x, theta: float):
+    """Rotary embedding of ``x`` (S, heads, hd) at positions 0..S-1: pairs
+    are (i, i + hd/2), the ``rotate_half`` convention."""
+    s, _, hd = x.shape
+    half = hd // 2
+    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _attention(q, k, v):
+    """Causal softmax attention, one K/V head with its group of query
+    heads at a time. q (S, KV, G, hd); k, v (S, KV, hd)."""
+    s, _, _, hd = q.shape
+    causal = jnp.tril(jnp.ones((s, s), jnp.bool_))
+
+    def one(args):
+        qh, kh, vh = args  # (S, G, hd), (S, hd), (S, hd)
+        scores = jnp.einsum("sgd,td->gst", qh, kh, precision=HIGHEST)
+        scores = jnp.where(causal, scores / jnp.sqrt(jnp.float32(hd)), -1e30)
+        return jnp.einsum(
+            "gst,td->sgd", jax.nn.softmax(scores, -1), vh, precision=HIGHEST
+        )
+
+    out = jax.lax.map(
+        one, (q.transpose(1, 0, 2, 3), k.transpose(1, 0, 2), v.transpose(1, 0, 2))
+    )  # (KV, S, G, hd)
+    return out.transpose(1, 0, 2, 3).reshape(s, -1)
+
+
+def _block(x, lp, shape: Shape, precision: str, weight_bits: int):
+    lin = functools.partial(
+        _linear, precision=precision, weight_bits=weight_bits
+    )
+    s = x.shape[0]
+    h, kv, hd = (
+        shape.num_attention_heads, shape.num_key_value_heads, shape.head_dim
+    )
+    y = rms_norm(x, lp["attn_norm"], shape.rms_norm_eps)
+    q = rope(lin(y, lp["wq"]).reshape(s, h, hd), shape.rope_theta)
+    k = rope(lin(y, lp["wk"]).reshape(s, kv, hd), shape.rope_theta)
+    v = lin(y, lp["wv"]).reshape(s, kv, hd)
+    x = x + lin(_attention(q.reshape(s, kv, h // kv, hd), k, v), lp["wo"])
+    y = rms_norm(x, lp["mlp_norm"], shape.rms_norm_eps)
+    gated = jax.nn.silu(lin(y, lp["w_gate"])) * lin(y, lp["w_up"])
+    return x + lin(gated, lp["w_down"])
+
+
+def hidden(params, tokens, shape: Shape, precision="float32", weight_bits=8):
+    """Final-norm hidden states (S, d) of one sequence ``tokens`` (S,)."""
+    x = params["embed"][tokens]
+    block = jax.checkpoint(
+        lambda x, lp: (_block(x, lp, shape, precision, weight_bits), None)
+    )
+    x, _ = jax.lax.scan(block, x, params["layers"])
+    return rms_norm(x, params["final_norm"], shape.rms_norm_eps)
+
+
+def logits(
+    params, tokens, shape: Shape, positions=None, precision="float32",
+    weight_bits=8,
+):
+    """Logits (P, V) of one sequence at ``positions`` (all when None)."""
+    x = hidden(params, tokens, shape, precision, weight_bits)
+    if positions is not None:
+        x = x[positions]
+    return _linear(x, params["head"], precision, weight_bits)
+
+
+def sequence_loss(params, tokens, targets, shape: Shape, precision="float32"):
+    """Mean next-token cross entropy of one sequence."""
+    lg = logits(params, tokens, shape, precision=precision)
+    lse = jax.nn.logsumexp(lg, -1)
+    return jnp.mean(lse - jnp.take_along_axis(lg, targets[:, None], 1)[:, 0])
+
+
+def grad_fn(shape: Shape, precision="float32", placement=None):
+    """``fn(params, tokens, targets, rows=None)`` -> (mean loss over the
+    batch rows, its gradients), a row at a time. ``rows`` limits the mean
+    to those rows (the half-batch fault of the tests). ``placement`` is a
+    tree of shardings for the gradients, where the parameters are spread
+    over several chips because one cannot hold them: where a leaf lies,
+    not what is computed."""
+    kw = {} if placement is None else {"out_shardings": (None, placement)}
+    step = jax.jit(
+        jax.value_and_grad(
+            lambda p, x, y: sequence_loss(p, x, y, shape, precision)), **kw
+    )
+    add = jax.jit(
+        lambda a, b: jax.tree_util.tree_map(jnp.add, a, b), donate_argnums=0
+    )
+    scale = jax.jit(
+        lambda g, n: jax.tree_util.tree_map(lambda x: x / n, g), donate_argnums=0
+    )
+
+    def fn(params, tokens, targets, rows=None):
+        rows = range(tokens.shape[0]) if rows is None else rows
+        total, grads = 0.0, None
+        for r in rows:
+            loss, g = step(params, tokens[r], targets[r])
+            total += float(loss)
+            grads = g if grads is None else add(grads, g)
+        n = len(rows)
+        return total / n, scale(grads, jnp.float32(n))
+
+    return fn
+
+
+def loss_and_grads(params, tokens, targets, shape: Shape, precision="float32",
+                   rows=None):
+    """One call of :func:`grad_fn`."""
+    return grad_fn(shape, precision)(params, tokens, targets, rows)
+
+
+@functools.partial(jax.jit, static_argnums=(5,), donate_argnums=(0, 2, 3))
+def adamw(params, grads, mu, nu, count, hyper: tuple):
+    """One AdamW step (Loshchilov & Hutter; decay on every leaf, as the
+    configuration's train options state). ``hyper`` is
+    ``(lr, b1, b2, eps, weight_decay)``; ``count`` is the step just taken,
+    from 1."""
+    lr, b1, b2, eps, wd = hyper
+    t = count.astype(jnp.float32)
+
+    def leaf(p, g, m, n):
+        m = b1 * m + (1 - b1) * g
+        n = b2 * n + (1 - b2) * g * g
+        step = (m / (1 - b1**t)) / (jnp.sqrt(n / (1 - b2**t)) + eps)
+        return p - lr * (step + wd * p), m, n
+
+    out = jax.tree_util.tree_map(leaf, params, grads, mu, nu)
+    pick = lambda i: jax.tree_util.tree_map(  # noqa: E731
+        lambda _, o: o[i], params, out
+    )
+    return pick(0), pick(1), pick(2)
