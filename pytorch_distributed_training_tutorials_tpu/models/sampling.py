@@ -101,6 +101,7 @@ def filter_logits(logits, top_k: int, top_p: float):
     return logits
 
 
+@jax.named_scope("sampling")
 def sample_logits(
     logits, key, temperature: float, top_k: int = 0, top_p: float = 1.0
 ):
@@ -121,6 +122,7 @@ def sample_logits(
     return nxt.astype(jnp.int32), key
 
 
+@jax.named_scope("sampling")
 def sample_logits_per_slot(
     logits, keys, temperature: float, top_k: int = 0, top_p: float = 1.0
 ):
@@ -207,6 +209,7 @@ def ngram_draft(hist, hist_len, k: int, ngram: int):
     return draft.astype(jnp.int32)
 
 
+@jax.named_scope("sampling")
 def speculative_accept(
     logits, draft, keys, temperature: float, top_k: int = 0,
     top_p: float = 1.0,

@@ -347,6 +347,7 @@ def _kv_storage(k_dtype, v_dtype, d: int):
     return k_dtype, v_dtype, d, None
 
 
+@jax.named_scope("kv_cache")
 def _store_decode_kv(var, val: jax.Array, pos: jax.Array) -> None:
     """Write one decode chunk's per-row value ``val`` (B, S, ...) into cache
     variable ``var`` (B, max_seq_len, ...) at sequence positions
@@ -402,6 +403,7 @@ def _gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
     return out.reshape((b, p * pool.shape[1]) + pool.shape[2:])
 
 
+@jax.named_scope("kv_cache")
 def _store_paged_kv(var, table: jax.Array, val: jax.Array, pos) -> None:
     """Paged twin of :func:`_store_decode_kv`: write row r's token s of
     ``val`` (B, S, ...) into pool variable ``var`` (kv_pages, page_size,
@@ -744,16 +746,17 @@ class Attention(nn.Module):
                     quant=quant,
                 )
             else:
-                k_read = _decode_kv(
-                    _gather_pages(cached_k.value, tbl),
-                    _gather_pages(k_scale.value, tbl) if quant else None,
-                    quant, k.dtype,
-                )
-                v_read = _decode_kv(
-                    _gather_pages(cached_v.value, tbl),
-                    _gather_pages(v_scale.value, tbl) if quant else None,
-                    quant, v.dtype,
-                )
+                with jax.named_scope("kv_cache"):
+                    k_read = _decode_kv(
+                        _gather_pages(cached_k.value, tbl),
+                        _gather_pages(k_scale.value, tbl) if quant else None,
+                        quant, k.dtype,
+                    )
+                    v_read = _decode_kv(
+                        _gather_pages(cached_v.value, tbl),
+                        _gather_pages(v_scale.value, tbl) if quant else None,
+                        quant, v.dtype,
+                    )
                 qpos = pos[..., None] + jnp.arange(s)
                 valid = (
                     jnp.arange(cfg.max_seq_len) <= qpos[..., :, None]
@@ -794,14 +797,15 @@ class Attention(nn.Module):
             if quant:
                 _store_decode_kv(k_scale, k_s, pos)
                 _store_decode_kv(v_scale, v_s, pos)
-            k_read = _decode_kv(
-                cached_k.value, k_scale.value if quant else None,
-                quant, k.dtype,
-            )
-            v_read = _decode_kv(
-                cached_v.value, v_scale.value if quant else None,
-                quant, v.dtype,
-            )
+            with jax.named_scope("kv_cache"):
+                k_read = _decode_kv(
+                    cached_k.value, k_scale.value if quant else None,
+                    quant, k.dtype,
+                )
+                v_read = _decode_kv(
+                    cached_v.value, v_scale.value if quant else None,
+                    quant, v.dtype,
+                )
             idx.value = pos + s
             # attend over the whole cache: query token i (global position
             # pos + i) masks positions beyond pos + i — same math as
@@ -837,21 +841,22 @@ class Attention(nn.Module):
                 quant = _kv_quant_mode(cfg.kv_cache_dtype)
                 k_q, k_s = _encode_kv(k, quant)  # quantized cache: q+scale
                 v_q, v_s = _encode_kv(v, quant)
-                cached_k.value = jax.lax.dynamic_update_slice(
-                    cached_k.value, k_q.astype(cached_k.value.dtype),
-                    (0, 0, 0, 0)
-                )
-                cached_v.value = jax.lax.dynamic_update_slice(
-                    cached_v.value, v_q.astype(cached_v.value.dtype),
-                    (0, 0, 0, 0)
-                )
-                if quant:
-                    k_scale.value = jax.lax.dynamic_update_slice(
-                        k_scale.value, k_s, (0, 0, 0)
+                with jax.named_scope("kv_cache"):
+                    cached_k.value = jax.lax.dynamic_update_slice(
+                        cached_k.value, k_q.astype(cached_k.value.dtype),
+                        (0, 0, 0, 0)
                     )
-                    v_scale.value = jax.lax.dynamic_update_slice(
-                        v_scale.value, v_s, (0, 0, 0)
+                    cached_v.value = jax.lax.dynamic_update_slice(
+                        cached_v.value, v_q.astype(cached_v.value.dtype),
+                        (0, 0, 0, 0)
                     )
+                    if quant:
+                        k_scale.value = jax.lax.dynamic_update_slice(
+                            k_scale.value, k_s, (0, 0, 0)
+                        )
+                        v_scale.value = jax.lax.dynamic_update_slice(
+                            v_scale.value, v_s, (0, 0, 0)
+                        )
                 idx.value = jnp.asarray(s, jnp.int32)
             attn = (
                 cfg.attention_fn
@@ -1080,7 +1085,14 @@ class TransformerLM(nn.Module):
                 in_axes=nn.broadcast,
                 length=cfg.n_layers,
             )(cfg, decode, prefill, name="layers")
-            x, _ = stack(x, ids)
+            # the scope marks what lax.scan itself does around the cell:
+            # it slices every stacked leaf (each layer's weights, its cache
+            # slice) out by the layer index and stacks the new cache back.
+            # No line of the program does that, so no narrower scope
+            # (weights_slice / kv_cache) can be put on it; an op under
+            # layer_scan and not under the cell's "layers" is that slicing.
+            with jax.named_scope("layer_scan"):
+                x, _ = stack(x, ids)
         else:
             # decode/prefill are Python bools steering cache behavior — they
             # must stay static under remat (args 2/3 of __call__ incl. self)

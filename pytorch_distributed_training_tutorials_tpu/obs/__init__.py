@@ -30,6 +30,17 @@ Plus the production twin of the benchmarking pillars (ISSUE 10):
   the test monkeypatch spies), and no host-numpy re-uploads per
   dispatch; violations announce as typed flight events + auto-dumps.
 
+Which instrument is for what (ISSUE 27). The profiler's spans are NOT
+here: ``ServeEngine`` and ``Trainer`` wrap their phases in
+:func:`..utils.profiling.annotate` (``prog:<phase>``, integer fields) —
+always there, free while nothing traces, on the profiler's own clock
+beside the device's ``XLA Ops`` line, and read by the benchmark's
+per-layer readers (``benchmark/lib/program_trace.py``).
+:class:`FlightRecorder` is the opt-in ring for post-mortems: its own
+``perf_counter`` clock, jax-free by contract, read by ``flight_stats()``
+and ``scripts/flight_view.py``. The spans are named after its
+``EVENT_KINDS`` where a kind exists, so both name the same boundaries.
+
 ``python -m pytorch_distributed_training_tutorials_tpu.obs --selftest`` smoke-runs all four on a
 tiny CPU-mesh workload.
 

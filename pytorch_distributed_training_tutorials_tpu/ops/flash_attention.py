@@ -285,6 +285,7 @@ def _fwd_impl(q, k, v, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(qf, kf, vf)
     return out, lse, (qf, kf, vf), sp, pad
@@ -374,6 +375,7 @@ def _flash_bwd(block_q, block_k, interpret, res, g):
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, sp, d), qf.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_attention_dq",
         interpret=interpret,
     )(qf, kf, vf, do, lse, delta)
 
@@ -407,6 +409,7 @@ def _flash_bwd(block_q, block_k, interpret, res, g):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_attention_dkv",
         interpret=interpret,
     )(qf, kf, vf, do, lse, delta)
 

@@ -294,6 +294,7 @@ def _fwd_impl(h2, w, y, block_n, block_v, interpret):
         out_specs=[rowspec, rowspec],
         out_shape=[jax.ShapeDtypeStruct((8, np_), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32)] * 3,
+        name="fused_loss_fwd",
         interpret=interpret,
     )(hf, wf, y8)
     return lse[0, :n], tgt[0, :n]
@@ -328,6 +329,7 @@ def _bwd_impl(h2, w, y, lse, g, block_n, block_v, interpret):
         out_specs=hspec,
         out_shape=jax.ShapeDtypeStruct((np_, d), hf.dtype),
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
+        name="fused_loss_dh",
         interpret=interpret,
     )(hf, wf, y8, _row8(lse, np_), _row8(gf, np_))
 
@@ -353,6 +355,7 @@ def _bwd_impl(h2, w, y, lse, g, block_n, block_v, interpret):
         out_specs=wspec_t,
         out_shape=jax.ShapeDtypeStruct((d, vp), wf.dtype),
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
+        name="fused_loss_dw",
         interpret=interpret,
     )(hf, wf, y8, _row8(lse, np_), _row8(gf, np_))
 

@@ -104,6 +104,7 @@ def _leaf_update(
         ],
         # moments update in place — no double-buffered m/v in HBM
         input_output_aliases={1: 1, 2: 2},
+        name="fused_adamw",
         interpret=interpret,
     )(pack(g), pack(m), pack(v), pack(p), c)
 

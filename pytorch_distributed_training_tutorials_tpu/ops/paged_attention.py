@@ -257,6 +257,7 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, sg, d), q.dtype),
+        name="paged_attention",
         interpret=interpret,
     )(table, pos, *operands)
     return (

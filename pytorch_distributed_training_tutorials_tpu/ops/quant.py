@@ -256,6 +256,7 @@ def int8_matmul(
         ),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        name="int8_matmul",
         interpret=interpret,
     )(x.astype(jnp.float32), q, scale_row)
     return out[:m, :n] if (pad_m or pad_n) else out

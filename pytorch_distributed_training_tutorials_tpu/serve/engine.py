@@ -184,6 +184,7 @@ from pytorch_distributed_training_tutorials_tpu.serve.slots import (
     zero_cache,
 )
 from pytorch_distributed_training_tutorials_tpu.utils import chaos as chaos_lib
+from pytorch_distributed_training_tutorials_tpu.utils.profiling import annotate
 
 
 class _Active:
@@ -1768,7 +1769,17 @@ class ServeEngine:
 
     def _admit(self, request: Request) -> int:
         """Shared admission body of :meth:`submit` and :meth:`accept`:
-        adapter + paged checks, scheduler enqueue, flight stamp."""
+        adapter + paged checks, scheduler enqueue, flight stamp — all
+        inside the ``prog:submit`` profiler span."""
+        with annotate(
+            "submit", p_len=len(request.prompt),
+            max_new=request.max_new_tokens,
+        ) as span:
+            rid = self._admit_checked(request)
+            span.set_metadata(rid=rid)
+        return rid
+
+    def _admit_checked(self, request: Request) -> int:
         aid = int(getattr(request, "adapter", 0))
         if aid != 0 and not self._adapters:
             raise ValueError(
@@ -1924,16 +1935,18 @@ class ServeEngine:
         this round (possibly mid-chain — surplus chain tokens for a
         finished slot are discarded, exactly like ``generate()``
         truncating at ``max_new_tokens``)."""
-        if self._sentry is None:
-            return self._step_impl()
-        # one sentry accounting round per scheduling round: every fetch
-        # inside must arrive through _sentry_fetch or end_round() flags
-        # it — the production twin of the test monkeypatch spies
-        self._sentry.begin_round(f"step:{self.n_chains}")
-        try:
-            return self._step_impl()
-        finally:
-            self._sentry.end_round()
+        with annotate("step", chain=self.n_chains):
+            if self._sentry is None:
+                return self._step_impl()
+            # one sentry accounting round per scheduling round: every
+            # fetch inside must arrive through _sentry_fetch or
+            # end_round() flags it — the production twin of the test
+            # monkeypatch spies
+            self._sentry.begin_round(f"step:{self.n_chains}")
+            try:
+                return self._step_impl()
+            finally:
+                self._sentry.end_round()
 
     def _step_impl(self) -> list[Completion]:
         if self._adapters and self._bank.version != self._merged_version:
@@ -1943,15 +1956,17 @@ class ServeEngine:
             # slots see the new factors too — register into a free row
             # before serving it and this is a non-event for them)
             self.refresh_adapters()
-        done: list[Completion] = list(self._sweep())
-        if self._flight is not None and done:
-            self._flight.sweep(len(done))
-        done.extend(self._advance_pending())
-        if self._slo:
-            # preemption decision at the chain boundary, BEFORE refill:
-            # a freed (swapped-out) slot is refillable this very round,
-            # so the waiting high-class request starts immediately
-            done.extend(self._maybe_preempt())
+        with annotate("sweep"):
+            done: list[Completion] = list(self._sweep())
+            if self._flight is not None and done:
+                self._flight.sweep(len(done))
+            done.extend(self._advance_pending())
+            if self._slo:
+                # preemption decision at the chain boundary, BEFORE
+                # refill: a freed (swapped-out) slot is refillable this
+                # very round, so the waiting high-class request starts
+                # immediately
+                done.extend(self._maybe_preempt())
         for s in range(self.n_slots):
             if self._slots[s] is not None or s in self._pending:
                 continue
@@ -1960,13 +1975,15 @@ class ServeEngine:
                 break
             if self._flight is not None:
                 self._flight.request_popped(req.request_id)
-            done.extend(self._refill(s, req))
-        if self.active_slots:
+            with annotate("refill", rid=req.request_id, slot=s):
+                done.extend(self._refill(s, req))
+        occupancy = self.active_slots
+        if occupancy:
             chain_id = self.n_chains
             if self._flight is not None:
                 # occupancy at dispatch = chain utilization sample
                 self._flight.chain_start(
-                    self.active_slots, self.n_slots, chain=chain_id
+                    occupancy, self.n_slots, chain=chain_id
                 )
             if self._chaos is not None:
                 chaos_lib.maybe_stall(
@@ -1991,7 +2008,10 @@ class ServeEngine:
             # a host sync — device program order runs them after this
             # chain — so the fetch below is the only place the host
             # waits.
-            self._state, out = self._chain(*args)
+            with annotate(
+                "chain_dispatch", chain=chain_id, occupancy=occupancy
+            ):
+                self._state, out = self._chain(*args)
             self.n_chains += 1
             if self._spec:
                 self.n_verify_forwards += self.tokens_per_launch
@@ -2031,20 +2051,26 @@ class ServeEngine:
         refilled inside the pipeline window fails the snapshot identity
         check in the distribute and ignores this chain's junk rows."""
         fl = self._inflight.popleft()
-        fetched = self._sentry_fetch(fl.out)  # the chain's ONE host fetch
+        with annotate("chain_fetch", chain=fl.chain_id):
+            # the chain's ONE host fetch
+            fetched = self._sentry_fetch(fl.out)
         gen_before = self.generated_tokens
-        if self._spec:
-            if self._guard:
-                toks, counts, oks = fetched
+        with annotate("distribute", chain=fl.chain_id) as span:
+            if self._spec:
+                if self._guard:
+                    toks, counts, oks = fetched
+                else:
+                    (toks, counts), oks = fetched, None
+                done = self._distribute_spec(
+                    toks, counts, oks, view=fl.view
+                )
             else:
-                (toks, counts), oks = fetched, None
-            done = self._distribute_spec(toks, counts, oks, view=fl.view)
-        else:
-            if self._guard:
-                toks, oks = fetched
-            else:
-                toks, oks = fetched, None
-            done = self._distribute(toks, oks, view=fl.view)
+                if self._guard:
+                    toks, oks = fetched
+                else:
+                    toks, oks = fetched, None
+                done = self._distribute(toks, oks, view=fl.view)
+            span.set_metadata(tokens=self.generated_tokens - gen_before)
         if self._flight is not None:
             self._flight.chain_end(
                 tokens=self.generated_tokens - gen_before,
@@ -2076,22 +2102,25 @@ class ServeEngine:
                     len(r.prompt) + r.max_new_tokens
                 )
 
-        while True:
-            if self._chunk:
-                req = self.scheduler.pop(
-                    chunk=self._chunk, pending_long=len(self._pending),
-                    fits=fits,
-                )
-            else:
-                req = self.scheduler.pop(fits=fits)
-            if req is not None or fits is None:
-                return req
-            if (
-                len(self.scheduler) == 0
-                or self.prefix is None
-                or not self.prefix.evict_coldest()
-            ):
-                return None
+        with annotate("queue_pop") as span:
+            while True:
+                if self._chunk:
+                    req = self.scheduler.pop(
+                        chunk=self._chunk,
+                        pending_long=len(self._pending), fits=fits,
+                    )
+                else:
+                    req = self.scheduler.pop(fits=fits)
+                if req is not None or fits is None:
+                    break
+                if (
+                    len(self.scheduler) == 0
+                    or self.prefix is None
+                    or not self.prefix.evict_coldest()
+                ):
+                    break
+            span.set_metadata(rid=-1 if req is None else req.request_id)
+        return req
 
     def _deadline_for(self, req: Request) -> float | None:
         return (
@@ -2526,7 +2555,11 @@ class ServeEngine:
                 self.prefix.insert(
                     tuple(pkey), new_seg, self._nbytes(new_seg)
                 )
-            first = int(self._sentry_fetch(first))
+            with annotate(
+                "prefill_fetch", rid=req.request_id,
+                bucket=bucket if segment is None else s_bucket,
+            ):
+                first = int(self._sentry_fetch(first))
         except Exception:
             # request-level isolation: unpin any splice donor, park the
             # slot (prefill may have set its device-side budget before
@@ -2635,7 +2668,11 @@ class ServeEngine:
                 self.n_prefills += 1
             if grow:
                 self._insert_paged_segment(pkey, pages, p_len)
-            first = int(self._sentry_fetch(first))
+            with annotate(
+                "prefill_fetch", rid=req.request_id,
+                bucket=bucket if segment is None else s_bucket,
+            ):
+                first = int(self._sentry_fetch(first))
         except Exception:
             if segment is not None:
                 self.prefix.release(segment)
@@ -2781,7 +2818,9 @@ class ServeEngine:
                     **akw,
                 )
             self.n_handoffs_in += 1
-            first = int(self._sentry_fetch(first))  # the handoff's ONE fetch
+            with annotate("prefill_fetch", rid=req.request_id):
+                # the handoff's ONE fetch
+                first = int(self._sentry_fetch(first))
         except Exception:
             if pages:
                 self._pool.release_all(pages)
@@ -3073,7 +3112,10 @@ class ServeEngine:
                     self.prefix.insert(
                         tuple(pend.pkey), new_seg, self._nbytes(new_seg)
                     )
-            first = int(self._sentry_fetch(first))
+            with annotate(
+                "prefill_fetch", rid=req.request_id, bucket=f_bucket
+            ):
+                first = int(self._sentry_fetch(first))
         except Exception:
             self._abandon_pending(pend)  # also releases pend.pages
             self.n_prefill_errors += 1
@@ -3257,13 +3299,14 @@ class ServeEngine:
         request cancelled while queued must not strand its transfer
         record — the device futures are simply released)."""
         self._handoff_in.pop(req.request_id, None)
-        comp = Completion(
-            request_id=req.request_id,
-            prompt=[int(t) for t in req.prompt],
-            tokens=[],
-            finish_reason=reason,
-            latency_s=time.perf_counter() - req.submitted_s,
-        )
+        with annotate("complete", rid=req.request_id, tokens=0):
+            comp = Completion(
+                request_id=req.request_id,
+                prompt=[int(t) for t in req.prompt],
+                tokens=[],
+                finish_reason=reason,
+                latency_s=time.perf_counter() - req.submitted_s,
+            )
         if self._flight is not None:
             self._flight.request_completed(
                 req.request_id, reason, tokens=0,
@@ -3288,14 +3331,17 @@ class ServeEngine:
             # unpin it (it stays resident + hot for the next hit)
             self.prefix.release(act.segment)
             act.segment = None
-        comp = Completion(
-            request_id=act.request.request_id,
-            prompt=[int(t) for t in act.request.prompt],
-            tokens=act.tokens,
-            finish_reason=reason,
-            latency_s=time.perf_counter() - act.request.submitted_s,
-            ttft_s=act.ttft_s,
-        )
+        with annotate(
+            "complete", rid=act.request.request_id, tokens=len(act.tokens)
+        ):
+            comp = Completion(
+                request_id=act.request.request_id,
+                prompt=[int(t) for t in act.request.prompt],
+                tokens=act.tokens,
+                finish_reason=reason,
+                latency_s=time.perf_counter() - act.request.submitted_s,
+                ttft_s=act.ttft_s,
+            )
         if self._flight is not None:
             # the span records the Completion's OWN numbers, so the
             # histogram percentiles are sample-identical to sorting the

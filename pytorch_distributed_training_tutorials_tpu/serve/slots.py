@@ -153,6 +153,7 @@ def init_slot_state(model, params, n_slots: int, history: int = 0,
     return state
 
 
+@jax.named_scope("kv_cache")
 def write_slot(cache, prefill_cache, slot, p_len, scan_layers: bool):
     """Splice a batch-1 prefilled cache into slot ``slot`` of the big
     slot-indexed ``cache`` and reset that slot's position to ``p_len`` —
@@ -207,6 +208,7 @@ def _path_strs(path) -> tuple:
     )
 
 
+@jax.named_scope("kv_cache")
 def write_slot_paged(cache, prefill_cache, row, slot, p_len,
                      page_size: int, scan_layers: bool):
     """Paged refill: scatter a batch-1 UNPAGED prefilled cache into the
@@ -252,6 +254,7 @@ def write_slot_paged(cache, prefill_cache, row, slot, p_len,
     return jax.tree_util.tree_map_with_path(upd, cache)
 
 
+@jax.named_scope("kv_cache")
 def extract_segment(cache, seg_len: int, scan_layers: bool):
     """Cut the first ``seg_len`` sequence positions out of a batch-1
     prefilled ``cache`` tree — the retained prefix segment the radix
@@ -286,6 +289,7 @@ def extract_segment(cache, seg_len: int, scan_layers: bool):
     return jax.tree_util.tree_map_with_path(cut, cache)
 
 
+@jax.named_scope("kv_cache")
 def seed_cache(proto, segment, depth):
     """Build a batch-1 full-window cache whose ``[0, seg_len)`` positions
     come from a retained ``segment`` and whose position counters read

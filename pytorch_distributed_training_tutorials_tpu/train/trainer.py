@@ -45,6 +45,9 @@ from pytorch_distributed_training_tutorials_tpu.parallel.data_parallel import (
 from pytorch_distributed_training_tutorials_tpu.obs.metrics import MetricsLogger
 from pytorch_distributed_training_tutorials_tpu.utils import chaos as chaos_lib
 from pytorch_distributed_training_tutorials_tpu.utils.logging import epoch_line
+from pytorch_distributed_training_tutorials_tpu.utils.profiling import annotate
+
+_DONE = object()  # what next() gives when a loader is exhausted
 
 
 class TrainState(struct.PyTreeNode):
@@ -171,12 +174,15 @@ def _make_loss_fn(
             )
         else:
             out, updates = state.apply_fn(variables, x, **kwargs), {}
-        if fused:
-            loss_val = _fused_ce_loss(params, out, y)
-        else:
-            loss_val = _compute_loss(loss, out, y)
-        if aux_loss_weight:
-            loss_val = loss_val + aux_loss_weight * moe_aux_loss(updates)
+        with jax.named_scope("loss"):
+            if fused:
+                loss_val = _fused_ce_loss(params, out, y)
+            else:
+                loss_val = _compute_loss(loss, out, y)
+            if aux_loss_weight:
+                loss_val = loss_val + aux_loss_weight * moe_aux_loss(
+                    updates
+                )
         return loss_val, updates.get("batch_stats")
 
     return loss_fn
@@ -212,10 +218,11 @@ def _apply_update(
     a real non-finite backward reduction would."""
     if chaos is not None and chaos.poisons_grads:
         grads = chaos_lib.poison_grads(grads, state.step, chaos.nan_grad_step)
-    updates, new_opt_state = state.tx.update(
-        grads, state.opt_state, state.params
-    )
-    new_params = optax.apply_updates(state.params, updates)
+    with jax.named_scope("optimizer"):
+        updates, new_opt_state = state.tx.update(
+            grads, state.opt_state, state.params
+        )
+        new_params = optax.apply_updates(state.params, updates)
     metrics = {"loss": loss_val}
     if skip_nonfinite:
         ok = jnp.isfinite(loss_val)
@@ -865,9 +872,17 @@ class Trainer:
         losses = []
         steps = 0
         next_log = self.log_every or 0
-        for chunk in loader.iter_chunks():
+        chunks = iter(loader.iter_chunks())
+        while True:
+            with annotate("loader_next", step=steps):
+                chunk = next(chunks, _DONE)
+            if chunk is _DONE:
+                break
             steps += jax.tree_util.tree_leaves(chunk)[0].shape[0]
-            self.state, chunk_losses = self._chunk_scan(self.state, chunk)
+            with annotate("dispatch", step=steps):
+                self.state, chunk_losses = self._chunk_scan(
+                    self.state, chunk
+                )
             losses.append(chunk_losses)
             if self.log_every and steps >= next_log:
                 # per-chunk granularity (a chunk is one compiled launch;
@@ -882,14 +897,15 @@ class Trainer:
                 if self._monitor_loss(float(chunk_losses[-1])):
                     break  # rolled back: abandon the rest of this epoch
         self.last_epoch_losses = losses[-1] if losses else None
-        if self.defer_host_fetch:
-            # completion sync only — no D2H (see defer_host_fetch in
-            # __init__)
-            if losses:
-                jax.block_until_ready(losses[-1])
-            loss = None
-        else:
-            loss = float(losses[-1][-1]) if losses else None
+        with annotate("epoch_sync", step=steps):
+            if self.defer_host_fetch:
+                # completion sync only — no D2H (see defer_host_fetch in
+                # __init__)
+                if losses:
+                    jax.block_until_ready(losses[-1])
+                loss = None
+            else:
+                loss = float(losses[-1][-1]) if losses else None
         dt = time.perf_counter() - t0
         return self._epoch_metrics(epoch, loss, steps, dt)
 
@@ -925,7 +941,13 @@ class Trainer:
         t0 = time.perf_counter()
         loss = None
         steps = 0
-        for batch in self.loader:
+        batches = iter(self.loader)
+        while True:
+            # the loop's own next(): the wait for a batch is inside the span
+            with annotate("loader_next", step=steps):
+                batch = next(batches, _DONE)
+            if batch is _DONE:
+                break
             if not isinstance(batch, tuple):
                 batch = (batch,)
             self._dispatches += 1
@@ -933,7 +955,8 @@ class Trainer:
                 batch = chaos_lib.maybe_poison_batch(
                     self.chaos, self._dispatches, batch
                 )
-            self.state, metrics = self.train_step(self.state, batch)
+            with annotate("dispatch", step=steps):
+                self.state, metrics = self.train_step(self.state, batch)
             loss = metrics["loss"]
             steps += 1
             # device scalar retained un-fetched; the verbose line is the
@@ -954,7 +977,8 @@ class Trainer:
             if self._rb_factor is not None:
                 # rollback opted in: per-step loss visibility is its price
                 self._monitor_loss(float(loss))
-        jax.block_until_ready(self.state.params)
+        with annotate("epoch_sync", step=steps):
+            jax.block_until_ready(self.state.params)
         dt = time.perf_counter() - t0
         return self._epoch_metrics(epoch, loss, steps, dt)
 

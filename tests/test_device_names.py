@@ -1,0 +1,125 @@
+"""The names the device trace tells the program's work apart by (ISSUE 27):
+a unique ``name=`` on every ``pl.pallas_call`` of ``ops/``, the
+``jax.named_scope``s around the cache, the sampler, the loss and the
+optimizer, and the jitted programs' module names (the trace's
+``XLA Modules`` line; ``benchmark/lib/program_trace.py`` reads all three).
+Metadata only: nothing here may change what a program computes."""
+
+import ast
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+import pytorch_distributed_training_tutorials_tpu as pkg
+from pytorch_distributed_training_tutorials_tpu import create_mesh
+from pytorch_distributed_training_tutorials_tpu.data import ShardedLoader
+from pytorch_distributed_training_tutorials_tpu.models import MLP
+from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+)
+from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+from pytorch_distributed_training_tutorials_tpu.train import Trainer
+from tests.helpers import make_cls_dataset
+
+OPS = pathlib.Path(pkg.__file__).parent / "ops"
+KERNELS = {
+    "flash_attention_fwd": "flash_attention.py",
+    "flash_attention_dq": "flash_attention.py",
+    "flash_attention_dkv": "flash_attention.py",
+    "fused_loss_fwd": "fused_loss.py",
+    "fused_loss_dh": "fused_loss.py",
+    "fused_loss_dw": "fused_loss.py",
+    "fused_adamw": "fused_optim.py",
+    "paged_attention": "paged_attention.py",
+    "int8_matmul": "quant.py",
+}
+
+
+def _pallas_calls():
+    """[(file, line, name or None)] of every ``pl.pallas_call`` in ops/."""
+    out = []
+    for path in sorted(OPS.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                name = next(
+                    (k.value.value for k in node.keywords
+                     if k.arg == "name" and isinstance(k.value, ast.Constant)),
+                    None)
+                out.append((path.name, node.lineno, name))
+    return out
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_pallas_call_is_named(kernel):
+    calls = _pallas_calls()
+    mine = [c for c in calls if c[2] == kernel]
+    assert len(mine) == 1, f"{kernel}: {mine}"  # there, and no two share it
+    assert mine[0][0] == KERNELS[kernel]
+
+
+def test_every_pallas_call_has_a_name_of_its_own():
+    calls = _pallas_calls()
+    assert len(calls) == len(KERNELS)
+    assert all(c[2] for c in calls), [c for c in calls if not c[2]]
+    assert sorted(c[2] for c in calls) == sorted(KERNELS)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        max_seq_len=32, scan_layers=True,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    return ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+
+
+@pytest.fixture(scope="module")
+def trainer():
+    loader = ShardedLoader(make_cls_dataset(), 8, create_mesh({"data": 8}), seed=0)
+    return Trainer(MLP(features=(32, 4)), loader, optax.adam(1e-3),
+                   loss="cross_entropy", seed=0, quiet=True)
+
+
+def _lowered(which, engine, trainer):
+    if which == "chain":
+        return engine._chain.lower(engine.params, engine._state)
+    if which == "prefill":
+        return engine._prefill.lower(
+            engine.params, engine._state, jnp.zeros((1, 8), jnp.int32),
+            5, 0, 0, 4)
+    batch = next(iter(trainer.loader))
+    return trainer.train_step.lower(trainer.state, batch)
+
+
+@pytest.mark.parametrize("which,scope", [
+    ("chain", "kv_cache"), ("chain", "sampling"), ("chain", "layer_scan"),
+    ("prefill", "kv_cache"), ("prefill", "sampling"),
+    ("train", "loss"), ("train", "optimizer"),
+])
+def test_scope_is_in_the_lowered_text(engine, trainer, which, scope):
+    text = _lowered(which, engine, trainer).as_text(debug_info=True)
+    # a scope under differentiation reads jvp(loss), transpose(jvp(loss))
+    assert re.search(rf'[/"(]{scope}\)*/', text), scope
+    if which == "train" and scope == "loss":
+        # the backward pass of the loss carries the scope too
+        assert "transpose(jvp(loss))" in text
+
+
+@pytest.mark.parametrize("which,module", [
+    ("chain", "jit__chain_fn"), ("prefill", "jit__prefill_fn"),
+    ("train", "jit_step_fn"),
+])
+def test_program_names_are_pinned(engine, trainer, which, module):
+    """What the trace's ``XLA Modules`` line and ``hlo_module`` stat carry,
+    and a part of the compile cache's key: renamed by nobody."""
+    assert f"module @{module} " in _lowered(which, engine, trainer).as_text()
