@@ -347,12 +347,29 @@ def _kv_storage(k_dtype, v_dtype, d: int):
     return k_dtype, v_dtype, d, None
 
 
+def _layer_value(var, layer):
+    """What one layer reads of cache variable ``var``: the variable's own
+    value, or — ``layer`` given, the variable being the whole stack the
+    layer scan carries — row ``layer`` of it."""
+    return var.value if layer is None else var.value[layer]
+
+
+def _update_slice(var, val: jax.Array, start: tuple, layer) -> None:
+    """``dynamic_update_slice`` of block ``val`` into cache variable ``var``
+    at ``start`` (at ``[layer, *start]`` of a carried stack)."""
+    if layer is not None:
+        val, start = val[None], (layer,) + start
+    var.value = jax.lax.dynamic_update_slice(var.value, val, start)
+
+
 @jax.named_scope("kv_cache")
-def _store_decode_kv(var, val: jax.Array, pos: jax.Array) -> None:
+def _store_decode_kv(var, val: jax.Array, pos: jax.Array, layer=None) -> None:
     """Write one decode chunk's per-row value ``val`` (B, S, ...) into cache
     variable ``var`` (B, max_seq_len, ...) at sequence positions
     ``pos + [0, S)`` — the one copy of the decode write used by K/V and
-    their int8 scales.
+    their int8 scales. With ``layer`` (the scan's layer index) ``var`` is
+    the carried stack ``(L, B, max_seq_len, ...)`` and the same rows land
+    at ``[layer, ...]``: only the new rows are written, in place.
 
     Scalar ``pos`` with ``S == 1``: every row writes the same position
     (``dynamic_update_slice``, the generate() path — kept as the exact
@@ -366,13 +383,26 @@ def _store_decode_kv(var, val: jax.Array, pos: jax.Array) -> None:
     val = val.astype(var.value.dtype)
     s = val.shape[1]
     if pos.ndim == 0 and s == 1:
-        var.value = jax.lax.dynamic_update_slice(
-            var.value, val, (0, pos) + (0,) * (val.ndim - 2)
-        )
+        _update_slice(var, val, (0, pos) + (0,) * (val.ndim - 2), layer)
     else:
         rows = jnp.arange(val.shape[0])[:, None]  # (B, 1)
         cols = (pos[:, None] if pos.ndim else pos) + jnp.arange(s)  # (B|1, S)
-        var.value = var.value.at[rows, cols].set(val, mode="drop")
+        at = (rows, cols) if layer is None else (layer, rows, cols)
+        var.value = var.value.at[at].set(val, mode="drop")
+
+
+@jax.named_scope("kv_cache")
+def _store_prefill_kv(var, val: jax.Array, layer=None) -> None:
+    """Write a prefill's ``val`` (B, S, ...) into cache variable ``var`` at
+    positions ``[0, S)`` (``[layer, :, 0:S]`` of a carried stack)."""
+    _update_slice(var, val.astype(var.value.dtype), (0,) * val.ndim, layer)
+
+
+@jax.named_scope("kv_cache")
+def _store_cache_index(var, new: jax.Array, layer=None) -> None:
+    """Set a layer's ``cache_index`` (its row of the carried ``(L,)`` /
+    ``(L, B)`` stack when ``layer`` is given)."""
+    var.value = new if layer is None else var.value.at[layer].set(new)
 
 
 def _gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
@@ -404,11 +434,15 @@ def _gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
 
 
 @jax.named_scope("kv_cache")
-def _store_paged_kv(var, table: jax.Array, val: jax.Array, pos) -> None:
+def _store_paged_kv(
+    var, table: jax.Array, val: jax.Array, pos, layer=None
+) -> None:
     """Paged twin of :func:`_store_decode_kv`: write row r's token s of
     ``val`` (B, S, ...) into pool variable ``var`` (kv_pages, page_size,
     ...) at the page/offset the row's ``table`` (B, P) maps logical
-    position ``pos[r] + s`` to.
+    position ``pos[r] + s`` to. With ``layer``, ``var`` is the carried
+    stack of pools ``(L, kv_pages, page_size, ...)`` (``table`` is still
+    this layer's) and the rows land in pool ``layer``.
 
     Logical positions past the table (bucket padding beyond the window)
     and positions whose table entry is the sentinel ``kv_pages`` (parked
@@ -418,7 +452,8 @@ def _store_paged_kv(var, table: jax.Array, val: jax.Array, pos) -> None:
     slot's junk writes land nowhere even after its pages are recycled."""
     val = val.astype(var.value.dtype)
     s = val.shape[1]
-    n_pages, page_size = var.value.shape[0], var.value.shape[1]
+    lead = 0 if layer is None else 1
+    n_pages, page_size = var.value.shape[lead], var.value.shape[lead + 1]
     p_cap = table.shape[1]
     # pos is (B,) by construction (paged decode always runs slot-indexed)
     cols = pos[:, None] + jnp.arange(s)  # (B, S) logical positions
@@ -428,7 +463,8 @@ def _store_paged_kv(var, table: jax.Array, val: jax.Array, pos) -> None:
         table, jnp.clip(p_idx, 0, p_cap - 1), axis=1
     )
     ids = jnp.where(p_idx < p_cap, ids, n_pages)  # past-window -> OOB
-    var.value = var.value.at[ids, offs].set(val, mode="drop")
+    at = (ids, offs) if layer is None else (layer, ids, offs)
+    var.value = var.value.at[at].set(val, mode="drop")
 
 
 def _is_cache_index(path) -> bool:
@@ -561,7 +597,17 @@ class Attention(nn.Module):
         its cache trees at the jit boundaries
         (``parallel.tensor_parallel.SLOT_STATE_RULES`` names these leaf
         paths; ``ServeEngine._pin``). Renaming a cache variable here
-        breaks that rule table — keep them in sync."""
+        breaks that rule table — keep them in sync.
+
+        Under ``scan_layers`` the tree gains a leading layer axis
+        (``cached_key`` ``(L, B, S, KV, D)``, ``cache_index`` ``(L,)`` or
+        ``(L, B)``). An apply that creates the cache declares THIS layer's
+        variables here and the scan stacks them; an apply that was handed
+        the cache carries the stack through the scan, so the variables
+        returned here (they exist: the initializers below do not run) ARE
+        the stack, and ``__call__`` writes and reads them at its
+        ``layer`` index (``_store_decode_kv`` / ``_layer_value``). Same
+        paths, shapes and dtypes either way."""
         cfg = self.cfg
         h, d = cfg.kv_heads, cfg.head_dim
         if cfg.kv_cache_dtype is not None:
@@ -655,8 +701,11 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(
         self, x, decode: bool = False, prefill: bool = False,
-        adapter_ids=None,
+        adapter_ids=None, layer=None,
     ):
+        # ``layer``: this layer's index when the layer scan carries the
+        # stacked cache (TransformerLM) — every cache variable is then the
+        # whole stack, written and read at ``[layer]``; None = own variables
         cfg = self.cfg
         assert not (decode and prefill), "decode and prefill are exclusive"
         h, kv, d = cfg.n_heads, cfg.kv_heads, cfg.head_dim
@@ -722,39 +771,42 @@ class Attention(nn.Module):
                 self._paged_cache_vars(b, k_raw.dtype, v.dtype)
             )
             quant = _kv_quant_mode(cfg.kv_cache_dtype)
-            pos = idx.value  # (B,) — paged decode is always slot-indexed
-            tbl = table.value
+            # (B,) — paged decode is always slot-indexed
+            pos = _layer_value(idx, layer)
+            tbl = _layer_value(table, layer)
             q = apply_rope(q_raw, cfg.rope_theta, offset=pos)
             k = apply_rope(k_raw, cfg.rope_theta, offset=pos)
             k_q, k_s = _encode_kv(k, quant)
             v_q, v_s = _encode_kv(v, quant)
-            _store_paged_kv(cached_k, tbl, k_q, pos)
-            _store_paged_kv(cached_v, tbl, v_q, pos)
+            _store_paged_kv(cached_k, tbl, k_q, pos, layer)
+            _store_paged_kv(cached_v, tbl, v_q, pos, layer)
             if quant:
-                _store_paged_kv(k_scale, tbl, k_s, pos)
-                _store_paged_kv(v_scale, tbl, v_s, pos)
-            idx.value = pos + s
+                _store_paged_kv(k_scale, tbl, k_s, pos, layer)
+                _store_paged_kv(v_scale, tbl, v_s, pos, layer)
+            _store_cache_index(idx, pos + s, layer)
+            k_pool = _layer_value(cached_k, layer)
+            v_pool = _layer_value(cached_v, layer)
+            ks_pool = _layer_value(k_scale, layer) if quant else None
+            vs_pool = _layer_value(v_scale, layer) if quant else None
             if cfg.paged_kernel:
                 from pytorch_distributed_training_tutorials_tpu.ops.paged_attention import (  # noqa: E501
                     paged_attention,
                 )
 
                 out = paged_attention(
-                    q, cached_k.value, cached_v.value, tbl, pos,
-                    k_scale=k_scale.value if quant else None,
-                    v_scale=v_scale.value if quant else None,
-                    quant=quant,
+                    q, k_pool, v_pool, tbl, pos,
+                    k_scale=ks_pool, v_scale=vs_pool, quant=quant,
                 )
             else:
                 with jax.named_scope("kv_cache"):
                     k_read = _decode_kv(
-                        _gather_pages(cached_k.value, tbl),
-                        _gather_pages(k_scale.value, tbl) if quant else None,
+                        _gather_pages(k_pool, tbl),
+                        _gather_pages(ks_pool, tbl) if quant else None,
                         quant, k.dtype,
                     )
                     v_read = _decode_kv(
-                        _gather_pages(cached_v.value, tbl),
-                        _gather_pages(v_scale.value, tbl) if quant else None,
+                        _gather_pages(v_pool, tbl),
+                        _gather_pages(vs_pool, tbl) if quant else None,
                         quant, v.dtype,
                     )
                 qpos = pos[..., None] + jnp.arange(s)
@@ -786,27 +838,29 @@ class Attention(nn.Module):
             # (B,) for slot-indexed serving (serve/: each slot decodes at
             # its own depth); apply_rope, _store_decode_kv, and the
             # validity mask all branch on the trace-time rank
-            pos = idx.value
+            pos = _layer_value(idx, layer)
             quant = _kv_quant_mode(cfg.kv_cache_dtype)
             q = apply_rope(q_raw, cfg.rope_theta, offset=pos)
             k = apply_rope(k_raw, cfg.rope_theta, offset=pos)
             k_q, k_s = _encode_kv(k, quant)  # quantized: store q + scale
             v_q, v_s = _encode_kv(v, quant)
-            _store_decode_kv(cached_k, k_q, pos)
-            _store_decode_kv(cached_v, v_q, pos)
+            _store_decode_kv(cached_k, k_q, pos, layer)
+            _store_decode_kv(cached_v, v_q, pos, layer)
             if quant:
-                _store_decode_kv(k_scale, k_s, pos)
-                _store_decode_kv(v_scale, v_s, pos)
+                _store_decode_kv(k_scale, k_s, pos, layer)
+                _store_decode_kv(v_scale, v_s, pos, layer)
             with jax.named_scope("kv_cache"):
                 k_read = _decode_kv(
-                    cached_k.value, k_scale.value if quant else None,
+                    _layer_value(cached_k, layer),
+                    _layer_value(k_scale, layer) if quant else None,
                     quant, k.dtype,
                 )
                 v_read = _decode_kv(
-                    cached_v.value, v_scale.value if quant else None,
+                    _layer_value(cached_v, layer),
+                    _layer_value(v_scale, layer) if quant else None,
                     quant, v.dtype,
                 )
-            idx.value = pos + s
+            _store_cache_index(idx, pos + s, layer)
             # attend over the whole cache: query token i (global position
             # pos + i) masks positions beyond pos + i — same math as
             # training/prefill (a masked-out cache column contributes an
@@ -841,23 +895,12 @@ class Attention(nn.Module):
                 quant = _kv_quant_mode(cfg.kv_cache_dtype)
                 k_q, k_s = _encode_kv(k, quant)  # quantized cache: q+scale
                 v_q, v_s = _encode_kv(v, quant)
-                with jax.named_scope("kv_cache"):
-                    cached_k.value = jax.lax.dynamic_update_slice(
-                        cached_k.value, k_q.astype(cached_k.value.dtype),
-                        (0, 0, 0, 0)
-                    )
-                    cached_v.value = jax.lax.dynamic_update_slice(
-                        cached_v.value, v_q.astype(cached_v.value.dtype),
-                        (0, 0, 0, 0)
-                    )
-                    if quant:
-                        k_scale.value = jax.lax.dynamic_update_slice(
-                            k_scale.value, k_s, (0, 0, 0)
-                        )
-                        v_scale.value = jax.lax.dynamic_update_slice(
-                            v_scale.value, v_s, (0, 0, 0)
-                        )
-                idx.value = jnp.asarray(s, jnp.int32)
+                _store_prefill_kv(cached_k, k_q, layer)
+                _store_prefill_kv(cached_v, v_q, layer)
+                if quant:
+                    _store_prefill_kv(k_scale, k_s, layer)
+                    _store_prefill_kv(v_scale, v_s, layer)
+                _store_cache_index(idx, jnp.asarray(s, jnp.int32), layer)
             attn = (
                 cfg.attention_fn
                 if cfg.attention_fn is not None
@@ -973,12 +1016,12 @@ class Block(nn.Module):
     @nn.compact
     def __call__(
         self, x, decode: bool = False, prefill: bool = False,
-        adapter_ids=None,
+        adapter_ids=None, layer=None,
     ):
         cfg = self.cfg
         x = x + Attention(cfg, name="attn")(
             RMSNorm(cfg.norm_eps, name="attn_norm")(x), decode=decode,
-            prefill=prefill, adapter_ids=adapter_ids,
+            prefill=prefill, adapter_ids=adapter_ids, layer=layer,
         )
         if cfg.moe_experts > 0:
             # MoE blocks carry no LoRA hooks (TransformerLM rejects the
@@ -1006,12 +1049,15 @@ class _ScanCell(nn.Module):
     prefill: bool = False
 
     @nn.compact
-    def __call__(self, x, ids):
+    def __call__(self, x, ids, layer):
         # ``ids`` is the scan's nn.broadcast input: the per-row adapter-id
         # vector handed WHOLE to every layer (None when lora is off — an
-        # empty pytree, so the scanned program is unchanged)
+        # empty pytree, so the scanned program is unchanged). ``layer`` is
+        # the scanned layer index when the scan carries the cache, else
+        # None (also an empty pytree)
         return Block(self.cfg, name="block")(
-            x, decode=self.decode, prefill=self.prefill, adapter_ids=ids
+            x, decode=self.decode, prefill=self.prefill, adapter_ids=ids,
+            layer=layer,
         ), None
 
 
@@ -1074,25 +1120,44 @@ class TransformerLM(nn.Module):
                 cell = nn.remat(
                     cell, prevent_cse=False, policy=_remat_policy(cfg)
                 )
+            # An apply that was HANDED a cache carries the stacked tree
+            # through the scan whole: each layer gets its index, writes only
+            # its new rows at [layer] (in place on the carried buffer) and
+            # reads cache[layer] (Attention's ``layer``). Scanning over the
+            # cache instead makes it a scanned input AND a scanned output —
+            # two buffers — so every layer's whole slice is copied out and
+            # stacked back on every step. An apply that CREATES its cache
+            # (init, a prefill with no cache in, the eval_shape protos) has
+            # to scan over it, because flax cannot create a variable in a
+            # carried collection inside the scan; the stacked tree it
+            # returns has the same paths, shapes and dtypes.
+            carry_cache = "layers" in self.variables.get("cache", {})
+            axes = {"params": 0, "losses": 0}
+            if not carry_cache:
+                axes["cache"] = 0
             stack = nn.scan(
                 cell,
                 # 'losses' rides along axis 0 so per-layer sown values (MoE
                 # load balancing) survive the scan instead of being dropped;
-                # 'cache' stacks each layer's KV cache the same way; the
-                # adapter-id vector (or None) broadcasts to every layer
-                variable_axes={"params": 0, "losses": 0, "cache": 0},
+                # the adapter-id vector (or None) broadcasts to every layer
+                variable_axes=axes,
+                variable_carry="cache" if carry_cache else False,
                 split_rngs={"params": True},
-                in_axes=nn.broadcast,
+                in_axes=(nn.broadcast, 0),
                 length=cfg.n_layers,
             )(cfg, decode, prefill, name="layers")
             # the scope marks what lax.scan itself does around the cell:
-            # it slices every stacked leaf (each layer's weights, its cache
-            # slice) out by the layer index and stacks the new cache back.
-            # No line of the program does that, so no narrower scope
-            # (weights_slice / kv_cache) can be put on it; an op under
+            # it slices every stacked leaf (each layer's weights, a scanned
+            # cache's slice) out by the layer index and stacks a scanned
+            # cache back. No line of the program does that, so no narrower
+            # scope (weights_slice / kv_cache) can be put on it; an op under
             # layer_scan and not under the cell's "layers" is that slicing.
+            # (A carried cache's reads and writes are the program's own
+            # lines, under layers/block/attn/kv_cache.)
             with jax.named_scope("layer_scan"):
-                x, _ = stack(x, ids)
+                x, _ = stack(
+                    x, ids, jnp.arange(cfg.n_layers) if carry_cache else None
+                )
         else:
             # decode/prefill are Python bools steering cache behavior — they
             # must stay static under remat (args 2/3 of __call__ incl. self)

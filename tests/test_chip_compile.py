@@ -16,7 +16,9 @@ this one file.
 """
 
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -230,3 +232,126 @@ def test_int8_matmul_tp_compiles_on_a_mesh(tp_mesh, kind, monkeypatch):
         .lower(x, w).compile().as_text()
     )
     assert "tpu_custom_call" in hlo
+
+
+CHAT = dict(  # benchmark/configs/internlm2-1.8b.json, serve mode
+    vocab_size=92544, d_model=2048, n_layers=24, n_heads=16, n_kv_heads=8,
+    d_ff=8192, max_seq_len=2048, rope_theta=1e6, norm_eps=1e-5,
+    dtype=jnp.float32, scan_layers=True, kv_cache_dtype=jnp.bfloat16,
+)
+CHAT_SLOTS = 32
+
+
+def _update_operands(hlo: str) -> dict[str, int]:
+    """Shape -> element count of the update operand of every
+    ``dynamic-update-slice`` in an optimized HLO text (operands are named,
+    not typed, there: each is looked up among its own computation's
+    instructions)."""
+    out = {}
+    for comp in hlo.split("\n\n"):
+        shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", comp))
+        for update in re.findall(
+            r" dynamic-update-slice\(%[\w.\-]+, %([\w.\-]+)", comp
+        ):
+            dims = re.findall(r"\d+", shapes[update].split("[")[1])
+            out[shapes[update]] = math.prod(int(d) for d in dims)
+    return out
+
+
+@pytest.mark.parametrize(
+    "options, program, leaf, temp_share",
+    [
+        ({}, "_chain_fn", "cached_key", 1 / 24),  # < K + V of one layer
+        ({"kv_bits": 8}, "_chain_fn", "cached_key", 1 / 8),
+        # the compiler changes the layout of the whole u8[..., 8, 64] stack
+        # at the program's entry and back at its exit: four copies a
+        # launch (six, and 6.7 times the cache, when the scan ran over it)
+        ({"kv_bits": 4}, "_chain_fn", "cached_key", 2.5),
+        (dict(paged=True, page_size=128, pool_pages=512), "_chain_fn",
+         "paged_key", 1 / 8),
+        (dict(paged=True, page_size=128, pool_pages=512, paged_kernel=True),
+         "_chain_fn", "paged_key", 1 / 8),
+        ({"speculative_k": 3}, "_spec_chain_fn", "cached_key", 1 / 24),
+    ],
+    ids=["bf16", "int8", "int4", "paged", "paged_kernel", "speculative"],
+)
+def test_serve_chain_carries_the_cache_in_place(
+    one_chip, monkeypatch, options, program, leaf, temp_share
+):
+    """``ServeEngine``'s decode chain at the chat cell's widths (24 layers,
+    32 slots x 2048 positions, 8 KV heads of 128, int8 weights), over the
+    cache's storages (bf16 as the cell runs it, int8 and int4 with their
+    scales, the paged pool of the same bytes read by gather and by the
+    kernel) and the speculative chain: the layer scan carries the stacked
+    cache, so the compiled program writes a layer's new rows into it in
+    place. Scanning over the cache instead copies every layer's whole
+    slice back into the stack on every decode step
+    (``bitcast_dynamic-update-slice_fusion`` with a ``bf16[32,2048,8,128]``
+    update, half of the chat cell's device time before ISSUE 28): this is
+    the test that fails if that comes back. Nothing is allocated at that
+    size: params and the engine's slot state are shapes."""
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+        quantize_lm_params,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+    from pytorch_distributed_training_tutorials_tpu.serve import (
+        engine as engine_module,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve.slots import (
+        init_slot_state,
+    )
+
+    # int8_matmul asks the backend whether to interpret its kernel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the engine's own 32-slot state, as shapes
+    monkeypatch.setattr(
+        engine_module, "init_slot_state",
+        lambda model, params, *a, **kw: jax.eval_shape(
+            lambda p: init_slot_state(model, p, *a, **kw), params
+        ),
+    )
+    float_model = TransformerLM(TransformerConfig(**CHAT))
+    model = TransformerLM(TransformerConfig(**CHAT, quantized=True))
+    params = jax.eval_shape(
+        lambda key: quantize_lm_params(
+            float_model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+        ),
+        jax.random.PRNGKey(0),
+    )
+    engine = ServeEngine(
+        model, params, n_slots=CHAT_SLOTS, tokens_per_launch=8, **options
+    )
+    state = engine._state
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (params, state),
+    )
+    compiled = (
+        jax.jit(getattr(engine, program), donate_argnums=(1,))
+        .lower(*placed).compile()
+    )
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo  # the int8 kernels, not their emulation
+
+    attn = state["cache"]["layers"]["block"]["attn"]
+    kv = attn[leaf]
+    assert kv.shape[0] == 24 and kv.size // kv.shape[-1] == 24 * 32 * 2048 * 8
+    # the smallest layer slice of a stacked leaf that holds rows of
+    # positions (K, V, their scales); cache_index and page_table are a
+    # number a slot or a page
+    layer_slice = min(
+        v.size // v.shape[0] for v in attn.values() if v.ndim >= 4
+    )
+    sizes = _update_operands(hlo)
+    assert sizes  # the reader still finds the instruction it looks for
+    assert all(n < layer_slice for n in sizes.values()), sizes
+    # the state is donated into the result: K and V are not allocated twice
+    cache_bytes = sum(
+        v.size * v.dtype.itemsize for v in attn.values() if v.ndim >= 4
+    )
+    analysis = compiled.memory_analysis()
+    assert analysis.alias_size_in_bytes >= cache_bytes
+    # nor copied whole inside (half of it is all of K)
+    assert analysis.temp_size_in_bytes < temp_share * cache_bytes

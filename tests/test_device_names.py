@@ -115,6 +115,24 @@ def test_scope_is_in_the_lowered_text(engine, trainer, which, scope):
         assert "transpose(jvp(loss))" in text
 
 
+@pytest.mark.parametrize("which,op", [
+    # the chain is handed its cache: the layer scan carries it, and a layer's
+    # write of its new rows and its read of cache[layer] are lines of the
+    # program under the cell's module path (ISSUE 28)
+    ("chain", "scatter"), ("chain", "dynamic_slice"),
+    # the prefill creates its cache: the scan stacks each layer's own
+    ("prefill", "dynamic_update_slice"),
+])
+def test_cache_ops_keep_the_module_path_under_the_scan(
+        engine, trainer, which, op):
+    """``layers/block/attn/kv_cache/<op>`` inside ``layer_scan``: what
+    ``benchmark/lib/program_trace.py`` splits a step by, whichever way the
+    scan treats the cache."""
+    text = _lowered(which, engine, trainer).as_text(debug_info=True)
+    assert f'"layers/block/attn/kv_cache/{op}"' in text
+    assert re.search(r'[/"(]layer_scan\)*/', text)
+
+
 @pytest.mark.parametrize("which,module", [
     ("chain", "jit__chain_fn"), ("prefill", "jit__prefill_fn"),
     ("train", "jit_step_fn"),
