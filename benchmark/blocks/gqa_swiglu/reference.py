@@ -1,14 +1,15 @@
-"""Plain reference: a pre-RMSNorm, rotary, grouped-query, SwiGLU decoder
-with an untied head, its loss, its gradients and AdamW, in float32
-``jax.numpy``.
+"""Block ``gqa_swiglu``, plain reference: a pre-RMSNorm, rotary,
+grouped-query, SwiGLU decoder with an untied head, its loss and its
+gradients in float32 ``jax.numpy``, the tree of its leaves and its analytic
+operation counts.
 
 Written from the published description of the block (InternLM2 and Mistral
 share it: ``x + Attn(RMSNorm(x))``, ``x + SwiGLU(RMSNorm(x))``, rotary
 embedding in the half-split ``rotate_half`` convention of both models'
 public code, K/V heads shared by groups of query heads, no bias, a final
 RMSNorm and an untied output head). It imports nothing of the program and
-takes nothing the program has made: weights come from
-``benchmark/lib/weights.py`` in this file's own layout.
+takes nothing the program has made: ``benchmark/lib/weights.py`` makes the
+weights from :func:`leaf_shapes`, in this file's own layout.
 
 Every matrix product runs at ``Precision.HIGHEST`` (on a TPU a float32
 product is otherwise one bfloat16 pass). One sequence at a time, layers
@@ -30,6 +31,17 @@ Parameter layout (``L`` layers stacked on the leading axis)::
 
 A serving weight is ``{"q": int8 (..., K, N), "scale": f32 (..., 1, N)}``
 in place of the float array, standing for ``q * scale``.
+
+Operation counts are what the mathematics needs, whatever computes it.
+Recomputed operations (rematerialization, a flash backward's second pass
+over the scores) are not counted. PaLM's convention (appendix B): 2
+operations a multiply-add, 6 N a trained token for N matrix parameters, and
+12 L H hd S for the attention scores and their weighted sums over a full
+S x S square; serving counts the causal triangle it really needs.
+
+Another block's reference may import this file's pieces
+(``from benchmark.blocks.gqa_swiglu import reference``): ``linear``,
+``rms_norm``, ``rope``, ``attention_sublayer`` and ``grad_fn(..., loss=)``.
 """
 
 from __future__ import annotations
@@ -41,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+MODES = ("train", "serve")  # the cells this block can stand behind
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,7 +102,7 @@ def _weight(w, weight_bits: int):
     return q * w["scale"]
 
 
-def _linear(x, w, precision: str, weight_bits: int):
+def linear(x, w, precision: str, weight_bits: int):
     w = _weight(w, weight_bits)
     if precision == "int8":
         x, w = _fake_int8(x, -1), _fake_int8(w, 0)
@@ -136,10 +149,9 @@ def _attention(q, k, v):
     return out.transpose(1, 0, 2, 3).reshape(s, -1)
 
 
-def _block(x, lp, shape: Shape, precision: str, weight_bits: int):
-    lin = functools.partial(
-        _linear, precision=precision, weight_bits=weight_bits
-    )
+def attention_sublayer(x, lp, shape, lin):
+    """``x + Attn(RMSNorm(x))`` of one sequence (S, d); ``lin`` is
+    :func:`linear` with its controls bound."""
     s = x.shape[0]
     h, kv, hd = (
         shape.num_attention_heads, shape.num_key_value_heads, shape.head_dim
@@ -148,7 +160,14 @@ def _block(x, lp, shape: Shape, precision: str, weight_bits: int):
     q = rope(lin(y, lp["wq"]).reshape(s, h, hd), shape.rope_theta)
     k = rope(lin(y, lp["wk"]).reshape(s, kv, hd), shape.rope_theta)
     v = lin(y, lp["wv"]).reshape(s, kv, hd)
-    x = x + lin(_attention(q.reshape(s, kv, h // kv, hd), k, v), lp["wo"])
+    return x + lin(_attention(q.reshape(s, kv, h // kv, hd), k, v), lp["wo"])
+
+
+def _block(x, lp, shape: Shape, precision: str, weight_bits: int):
+    lin = functools.partial(
+        linear, precision=precision, weight_bits=weight_bits
+    )
+    x = attention_sublayer(x, lp, shape, lin)
     y = rms_norm(x, lp["mlp_norm"], shape.rms_norm_eps)
     gated = jax.nn.silu(lin(y, lp["w_gate"])) * lin(y, lp["w_up"])
     return x + lin(gated, lp["w_down"])
@@ -172,7 +191,7 @@ def logits(
     x = hidden(params, tokens, shape, precision, weight_bits)
     if positions is not None:
         x = x[positions]
-    return _linear(x, params["head"], precision, weight_bits)
+    return linear(x, params["head"], precision, weight_bits)
 
 
 def sequence_loss(params, tokens, targets, shape: Shape, precision="float32"):
@@ -182,17 +201,19 @@ def sequence_loss(params, tokens, targets, shape: Shape, precision="float32"):
     return jnp.mean(lse - jnp.take_along_axis(lg, targets[:, None], 1)[:, 0])
 
 
-def grad_fn(shape: Shape, precision="float32", placement=None):
+def grad_fn(shape: Shape, precision="float32", placement=None,
+            loss=sequence_loss):
     """``fn(params, tokens, targets, rows=None)`` -> (mean loss over the
     batch rows, its gradients), a row at a time. ``rows`` limits the mean
     to those rows (the half-batch fault of the tests). ``placement`` is a
     tree of shardings for the gradients, where the parameters are spread
     over several chips because one cannot hold them: where a leaf lies,
-    not what is computed."""
+    not what is computed. ``loss`` is the block's own
+    ``(params, tokens, targets, shape, precision)`` of one sequence."""
     kw = {} if placement is None else {"out_shardings": (None, placement)}
     step = jax.jit(
         jax.value_and_grad(
-            lambda p, x, y: sequence_loss(p, x, y, shape, precision)), **kw
+            lambda p, x, y: loss(p, x, y, shape, precision)), **kw
     )
     add = jax.jit(
         lambda a, b: jax.tree_util.tree_map(jnp.add, a, b), donate_argnums=0
@@ -205,8 +226,8 @@ def grad_fn(shape: Shape, precision="float32", placement=None):
         rows = range(tokens.shape[0]) if rows is None else rows
         total, grads = 0.0, None
         for r in rows:
-            loss, g = step(params, tokens[r], targets[r])
-            total += float(loss)
+            loss_r, g = step(params, tokens[r], targets[r])
+            total += float(loss_r)
             grads = g if grads is None else add(grads, g)
         n = len(rows)
         return total / n, scale(grads, jnp.float32(n))
@@ -214,29 +235,66 @@ def grad_fn(shape: Shape, precision="float32", placement=None):
     return fn
 
 
-def loss_and_grads(params, tokens, targets, shape: Shape, precision="float32",
-                   rows=None):
-    """One call of :func:`grad_fn`."""
-    return grad_fn(shape, precision)(params, tokens, targets, rows)
+def leaf_shapes(shape: Shape) -> dict:
+    """name -> (dims, kind) with kind ``matrix``, ``norm`` or ``embed``."""
+    d, ff, L = shape.hidden_size, shape.intermediate_size, shape.num_hidden_layers
+    q = shape.num_attention_heads * shape.head_dim
+    kv = shape.num_key_value_heads * shape.head_dim
+    layers = {
+        "attn_norm": ((L, d), "norm"),
+        "wq": ((L, d, q), "matrix"),
+        "wk": ((L, d, kv), "matrix"),
+        "wv": ((L, d, kv), "matrix"),
+        "wo": ((L, q, d), "matrix"),
+        "mlp_norm": ((L, d), "norm"),
+        "w_gate": ((L, d, ff), "matrix"),
+        "w_up": ((L, d, ff), "matrix"),
+        "w_down": ((L, ff, d), "matrix"),
+    }
+    return {
+        "embed": ((shape.vocab_size, d), "embed"),
+        "layers": layers,
+        "final_norm": ((d,), "norm"),
+        "head": ((d, shape.vocab_size), "matrix"),
+    }
 
 
-@functools.partial(jax.jit, static_argnums=(5,), donate_argnums=(0, 2, 3))
-def adamw(params, grads, mu, nu, count, hyper: tuple):
-    """One AdamW step (Loshchilov & Hutter; decay on every leaf, as the
-    configuration's train options state). ``hyper`` is
-    ``(lr, b1, b2, eps, weight_decay)``; ``count`` is the step just taken,
-    from 1."""
-    lr, b1, b2, eps, wd = hyper
-    t = count.astype(jnp.float32)
+def matmul_params(shape: Shape) -> dict:
+    """Matrix parameters that multiply a token's activations: a layer's
+    projections and feed-forward, and the output head. The embedding is a
+    lookup and does no multiplication."""
+    d, ff = shape.hidden_size, shape.intermediate_size
+    q = shape.num_attention_heads * shape.head_dim
+    kv = shape.num_key_value_heads * shape.head_dim
+    layer = d * (q + 2 * kv) + q * d + 3 * d * ff
+    return {
+        "layer": layer,
+        "layers": layer * shape.num_hidden_layers,
+        "head": d * shape.vocab_size,
+        "embedding": d * shape.vocab_size,
+        "norms": d * (2 * shape.num_hidden_layers + 1),
+    }
 
-    def leaf(p, g, m, n):
-        m = b1 * m + (1 - b1) * g
-        n = b2 * n + (1 - b2) * g * g
-        step = (m / (1 - b1**t)) / (jnp.sqrt(n / (1 - b2**t)) + eps)
-        return p - lr * (step + wd * p), m, n
 
-    out = jax.tree_util.tree_map(leaf, params, grads, mu, nu)
-    pick = lambda i: jax.tree_util.tree_map(  # noqa: E731
-        lambda _, o: o[i], params, out
-    )
-    return pick(0), pick(1), pick(2)
+def total_params(shape: Shape) -> int:
+    p = matmul_params(shape)
+    return p["layers"] + p["head"] + p["embedding"] + p["norms"]
+
+
+def train_flops_per_token(shape: Shape, seq_len: int) -> float:
+    """Forward and backward of one token in a sequence of ``seq_len``."""
+    p = matmul_params(shape)
+    attn = (12 * shape.num_hidden_layers * shape.num_attention_heads
+            * shape.head_dim * seq_len)
+    return 6.0 * (p["layers"] + p["head"]) + attn
+
+
+def serve_flops(shape: Shape, prompt_len: int, new_tokens: int) -> float:
+    """One request: its prompt and all but the last generated token pass
+    through the layers, each attending to what precedes it; the head is
+    applied once for each generated token."""
+    p = matmul_params(shape)
+    through = prompt_len + new_tokens - 1
+    attn = (4 * shape.num_hidden_layers * shape.num_attention_heads
+            * shape.head_dim * through * (through + 1) / 2)
+    return 2.0 * p["layers"] * through + 2.0 * p["head"] * new_tokens + attn

@@ -5,6 +5,7 @@ that decide ``correct``.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -23,6 +24,37 @@ def read_json(path: str) -> dict:
         return json.load(f)
 
 
+DEFAULT_BLOCK = "gqa_swiglu"  # of a configuration whose file names none
+
+
+class Block:
+    """``benchmark/blocks/<name>/``: a configuration's file names it under
+    ``"block"``. ``reference`` is the block's plain reference with its
+    leaves and operation counts, ``program`` the model and the weights'
+    mapping in the program; both are loaded when first asked for."""
+
+    def __init__(self, name: str, root: str = ROOT):
+        self.name = name
+        self.dir = os.path.join(root, "benchmark", "blocks", name)
+        if not os.path.isdir(self.dir):
+            raise SystemExit(f"no block {name!r}: {self.dir} is not there")
+
+    @functools.cached_property
+    def reference(self):
+        return load_module(os.path.join(self.dir, "reference.py"))
+
+    @functools.cached_property
+    def program(self):
+        return load_module(os.path.join(self.dir, "program.py"))
+
+    def needs(self, mode: str) -> None:
+        """Exit where the block has no ``mode`` (``train`` or ``serve``)."""
+        if mode not in self.reference.MODES:
+            raise SystemExit(
+                f"block {self.name!r} has no {mode!r} mode: it has "
+                f"{list(self.reference.MODES)}")
+
+
 class Cell:
     """One entry of ``BENCHMARK.json``'s workloads with its files."""
 
@@ -39,6 +71,7 @@ class Cell:
             c for c in self.spec["configs"] if c["name"] == self.workload["config"]
         )
         self.config = read_json(os.path.join(root, entry["file"]))
+        self.block = Block(self.config.get("block", DEFAULT_BLOCK), root)
         self.traffic = read_json(
             os.path.join(root, "benchmark", "traffic",
                          self.workload["traffic"] + ".json")
@@ -177,9 +210,12 @@ class Tracer:
 
 
 def load_module(path: str):
-    name = "bench_" + os.path.basename(path).replace(".", "_")
+    """The file as a module of its own, found by path and entered in
+    ``sys.modules``: ``dataclasses`` looks a class's module up there."""
+    name = "bench_" + "_".join(path.split(os.sep)[-3:]).replace(".", "_")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 

@@ -4,8 +4,8 @@
 The window drives the engine that set-up built and warmed (one request for
 each prompt bucket the mix reaches). Once the window has closed and every
 request due in it has been waited for, a sample of the finished requests,
-drawn from the seed with the longest in it, goes to
-``benchmark/reference.py``: one pass over each prompt with its served
+drawn from the seed with the longest in it, goes to the block's plain
+reference (``benchmark/blocks/<block>/reference.py``): one pass over each prompt with its served
 tokens, and the number compared is the widest gap by which a served
 token's logit lies below the reference's best, in units of the deviation
 of that position's logits.
@@ -36,16 +36,16 @@ def build_engine(cell, seed: int):
     import jax
     import jax.numpy as jnp
 
-    from ..reference import Shape
-
-    cfg = cell.config
-    shape = Shape.from_config(cfg)
+    cfg, block = cell.config, cell.block
+    block.needs("serve")
+    shape = block.reference.Shape.from_config(cfg)
     serve = cfg["serve"]
-    model = program.model_config(cfg, "serve", serve["window"])
+    model = block.program.model(cfg, "serve", serve["window"])
     ref_params = weights.make(
-        shape, seed, serve["weights_dtype"], cfg["initializer_range"]
+        block.reference.leaf_shapes(shape), seed, serve["weights_dtype"],
+        cfg["initializer_range"]
     )
-    params = program.to_program(ref_params, shape)
+    params = block.program.to_program(ref_params, shape)
     theirs = jax.eval_shape(
         model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )["params"]
@@ -256,7 +256,8 @@ def run(cell, args, log, tracer, fault=None) -> dict:
     del driver, engine, plan, due, in_window, finished, sample
     gc.collect()
     return {
-        "kind": mix["kind"], "shape": shape, "setup_s": setup_s,
+        "kind": mix["kind"], "block": cell.block, "shape": shape,
+        "setup_s": setup_s,
         "window_s": window_s, "attempted": len(ttft),
         "failed": sum(1 for x in ttft if x >= DRAIN_SECONDS),
         "end_to_end": end_to_end,
@@ -282,15 +283,15 @@ def run(cell, args, log, tracer, fault=None) -> dict:
     }
 
 
-def token_gaps(shape, ref_params, served, window: int, weight_bits: int = 8,
-               max_out: int = 0):
+def token_gaps(block, shape, ref_params, served, window: int,
+               weight_bits: int = 8, max_out: int = 0):
     """For each sampled request the widest gap of its tokens, and the count
     compared. With ``weight_bits=4`` the tokens judged are not the served
     ones but those the int4 control puts first at the same positions."""
     import jax
     import jax.numpy as jnp
 
-    from .. import reference
+    reference = block.reference
 
     # one shape whatever the sample: one program to compile
     max_out = max(max_out, max(len(t) for _, t in served))
@@ -334,7 +335,7 @@ def decide(cell, args, bundle: dict, checks) -> dict:
     detail = {}
     if proof["served"]:
         worst, compared = token_gaps(
-            bundle["shape"], proof["ref_params"], proof["served"],
+            bundle["block"], bundle["shape"], proof["ref_params"], proof["served"],
             cell.config["serve"]["window"],
             max_out=cell.traffic["output"]["max"],
         )

@@ -3,7 +3,7 @@ names where the cells it is read in report different end-to-end metrics."""
 
 from __future__ import annotations
 
-from . import flops, harness, roofline, xplane
+from . import harness, roofline, xplane
 
 
 def slot_occupancy(bundle):
@@ -23,7 +23,8 @@ def mfu(bundle):
     done = bundle["counters"].get("done_lengths")
     if not done or bundle["peaks"] is None:
         return None
-    ops = sum(flops.serve_flops(bundle["shape"], p, n) for p, n in done)
+    serve_flops = bundle["block"].reference.serve_flops
+    ops = sum(serve_flops(bundle["shape"], p, n) for p, n in done)
     dtype = bundle["cell"].config["serve"]["matmul_dtype"]
     peak = bundle["peaks"]["flops_per_s"][dtype] * bundle["device"]["count"]
     return 100.0 * ops / bundle["window_s"] / peak

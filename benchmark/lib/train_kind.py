@@ -5,7 +5,8 @@ passed.
 Set-up builds one ``Trainer``, gives it the benchmark's weights, and drives
 it through its first epoch by the window's own call; the same object then
 runs the window. From that first epoch come the numbers ``correct``
-compares with ``benchmark/reference.py``: the loss of steps 1 to 3, the
+compares with the block's plain reference
+(``benchmark/blocks/<block>/reference.py``): the loss of steps 1 to 3, the
 norm of each leaf's first gradient (Adam's first moment after one step,
 over ``1 - b1``) and the norm of each leaf's change after three steps.
 """
@@ -19,7 +20,7 @@ import time
 
 import numpy as np
 
-from . import harness, program, traffic as traffic_lib, weights
+from . import adamw, harness, program, traffic as traffic_lib, weights
 
 PROOF_STEPS = 3
 
@@ -68,22 +69,20 @@ def _moment(opt_state):
     return found[0].mu
 
 
-def leaf_norms(tree: dict) -> dict:
-    """Norm of every leaf of a reference-layout tree, a layer at a time:
-    ``{"embed": (), "layers/wq": (L,), ...}``."""
+def leaf_norms(tree: dict, prefix: str = "") -> dict:
+    """Norm of every leaf of a reference-layout tree; the leaves of a
+    group (a dict inside the tree: layers stacked on the leading axis) an
+    index at a time: ``{"embed": (), "layers/wq": (L,), ...}``."""
     import jax.numpy as jnp
 
     out = {}
     for name, leaf in tree.items():
-        if name == "layers":
-            for sub, x in leaf.items():
-                x = x.astype(jnp.float32)
-                out["layers/" + sub] = jnp.sqrt(
-                    jnp.sum(x * x, axis=tuple(range(1, x.ndim)))
-                )
-        else:
-            x = leaf.astype(jnp.float32)
-            out[name] = jnp.sqrt(jnp.sum(x * x))
+        if isinstance(leaf, dict):
+            out.update(leaf_norms(leaf, prefix + name + "/"))
+            continue
+        x = leaf.astype(jnp.float32)
+        axes = tuple(range(1, x.ndim)) if prefix else None
+        out[prefix + name] = jnp.sqrt(jnp.sum(x * x, axis=axes))
     return out
 
 
@@ -114,7 +113,7 @@ def worst_gap(ours: dict, ref: dict, skip=()) -> tuple[float, str]:
     return worst, where
 
 
-def spread(shape):
+def spread(spec: dict):
     """Where the reference's leaves lie when one chip cannot hold them
     (None on one device): each leaf split over all devices along its
     longest axis that divides evenly."""
@@ -135,40 +134,39 @@ def spread(shape):
         return NamedSharding(mesh, PartitionSpec(*spec))
 
     return jax.tree_util.tree_map(
-        place, weights.leaf_shapes(shape), is_leaf=lambda x: isinstance(x, tuple))
+        place, spec, is_leaf=lambda x: isinstance(x, tuple))
 
 
-def reference_steps(shape, seed, std, batches, hyper, precision="float32",
-                    rows=None, skip_update=False):
-    """The reference through the first steps: (losses, first gradient's
-    norms, norms of the change after the last step)."""
+def reference_steps(block, shape, seed, std, batches, hyper,
+                    precision="float32", rows=None, skip_update=False):
+    """The block's reference through the first steps: (losses, first
+    gradient's norms, norms of the change after the last step)."""
     import jax
     import jax.numpy as jnp
 
-    from .. import reference
-
-    placement = spread(shape)
-    params = weights.make(shape, seed, "float32", std, out_shardings=placement)
+    spec = block.reference.leaf_shapes(shape)
+    placement = spread(spec)
+    params = weights.make(spec, seed, "float32", std, out_shardings=placement)
     kw = {} if placement is None else {"out_shardings": placement}
     zeros = jax.jit(lambda t: jax.tree_util.tree_map(jnp.zeros_like, t), **kw)
     mu, nu = zeros(params), zeros(params)
     losses, grad_norms = [], None
     norms = jax.jit(leaf_norms)
     hyp = tuple(hyper[k] for k in ("learning_rate", "b1", "b2", "eps", "weight_decay"))
-    grad = reference.grad_fn(shape, precision, placement)
+    grad = block.reference.grad_fn(shape, precision, placement)
     for i, (tokens, targets) in enumerate(batches):
         loss, grads = grad(params, jnp.asarray(tokens), jnp.asarray(targets), rows)
         losses.append(loss)
         if grad_norms is None:
             grad_norms = flatten_norms(jax.device_get(norms(grads)))
         if not skip_update:
-            params, mu, nu = reference.adamw(
+            params, mu, nu = adamw.adamw(
                 params, grads, mu, nu, jnp.asarray(i + 1, jnp.int32), hyp
             )
         del grads
     change = jax.jit(
         lambda p, key: leaf_norms(jax.tree_util.tree_map(
-            jnp.subtract, p, weights.build(shape, key, "float32", std)))
+            jnp.subtract, p, weights.build(spec, key, "float32", std)))
     )(params, weights.seed_key(seed))
     return losses, grad_norms, flatten_norms(jax.device_get(change))
 
@@ -201,16 +199,16 @@ def run(cell, args, log, tracer, fault=None) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from ..reference import Shape
-
-    cfg, mix = cell.config, cell.traffic
-    shape = Shape.from_config(cfg)
+    cfg, mix, block = cell.config, cell.traffic, cell.block
+    block.needs("train")
+    shape = block.reference.Shape.from_config(cfg)
+    spec = block.reference.leaf_shapes(shape)
     std = cfg["initializer_range"]
     hyper = mix["adamw"]
     counters = {"input_wait_s": 0.0, "batches": 0}
 
     strat = program.strategy(cfg["train"]["strategy"])
-    model = program.model_config(cfg, "train", mix["seq_len"])
+    model = block.program.model(cfg, "train", mix["seq_len"])
     arrays = traffic_lib.train_tokens(mix, shape.vocab_size, args.seed)
     loader = TimedLoader(
         program.sharded_loader(arrays, mix["batch"], strat.mesh, args.seed),
@@ -223,15 +221,15 @@ def run(cell, args, log, tracer, fault=None) -> dict:
 
     @jax.jit
     def grad_norms_of(opt_state):
-        mu = program.from_program(_moment(opt_state))
+        mu = block.program.from_program(_moment(opt_state))
         return leaf_norms(jax.tree_util.tree_map(
             lambda m: m / (1 - hyper["b1"]), mu))
 
     @jax.jit
     def change_norms_of(params, key):
-        start = weights.build(shape, key, "float32", std)
+        start = weights.build(spec, key, "float32", std)
         return leaf_norms(jax.tree_util.tree_map(
-            jnp.subtract, program.from_program(params), start))
+            jnp.subtract, block.program.from_program(params), start))
 
     def on_step(step, loss):
         state["steps"] += 1
@@ -249,8 +247,8 @@ def run(cell, args, log, tracer, fault=None) -> dict:
     trainer = program.trainer(model, loader, cfg, mix, strat, args.seed, on_step)
     shardings = jax.tree_util.tree_map(lambda x: x.sharding, trainer.state.params)
     params = weights.make(
-        shape, args.seed, "float32", std,
-        convert=lambda t: program.to_program(t, shape),
+        spec, args.seed, "float32", std,
+        convert=lambda t: block.program.to_program(t, shape),
         out_shardings=shardings,
     )
     program.check_same_structure(params, trainer.state.params)
@@ -310,7 +308,8 @@ def run(cell, args, log, tracer, fault=None) -> dict:
 
     tokens_per_step = mix["batch"] * mix["seq_len"]
     return {
-        "kind": "train_steps", "shape": shape, "setup_s": setup_s,
+        "kind": "train_steps", "block": block, "shape": shape,
+        "setup_s": setup_s,
         "window_s": window_s, "attempted": int(steps),
         "failed": int(np.sum(~np.isfinite(losses))),
         "end_to_end": {"train_tokens_per_s": steps * tokens_per_step / window_s},
@@ -334,7 +333,7 @@ def decide(cell, args, bundle: dict, checks) -> dict:
     proof = bundle["proof"]
     t0 = time.perf_counter()
     ref = reference_steps(
-        bundle["shape"], args.seed, proof["std"], proof["batches"],
+        bundle["block"], bundle["shape"], args.seed, proof["std"], proof["batches"],
         proof["hyper"],
     )
     detail = compare(checks, cell.limits["limits"], proof["ours"], ref)

@@ -1,5 +1,6 @@
 """Weights from the seed, made on the device in one jitted call, in the
-type they are used in, in ``benchmark/reference.py``'s layout.
+type they are used in, in the layout of the block's reference: any tree of
+``(dims, kind)`` that its ``leaf_shapes(shape)`` gives.
 
 ``float32``: every matrix and the embedding ``normal(0, initializer_range)``
 (the published ``initializer_range``), norms 1: what a training run starts
@@ -18,43 +19,26 @@ import jax.numpy as jnp
 _UNIFORM_INT8_STD = 127 / 3**0.5  # deviation of uniform [-127, 127]
 
 
-def leaf_shapes(shape) -> dict:
-    """name -> (shape, kind) with kind ``matrix``, ``norm`` or ``embed``."""
-    d, ff, L = shape.hidden_size, shape.intermediate_size, shape.num_hidden_layers
-    q = shape.num_attention_heads * shape.head_dim
-    kv = shape.num_key_value_heads * shape.head_dim
-    layers = {
-        "attn_norm": ((L, d), "norm"),
-        "wq": ((L, d, q), "matrix"),
-        "wk": ((L, d, kv), "matrix"),
-        "wv": ((L, d, kv), "matrix"),
-        "wo": ((L, q, d), "matrix"),
-        "mlp_norm": ((L, d), "norm"),
-        "w_gate": ((L, d, ff), "matrix"),
-        "w_up": ((L, d, ff), "matrix"),
-        "w_down": ((L, ff, d), "matrix"),
-    }
-    return {
-        "embed": ((shape.vocab_size, d), "embed"),
-        "layers": layers,
-        "final_norm": ((d,), "norm"),
-        "head": ((d, shape.vocab_size), "matrix"),
-    }
-
-
 def seed_key(seed: int):
     """A PRNG key from any whole number (``--seed`` may pass 2**31)."""
     key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
     return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
 
 
-def n_params(shape) -> int:
+def _flat(spec: dict, parents: tuple = ()):
+    """(path, leaf) of every leaf of a tree of nested dicts."""
+    for name, v in spec.items():
+        if isinstance(v, dict):
+            yield from _flat(v, parents + (name,))
+        else:
+            yield parents + (name,), v
+
+
+def n_params(spec: dict) -> int:
     total = 0
-    for leaf in jax.tree_util.tree_leaves(
-        leaf_shapes(shape), is_leaf=lambda x: isinstance(x, tuple)
-    ):
+    for _, (dims, _) in _flat(spec):
         n = 1
-        for s in leaf[0]:
+        for s in dims:
             n *= s
         total += n
     return total
@@ -76,25 +60,29 @@ def _leaf(key, shp, kind, dtype, std):
     return {"q": q, "scale": jitter * (std / _UNIFORM_INT8_STD)}
 
 
-def build(shape, key, dtype: str, std: float):
-    """The whole tree (trace this under ``jax.jit``)."""
-    spec = leaf_shapes(shape)
-    flat = {("", k): v for k, v in spec.items() if k != "layers"}
-    flat.update({("layers", k): v for k, v in spec["layers"].items()})
-    out = {"layers": {}}
-    for i, ((group, name), (shp, kind)) in enumerate(sorted(flat.items())):
-        leaf = _leaf(jax.random.fold_in(key, i), shp, kind, dtype, std)
-        (out["layers"] if group else out)[name] = leaf
+def build(spec: dict, key, dtype: str, std: float):
+    """The whole tree of a block's ``leaf_shapes`` (trace this under
+    ``jax.jit``). A leaf's key is folded by its place in the sorted
+    ``(group, name)`` pairs, ``group`` the path of the dicts above it
+    (``""`` at the top), so a seed's weights do not move while a block's
+    names stay."""
+    out: dict = {}
+    flat = sorted(_flat(spec), key=lambda item: ("/".join(item[0][:-1]), item[0][-1]))
+    for i, (path, (shp, kind)) in enumerate(flat):
+        node = out
+        for p in path[:-1]:
+            node = node.setdefault(p, {})
+        node[path[-1]] = _leaf(jax.random.fold_in(key, i), shp, kind, dtype, std)
     return out
 
 
-def make(shape, seed: int, dtype: str, std: float, convert=None,
+def make(spec: dict, seed: int, dtype: str, std: float, convert=None,
          out_shardings=None):
     """One jitted call from the seed. ``convert`` maps the tree to another
     layout inside the same program (the program's own names and shapes);
     ``out_shardings`` places its leaves."""
     def fn(key):
-        tree = build(shape, key, dtype, std)
+        tree = build(spec, key, dtype, std)
         return tree if convert is None else convert(tree)
 
     kw = {} if out_shardings is None else {"out_shardings": out_shardings}

@@ -38,7 +38,7 @@ def main() -> None:
         proof = bundle["proof"]
         out = {}
         if bundle["kind"] == "train_steps":
-            common = (bundle["shape"], args.seed, proof["std"],
+            common = (bundle["block"], bundle["shape"], args.seed, proof["std"],
                       proof["batches"], proof["hyper"])
             ref = train_kind.reference_steps(*common)
             sides = {"program": proof["ours"]}
@@ -64,8 +64,8 @@ def main() -> None:
             out["losses"] = {"program": proof["ours"]["losses"], "reference": ref[0]}
             out["leaves_left_out"] = d["leaves_left_out"]
         else:
-            common = (bundle["shape"], proof["ref_params"], proof["served"],
-                      cell.config["serve"]["window"])
+            common = (bundle["block"], bundle["shape"], proof["ref_params"],
+                      proof["served"], cell.config["serve"]["window"])
             top = cell.traffic["output"]["max"]
             gaps, n = serve_kind.token_gaps(*common, max_out=top)
             low, _ = serve_kind.token_gaps(*common, weight_bits=4, max_out=top)

@@ -42,7 +42,8 @@ def test_train_control_stands_clear_of_the_program():
 
     def decide(cell, a, bundle, checks):
         proof = bundle["proof"]
-        common = (bundle["shape"], a.seed, proof["std"], proof["batches"], proof["hyper"])
+        common = (bundle["block"], bundle["shape"], a.seed, proof["std"],
+                  proof["batches"], proof["hyper"])
         ref = train_kind.reference_steps(*common)
         low = train_kind.reference_steps(*common, precision="int8")
         control = {"losses": low[0], "grad_norms": low[1], "change_norms": low[2]}
@@ -68,8 +69,8 @@ def test_serve_control_stands_clear_of_the_program():
 
     def decide(cell, a, bundle, checks):
         proof = bundle["proof"]
-        common = (bundle["shape"], proof["ref_params"], proof["served"],
-                  cell.config["serve"]["window"])
+        common = (bundle["block"], bundle["shape"], proof["ref_params"],
+                  proof["served"], cell.config["serve"]["window"])
         seen["program"] = max(serve_kind.token_gaps(*common)[0])
         seen["control"] = max(serve_kind.token_gaps(*common, weight_bits=4)[0])
         checks.at_most("placeholder", 0, 0)

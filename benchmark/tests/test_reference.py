@@ -1,4 +1,4 @@
-"""``benchmark/reference.py`` tied to the program at a small size on the
+"""``benchmark/blocks/gqa_swiglu/`` tied to the program at a small size on the
 CPU, for a grouped-query toy of each family's head ratio (16:8 as
 InternLM2, 32:8 as Mistral): the model's logits, the tokens ``ServeEngine``
 serves through its prefill and its cache, and the loss and gradients the
@@ -9,8 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import reference
-from benchmark.lib import program, serve_kind, train_kind, weights
+from benchmark.lib import adamw, harness, program, serve_kind, train_kind, weights
+
+BLOCK = harness.Block("gqa_swiglu")
+reference = BLOCK.reference
 
 RATIOS = [(16, 8), (32, 8)]
 
@@ -39,9 +41,9 @@ def config(shape, **serve):
 def test_logits_and_served_tokens(heads, kv):
     shape = toy(heads, kv)
     cfg = config(shape)
-    tree = weights.make(shape, 5, "float32", 0.05)
-    params = program.to_program(tree, shape)
-    model = program.model_config(cfg, "serve", 64)
+    tree = weights.make(reference.leaf_shapes(shape), 5, "float32", 0.05)
+    params = BLOCK.program.to_program(tree, shape)
+    model = BLOCK.program.model(cfg, "serve", 64)
     rng = np.random.default_rng(0)
     tokens = rng.integers(0, shape.vocab_size, 40).astype(np.int32)
     with jax.default_matmul_precision("highest"):
@@ -58,7 +60,7 @@ def test_logits_and_served_tokens(heads, kv):
     done = sorted(engine.run_until_idle(), key=lambda c: c.request_id)
     served = [(list(c.prompt), list(c.tokens)) for c in done]
     assert [p for p, _ in served] == prompts
-    gaps, compared = serve_kind.token_gaps(shape, tree, served, 64)
+    gaps, compared = serve_kind.token_gaps(BLOCK, shape, tree, served, 64)
     assert compared == 27 and max(gaps) < 1e-3
 
 
@@ -76,18 +78,19 @@ def test_loss_and_gradients_of_a_trainer_step(heads, kv):
     loader = program.sharded_loader(arrays, 4, strat.mesh, 1)
     seen = []
     trainer = program.trainer(
-        program.model_config(cfg, "train", 24), loader, cfg, mix, strat, 1,
+        BLOCK.program.model(cfg, "train", 24), loader, cfg, mix, strat, 1,
         lambda step, loss: seen.append(float(loss)))
+    spec = reference.leaf_shapes(shape)
     trainer.state = trainer.state.replace(params=weights.make(
-        shape, 1, "float32", 0.05, convert=lambda t: program.to_program(t, shape)))
+        spec, 1, "float32", 0.05, convert=lambda t: BLOCK.program.to_program(t, shape)))
     loader.set_epoch(0)
     batch = next(iter(loader))
     state, metrics = trainer.train_step(trainer.state, batch)
     grads = jax.tree_util.tree_map(
-        lambda m: m / 0.1, program.from_program(train_kind._moment(state.opt_state)))
-    tree = weights.make(shape, 1, "float32", 0.05)
-    loss, ref_grads = reference.loss_and_grads(
-        tree, jnp.asarray(batch[0]), jnp.asarray(batch[1]), shape)
+        lambda m: m / 0.1, BLOCK.program.from_program(train_kind._moment(state.opt_state)))
+    tree = weights.make(spec, 1, "float32", 0.05)
+    loss, ref_grads = reference.grad_fn(shape)(
+        tree, jnp.asarray(batch[0]), jnp.asarray(batch[1]))
     assert abs(float(metrics["loss"]) - loss) < 2e-5 * loss
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
                             jax.tree_util.tree_leaves(ref_grads)):
@@ -98,10 +101,10 @@ def test_loss_and_gradients_of_a_trainer_step(heads, kv):
     mu = jax.tree_util.tree_map(jnp.zeros_like, tree)
     nu = jax.tree_util.tree_map(jnp.zeros_like, tree)
     hyp = (1e-3, 0.9, 0.999, 1e-8, 0.01)
-    new, _, _ = reference.adamw(tree, ref_grads, mu, nu, jnp.asarray(1), hyp)
-    start = weights.make(shape, 1, "float32", 0.05)
+    new, _, _ = adamw.adamw(tree, ref_grads, mu, nu, jnp.asarray(1), hyp)
+    start = weights.make(spec, 1, "float32", 0.05)
     for (path, a), b, s in zip(
-            jax.tree_util.tree_leaves_with_path(program.from_program(state.params)),
+            jax.tree_util.tree_leaves_with_path(BLOCK.program.from_program(state.params)),
             jax.tree_util.tree_leaves(new), jax.tree_util.tree_leaves(start)):
         if float(jnp.abs(b - s).max()) == 0:
             continue
