@@ -160,6 +160,150 @@ class MoEFFN(nn.Module):
         ).astype(x.dtype)
 
 
+def plan_dispatch(ids: jax.Array, held: int, offset: int, block_m: int):
+    """Where each (token, choice) pair goes in a buffer of rows sorted by
+    expert, for a layer that holds experts ``[offset, offset + held)`` of a
+    router's whole width. ``ids``: (T, k) int32 global expert ids.
+
+    Returns a dict: ``row`` (T*k,) the pair's row (the buffer's length, out
+    of range, where its expert is not held: a scatter with ``mode="drop"``
+    loses it); ``here`` (T*k,) whether it is held; ``row_token`` (M,) the
+    token whose activations row ``r`` carries (token 0 on the padding rows,
+    whose results nobody reads); ``tile_expert`` (M // block_m,) the local
+    expert of each row tile; ``n_tiles`` the tiles in use, traced;
+    ``group_rows`` (held,) each expert's rows with its padding;
+    ``block_m``. Every expert's rows are padded to a multiple of
+    ``block_m`` so that a tile has one expert; M is the static worst case,
+    every pair held here: ``T * k`` rows and a tile of padding an expert.
+    No pair is ever dropped: the buffer has room for all of them whatever
+    the routing."""
+    t, k = ids.shape
+    pairs = t * k
+    m = -(-pairs // block_m) * block_m + held * block_m
+    local = ids.reshape(-1) - offset
+    here = (local >= 0) & (local < held)
+    onehot = (local[:, None] == jnp.arange(held)[None, :]) & here[:, None]
+    rank = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1  # (P, held)
+    sizes = rank[-1] + 1
+    padded = -(-sizes // block_m) * block_m
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    row = jnp.sum(jnp.where(onehot, starts[None, :] + rank, 0), axis=1)
+    row = jnp.where(here, row, m)
+    row_token = jnp.zeros((m,), jnp.int32).at[row].set(
+        jnp.arange(pairs, dtype=jnp.int32) // k, mode="drop"
+    )
+    tile_start = jnp.arange(m // block_m, dtype=jnp.int32) * block_m
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, tile_start, side="right"), held - 1
+    ).astype(jnp.int32)
+    return {
+        "row": row, "here": here, "row_token": row_token,
+        "tile_expert": tile_expert, "n_tiles": ends[-1] // block_m,
+        "group_rows": padded, "block_m": block_m,
+    }
+
+
+def _rows_per_tile(tokens: int, k: int, n_routed: int) -> int:
+    """Row tile of the grouped product: the power of two that holds twice
+    the rows an expert expects (``tokens * k / n_routed``), between 16 (a
+    bfloat16 tile's rows) and 256, so that an expert's weights are mostly
+    read for one tile."""
+    want = 2 * tokens * k / n_routed
+    bm = 16
+    while bm < want and bm < 256:
+        bm *= 2
+    return bm
+
+
+class RoutedExperts(nn.Module):
+    """The routed half of an expert layer that DROPS NO TOKEN, as one
+    chip's share of an expert-parallel deployment: the router scores all
+    ``n_routed`` experts in float32 (sigmoid), each token takes its
+    ``top_k``, their scores divided by the sum of the ``top_k`` and
+    multiplied by ``scaling`` are its weights, and the layer returns the
+    part of ``sum_i g_i E_i(x)`` that the ``held`` experts from ``offset``
+    on give. What the other experts would add is another chip's share (on
+    one chip nothing stands in for the exchange); with ``held ==
+    n_routed`` this is the whole layer. The shared expert is the block's
+    (``models/transformer.py``), counted once over all shares.
+
+    The work follows the pairs routed here: rows are sorted by expert into
+    a buffer whose static length is the worst case
+    (:func:`plan_dispatch`), and the grouped product runs over the row
+    tiles in use alone (``ops.quant.grouped_int8_matmul``'s traced grid
+    bound; float weights: ``lax.ragged_dot``). Weights: ``router`` (d,
+    n_routed) float32; ``w_gate``, ``w_up`` (held, d, ff), ``w_down``
+    (held, ff, d), float32 or with ``quantized`` ``{"q": int8, "scale":
+    float32 (held, 1, n)}``. Serving only so far: no auxiliary loss is
+    sown."""
+
+    n_routed: int
+    held: int
+    offset: int
+    top_k: int
+    d_ff: int
+    scaling: float = 1.0
+    dtype: jnp.dtype = jnp.float32
+    quantized: bool = False
+
+    def _product(self, name: str, k: int, n: int, rows, plan):
+        """Row tile ``i`` of ``rows`` times stack ``name`` (held, k, n) of
+        the tile's expert, over the tiles in use."""
+        if self.quantized:
+            from pytorch_distributed_training_tutorials_tpu.ops.quant import (
+                Int8ExpertStack,
+            )
+
+            return Int8ExpertStack(self.held, k, n, name=name)(
+                rows, plan["tile_expert"], plan["n_tiles"], plan["block_m"]
+            )
+        w = self.param(
+            name, nn.initializers.lecun_normal(), (self.held, k, n), jnp.float32
+        )
+        return jax.lax.ragged_dot(
+            rows, w.astype(self.dtype), plan["group_rows"]
+        )
+
+    @nn.compact
+    def __call__(self, x):
+        lead, d = x.shape[:-1], x.shape[-1]
+        x2 = x.reshape(-1, d)
+        t = x2.shape[0]
+        router = self.param(
+            "router", nn.initializers.lecun_normal(), (d, self.n_routed),
+            jnp.float32,
+        )
+        with jax.named_scope("moe_router"):
+            scores = jax.nn.sigmoid(jnp.matmul(
+                x2.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST,
+            ))
+            top, ids = jax.lax.top_k(scores, self.top_k)
+            gate = self.scaling * top / (
+                jnp.sum(top, -1, keepdims=True) + 1e-20
+            )
+        block_m = _rows_per_tile(t, self.top_k, self.n_routed)
+        with jax.named_scope("moe_dispatch"):
+            plan = plan_dispatch(ids, self.held, self.offset, block_m)
+            rows = x2.astype(self.dtype)[plan["row_token"]]
+        with jax.named_scope("moe_experts"):
+            hidden = nn.silu(
+                self._product("w_gate", d, self.d_ff, rows, plan)
+            ) * self._product("w_up", d, self.d_ff, rows, plan)
+            out_rows = self._product("w_down", self.d_ff, d, hidden, plan)
+        with jax.named_scope("moe_dispatch"):
+            # rows of tiles not in use were never written: select, never
+            # multiply by nought
+            picked = out_rows[jnp.minimum(plan["row"], out_rows.shape[0] - 1)]
+            weighted = jnp.where(
+                plan["here"][:, None],
+                picked.astype(jnp.float32) * gate.reshape(-1, 1), 0.0,
+            )
+            out = jnp.sum(weighted.reshape(t, self.top_k, d), axis=1)
+        return out.astype(x.dtype).reshape(*lead, d)
+
+
 def moe_aux_loss(variables_or_updates) -> jax.Array:
     """Sum every sown ``moe_aux_loss`` (one per MoE layer; each sown value is
     a 1-tuple). Add ``aux_weight * moe_aux_loss(updates)`` to the objective."""

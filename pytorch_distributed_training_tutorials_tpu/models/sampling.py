@@ -49,13 +49,21 @@ def greedy_token(logits):
     """Greedy next token over ``(..., V)`` logits with a DETERMINISTIC
     lowest-index tie-break, spelled out instead of inherited from the
     backend's argmax convention: among all positions holding the row
-    maximum, the smallest vocabulary index wins. ``jnp.argmax`` documents
-    first-occurrence semantics too, but the reduction below (min over the
-    tied index set) makes the contract explicit and backend-proof — the
-    greedy serving paths (one-shot, per-slot, speculative verify) must all
-    resolve an exact tie to the SAME token or token-exactness guarantees
-    silently become backend properties. Cost is one extra O(V) pass,
-    noise next to the lm_head matmul that produced the logits."""
+    maximum, the smallest vocabulary index wins. The greedy serving paths
+    (one-shot, per-slot, speculative verify) must all resolve an exact tie
+    to the SAME token or token-exactness guarantees silently become backend
+    properties. Two reductions, a maximum and a minimum over the tied index
+    set: under a vocabulary-sharded head each is one all-reduce (a single
+    (value, index) reduction gathers its partial results instead, which the
+    tensor-parallel decode's all-reduce-only audit refuses).
+
+    Both reductions must read the SAME logits. Under ``jit`` on the TPU they
+    did not: the logits arrive as bfloat16 widened to float32, the compiler
+    may keep the unrounded float32 in one consumer's fusion and the rounded
+    value in the other's (excess precision), no element then equalled the
+    maximum and the sentinel ``vocab_size`` was served (PERF.md, PR 26).
+    The barrier makes the logits one materialized array that both read."""
+    logits = jax.lax.optimization_barrier(logits)
     v = logits.shape[-1]
     top = jnp.max(logits, axis=-1, keepdims=True)
     tied = jnp.where(logits == top, jnp.arange(v), v)

@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from pytorch_distributed_training_tutorials_tpu.models.moe import (
     MOE_RULES,
     MoEFFN,
+    RoutedExperts,
 )
 
 
@@ -145,6 +146,48 @@ class TransformerConfig:
     # compiled programs are byte-identical to a build without LoRA.
     lora_adapters: int = 0
     lora_rank: int = 0
+    # Latent attention (MLA): kv_lora_rank > 0 replaces the block's
+    # attention with LatentAttention. Queries go through a q_lora_rank
+    # bottleneck with a norm; keys and values come from ONE kv_lora_rank
+    # latent a token (normed) plus qk_rope_head_dim rotary dims shared by
+    # all heads, and the cache holds just those kv_lora_rank +
+    # qk_rope_head_dim numbers a token and a layer ("cached_latent").
+    # Prefill up-projects K and V a head and attends blockwise; decode
+    # reads the latent cache itself, the up-projection absorbed into the
+    # query and the output. n_kv_heads and head_dim mean nothing then.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # a norm after each sublayer too, before it joins the residual stream
+    sandwich_norm: bool = False
+    # Routed experts that drop no token (models/moe.py RoutedExperts), as
+    # one chip's share: n_routed_experts > 0 gives the layers from
+    # n_dense_layers on a router over n_routed_experts, experts_per_token
+    # of them a token, of which this model holds experts_held (None: all)
+    # from expert_offset, and n_shared_experts shared experts, each a
+    # SwiGLU of width expert_d_ff. The n_dense_layers leading layers keep
+    # the dense SwiGLU of width d_ff; a model with both kinds of layer runs
+    # its layers unrolled (scan_layers=False): one nn.scan is one kind.
+    n_routed_experts: int = 0
+    experts_held: int | None = None
+    expert_offset: int = 0
+    experts_per_token: int = 0
+    expert_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    n_dense_layers: int = 0
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        if self.experts_held is None:
+            return self.n_routed_experts
+        return self.experts_held
 
     @property
     def ff_dim(self) -> int:
@@ -945,12 +988,253 @@ class Attention(nn.Module):
         return y
 
 
+def _latent_prefill_attention(q, k, v) -> jax.Array:
+    """Causal attention with q, k (B, S, H, nope + rope) and v (B, S, H,
+    v) through the flash kernel (128 heads of dense scores at S = 2048 are
+    2.1 GB). The kernel's q, k and v share one width that is a whole number
+    of lane tiles: all three are padded with zeros to it (192 and 128 ->
+    256; the zeros add nothing to a score or an output), and q is scaled so
+    that the kernel's ``1 / sqrt(width)`` comes out as ``1 / sqrt(nope +
+    rope)``."""
+    from pytorch_distributed_training_tutorials_tpu.ops.flash_attention import (
+        flash_attention,
+    )
+
+    d, dv = q.shape[-1], v.shape[-1]
+    width = -(-max(d, dv) // 128) * 128
+
+    def pad(t):
+        return jnp.pad(t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
+
+    q = (q.astype(jnp.float32) * (width / d) ** 0.5).astype(q.dtype)
+    return flash_attention(pad(q), pad(k), pad(v))[..., :dv]
+
+
+class LatentUp(nn.Module):
+    """``W_ukv`` of latent attention, (kv_lora_rank, heads * (nope + v)),
+    columns a head at a time ``[k_nope | v]``: float ``kernel`` or int8
+    ``q`` with ``scale`` a column. Two uses of the one matrix:
+    :meth:`project` up-projects latents (prefill), :meth:`absorbed` hands
+    the matrix itself out for decode, which multiplies the query and the
+    output by it and never the cache."""
+
+    cfg: TransformerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        shape = (
+            cfg.kv_lora_rank,
+            cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+        )
+        if cfg.quantized:
+            self.q = self.param("q", nn.initializers.zeros, shape, jnp.int8)
+            self.scale = self.param(
+                "scale", nn.initializers.ones, (1, shape[1]), jnp.float32
+            )
+        else:
+            self.kernel = self.param(
+                "kernel", nn.initializers.lecun_normal(), shape
+            )
+
+    def project(self, c: jax.Array) -> jax.Array:
+        """``c`` (B, S, rank) -> ``[k_nope | v]`` (B, S, H, nope + v)."""
+        cfg = self.cfg
+        c2 = c.reshape(-1, c.shape[-1])
+        if cfg.quantized:
+            from pytorch_distributed_training_tutorials_tpu.ops.quant import (
+                Int8Param,
+                int8_matmul,
+            )
+
+            out = int8_matmul(c2, Int8Param(q=self.q, scale=self.scale))
+        else:
+            out = c2.astype(cfg.dtype) @ self.kernel.astype(cfg.dtype)
+        return out.astype(c.dtype).reshape(
+            *c.shape[:-1], cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim
+        )
+
+    def absorbed(self, dtype) -> tuple[jax.Array, jax.Array]:
+        """``(w_uk (rank, H, nope), w_uv (rank, H, v))`` in ``dtype``."""
+        cfg = self.cfg
+        if cfg.quantized:
+            w = (self.q.astype(jnp.float32) * self.scale).astype(dtype)
+        else:
+            w = self.kernel.astype(dtype)
+        w = w.reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+        return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``cfg.kv_lora_rank > 0``)::
+
+        c_q = RMSNorm(x W_dq);  [q_nope | q_rope] = c_q W_uq   a head
+        [c_kv | k_r] = x W_dkv; c = RMSNorm(c_kv); k_rope = RoPE(k_r)
+        [k_nope | v] = c W_ukv                                 a head
+        softmax((q_nope . k_nope + RoPE(q_rope) . k_rope) / sqrt(nope + rope)) v
+
+    ``k_rope`` is one vector a token, shared by all heads. The cache holds
+    ``[c | k_rope]`` a token (``cached_latent`` (B, max_seq_len, rank +
+    rope rounded up to whole lane tiles), under ``scan_layers`` with the
+    leading layer axis of PR 28's carried stack) and ``cache_index``. Two paths, chosen by what the apply
+    is: without ``decode`` (training, prefill) K and V are up-projected a
+    head and attended blockwise (the flash kernel); with ``decode`` (a cache
+    handed in; one token or a chunk) the cache itself is read,
+    ``q_nope W_uk^T`` against ``c`` and ``p c`` times ``W_uv``: the same
+    sums in another order, without ever up-projecting the cached tokens.
+    """
+
+    cfg: TransformerConfig
+
+    def _stored_width(self) -> int:
+        """A token's row of the cache: ``[c | k_rope]`` and zeros up to a
+        whole number of 128-wide lane tiles (576 -> 640). The chip's tiled
+        memory pads a 576-wide minor axis to 640 anyway, unless the
+        compiler turns the sequence axis minor-most, and then the carried
+        stack is copied whole into and out of the layer scan on every
+        step (seen in the program compiled for a v5e, PR 30)."""
+        cfg = self.cfg
+        return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+    def _row(self, c, k_rope):
+        """``[c | k_rope | 0...]`` as the cache stores a token."""
+        pad = self._stored_width() - c.shape[-1] - k_rope.shape[-1]
+        parts = [c, k_rope.astype(c.dtype)]
+        if pad:
+            parts.append(jnp.zeros(c.shape[:-1] + (pad,), c.dtype))
+        return jnp.concatenate(parts, -1)
+
+    def _cache_vars(self, b: int, dtype):
+        cfg = self.cfg
+        if cfg.kv_cache_dtype is not None:
+            if _kv_quant_mode(cfg.kv_cache_dtype):
+                raise ValueError(
+                    "a latent cache is stored as floats: kv_cache_dtype "
+                    f"{cfg.kv_cache_dtype!r} is not supported"
+                )
+            dtype = jnp.dtype(cfg.kv_cache_dtype)
+        latent = self.variable(
+            "cache", "cached_latent", jnp.zeros,
+            (b, cfg.max_seq_len, self._stored_width()), dtype,
+        )
+        idx = self.variable(
+            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
+        )
+        return latent, idx
+
+    @nn.compact
+    def __call__(
+        self, x, decode: bool = False, prefill: bool = False,
+        adapter_ids=None, layer=None,
+    ):
+        cfg = self.cfg
+        assert not (decode and prefill), "decode and prefill are exclusive"
+        h, rank = cfg.n_heads, cfg.kv_lora_rank
+        nope, rope_d, vd = (
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        )
+        if cfg.quantized:
+            from pytorch_distributed_training_tutorials_tpu.ops.quant import (
+                Int8Dense,
+            )
+
+            dense = lambda f, name: Int8Dense(  # noqa: E731
+                f, use_bias=False, name=name
+            )
+        else:
+            dense = lambda f, name: nn.Dense(  # noqa: E731
+                f, use_bias=False, dtype=cfg.dtype, name=name
+            )
+        b, s = x.shape[0], x.shape[1]
+        c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
+            dense(cfg.q_lora_rank, "q_down")(x)
+        )
+        q = dense(h * (nope + rope_d), "q_up")(c_q).reshape(
+            b, s, h, nope + rope_d
+        )
+        kv = dense(rank + rope_d, "kv_down")(x)
+        c = RMSNorm(cfg.norm_eps, name="kv_norm")(kv[..., :rank])
+        up = LatentUp(cfg, name="kv_up")
+        out_proj = dense(cfg.d_model, "o_proj")
+        scale = (nope + rope_d) ** -0.5
+
+        if decode:
+            latent_var, idx = self._cache_vars(b, x.dtype)
+            pos = _layer_value(idx, layer)  # () generate, (B,) serve slots
+            q_rope = apply_rope(q[..., nope:], cfg.rope_theta, offset=pos)
+            k_rope = apply_rope(
+                kv[..., None, rank:], cfg.rope_theta, offset=pos
+            )[:, :, 0]
+            _store_decode_kv(latent_var, self._row(c, k_rope), pos, layer)
+            _store_cache_index(idx, pos + s, layer)
+            with jax.named_scope("latent_attn"):
+                from pytorch_distributed_training_tutorials_tpu.ops.latent_attention import (  # noqa: E501
+                    latent_decode_attention,
+                    latent_decode_attention_reference,
+                )
+
+                w_uk, w_uv = up.absorbed(x.dtype)
+                q_lat = jnp.einsum(
+                    "bshd,rhd->bshr", q[..., :nope], w_uk,
+                    preferred_element_type=jnp.float32,
+                )
+                # one contraction over a row as the cache holds it,
+                # [c | k_rope | 0...]: slicing the cache would copy it
+                q_full = self._row(q_lat, q_rope).astype(
+                    latent_var.value.dtype
+                )
+                if s == 1:
+                    # a step: the kernel reads the rows where they lie, in
+                    # the carried stack, up to each row's own depth
+                    stack = latent_var.value
+                    o_lat = latent_decode_attention(
+                        q_full[:, 0],
+                        stack if layer is not None else stack[None],
+                        0 if layer is None else layer,
+                        jnp.broadcast_to(pos, (b,)), sm_scale=scale,
+                    )[:, None]
+                else:
+                    # a chunk of positions: the same sums as plain einsums
+                    # over a copy of the layer's rows
+                    with jax.named_scope("kv_cache"):
+                        lat = _layer_value(latent_var, layer)
+                    qpos = (pos[..., None] if pos.ndim else pos) + jnp.arange(s)
+                    valid = jnp.arange(cfg.max_seq_len) <= qpos[..., :, None]
+                    o_lat = latent_decode_attention_reference(
+                        q_full, lat, valid if valid.ndim == 3 else valid[None],
+                        sm_scale=scale,
+                    )
+                out = jnp.einsum(
+                    "bshr,rhd->bshd", o_lat[..., :rank].astype(x.dtype), w_uv,
+                    preferred_element_type=jnp.float32,
+                ).astype(x.dtype)
+        else:
+            q_rope = apply_rope(q[..., nope:], cfg.rope_theta)
+            k_rope = apply_rope(kv[..., None, rank:], cfg.rope_theta)
+            if prefill:
+                latent_var, idx = self._cache_vars(b, x.dtype)
+                _store_prefill_kv(
+                    latent_var, self._row(c, k_rope[:, :, 0]), layer
+                )
+                _store_cache_index(idx, jnp.asarray(s, jnp.int32), layer)
+            with jax.named_scope("latent_attn"):
+                k_v = up.project(c)  # (B, S, H, nope + v)
+                k = jnp.concatenate(
+                    [k_v[..., :nope],
+                     jnp.broadcast_to(k_rope, (b, s, h, rope_d))], -1
+                )
+                q_full = jnp.concatenate([q[..., :nope], q_rope], -1)
+                out = _latent_prefill_attention(q_full, k, k_v[..., nope:])
+        return out_proj(out.reshape(b, s, h * vd))
+
+
 class SwiGLU(nn.Module):
     cfg: TransformerConfig
+    d_ff: int | None = None  # None: cfg.ff_dim (a shared expert gives its own)
 
     @nn.compact
     def __call__(self, x, adapter_ids=None):
         cfg = self.cfg
+        ff_dim = self.d_ff if self.d_ff is not None else cfg.ff_dim
         if cfg.quantized:
             from pytorch_distributed_training_tutorials_tpu.ops.quant import Int8Dense
 
@@ -963,24 +1247,24 @@ class SwiGLU(nn.Module):
             dense = lambda f, name, kind: nn.Dense(  # noqa: E731
                 f, use_bias=False, dtype=cfg.dtype, name=name
             )
-        gate_pre = dense(cfg.ff_dim, "gate_proj", "column")(x)
-        up = dense(cfg.ff_dim, "up_proj", "column")(x)
+        gate_pre = dense(ff_dim, "gate_proj", "column")(x)
+        up = dense(ff_dim, "up_proj", "column")(x)
         if cfg.lora_adapters:
             lora = lambda name, din, dout: LoRADelta(  # noqa: E731
                 cfg.lora_adapters, cfg.lora_rank, din, dout,
                 dtype=cfg.dtype, name=name,
             )
             gate_pre = gate_pre + lora(
-                "gate_proj_lora", cfg.d_model, cfg.ff_dim
+                "gate_proj_lora", cfg.d_model, ff_dim
             )(x, adapter_ids)
             up = up + lora(
-                "up_proj_lora", cfg.d_model, cfg.ff_dim
+                "up_proj_lora", cfg.d_model, ff_dim
             )(x, adapter_ids)
         hidden = nn.silu(gate_pre) * up
         y = dense(cfg.d_model, "down_proj", "row")(hidden)
         if cfg.lora_adapters:
             y = y + lora(
-                "down_proj_lora", cfg.ff_dim, cfg.d_model
+                "down_proj_lora", ff_dim, cfg.d_model
             )(hidden, adapter_ids)
         return y
 
@@ -1010,8 +1294,70 @@ def _remat_policy(cfg: TransformerConfig):
     )
 
 
+def _check_new_block_fields(cfg: TransformerConfig) -> None:
+    """Refuse, in words, the combinations latent attention and the
+    dropless experts do not run with yet."""
+    if cfg.latent:
+        if not (cfg.q_lora_rank and cfg.qk_nope_head_dim
+                and cfg.qk_rope_head_dim and cfg.v_head_dim):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
+            )
+        for field, what in (
+            ("kv_pages", "a paged KV cache"),
+            ("int8_mesh", "tensor-parallel int8 serving"),
+            ("lora_adapters", "LoRA adapters"),
+            ("attention_fn", "a custom attention_fn"),
+        ):
+            if getattr(cfg, field) not in (None, 0):
+                raise ValueError(
+                    f"latent attention does not run with {what} ({field}): "
+                    "its cache is one latent a token, not heads of K and V"
+                )
+    if cfg.n_routed_experts:
+        if cfg.moe_experts:
+            raise ValueError(
+                "n_routed_experts (dropless) and moe_experts (capacity "
+                "dropping) are two expert layers: set one"
+            )
+        if not (0 < cfg.experts_per_token <= cfg.n_routed_experts
+                and cfg.expert_d_ff > 0):
+            raise ValueError(
+                "n_routed_experts needs experts_per_token in "
+                "[1, n_routed_experts] and expert_d_ff"
+            )
+        if not (0 < cfg.held_experts
+                and cfg.expert_offset + cfg.held_experts <= cfg.n_routed_experts):
+            raise ValueError(
+                f"experts_held {cfg.held_experts} from expert_offset "
+                f"{cfg.expert_offset} do not lie in the router's "
+                f"{cfg.n_routed_experts}"
+            )
+        if not 0 <= cfg.n_dense_layers < cfg.n_layers:
+            raise ValueError(
+                "n_dense_layers must leave at least one layer of experts"
+            )
+        if cfg.scan_layers and cfg.n_dense_layers:
+            raise ValueError(
+                "scan_layers=True scans layers of one kind: a model with "
+                f"n_dense_layers={cfg.n_dense_layers} leading dense layers "
+                "before its layers of routed experts runs unrolled "
+                "(scan_layers=False)"
+            )
+        if cfg.lora_adapters or cfg.int8_mesh is not None:
+            raise ValueError(
+                "the dropless expert layer runs with neither LoRA adapters "
+                "nor tensor-parallel int8 serving"
+            )
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
+    # this layer's feed-forward is the routed experts (with the shared
+    # one) and not the dense SwiGLU: cfg.n_routed_experts > 0 and the
+    # layer is not one of the cfg.n_dense_layers leading ones
+    routed: bool = False
 
     @nn.compact
     def __call__(
@@ -1019,14 +1365,32 @@ class Block(nn.Module):
         adapter_ids=None, layer=None,
     ):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(
+        attention = LatentAttention if cfg.latent else Attention
+        y = attention(cfg, name="attn")(
             RMSNorm(cfg.norm_eps, name="attn_norm")(x), decode=decode,
             prefill=prefill, adapter_ids=adapter_ids, layer=layer,
         )
-        if cfg.moe_experts > 0:
+        if cfg.sandwich_norm:
+            y = RMSNorm(cfg.norm_eps, name="post_attn_norm")(y)
+        x = x + y
+        m = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+        if self.routed:
+            y = RoutedExperts(
+                n_routed=cfg.n_routed_experts, held=cfg.held_experts,
+                offset=cfg.expert_offset, top_k=cfg.experts_per_token,
+                d_ff=cfg.expert_d_ff, scaling=cfg.routed_scaling,
+                dtype=cfg.dtype, quantized=cfg.quantized, name="moe",
+            )(m)
+            if cfg.n_shared_experts:
+                with jax.named_scope("moe_shared"):
+                    y = y + SwiGLU(
+                        cfg, d_ff=cfg.n_shared_experts * cfg.expert_d_ff,
+                        name="shared",
+                    )(m)
+        elif cfg.moe_experts > 0:
             # MoE blocks carry no LoRA hooks (TransformerLM rejects the
             # combination up front)
-            ffn = MoEFFN(
+            y = MoEFFN(
                 num_experts=cfg.moe_experts,
                 top_k=cfg.moe_top_k,
                 d_ff=cfg.ff_dim,
@@ -1034,11 +1398,12 @@ class Block(nn.Module):
                 dtype=cfg.dtype,
                 group_size=cfg.moe_group_size,
                 name="moe",
-            )
-            return x + ffn(RMSNorm(cfg.norm_eps, name="mlp_norm")(x))
-        return x + SwiGLU(cfg, name="mlp")(
-            RMSNorm(cfg.norm_eps, name="mlp_norm")(x), adapter_ids
-        )
+            )(m)
+        else:
+            y = SwiGLU(cfg, name="mlp")(m, adapter_ids)
+        if cfg.sandwich_norm:
+            y = RMSNorm(cfg.norm_eps, name="post_mlp_norm")(y)
+        return x + y
 
 
 class _ScanCell(nn.Module):
@@ -1047,6 +1412,7 @@ class _ScanCell(nn.Module):
     cfg: TransformerConfig
     decode: bool = False
     prefill: bool = False
+    routed: bool = False
 
     @nn.compact
     def __call__(self, x, ids, layer):
@@ -1055,7 +1421,7 @@ class _ScanCell(nn.Module):
         # empty pytree, so the scanned program is unchanged). ``layer`` is
         # the scanned layer index when the scan carries the cache, else
         # None (also an empty pytree)
-        return Block(self.cfg, name="block")(
+        return Block(self.cfg, self.routed, name="block")(
             x, decode=self.decode, prefill=self.prefill, adapter_ids=ids,
             layer=layer,
         ), None
@@ -1084,8 +1450,11 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         if cfg.quantized and cfg.moe_experts:
             raise ValueError(
-                "quantized serving supports dense blocks only (no MoE)"
+                "quantized serving does not support the capacity-dropping "
+                "MoEFFN (moe_experts): the experts int8 serving runs are "
+                "the dropless ones (n_routed_experts)"
             )
+        _check_new_block_fields(cfg)
         if tokens.shape[1] > cfg.max_seq_len:
             raise ValueError(
                 f"sequence length {tokens.shape[1]} exceeds "
@@ -1114,6 +1483,9 @@ class TransformerLM(nn.Module):
         x = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="tok_emb"
         )(tokens)
+        # the layers from routed_from on have the routed experts for their
+        # feed-forward, the leading ones the dense SwiGLU
+        routed_from = cfg.n_dense_layers if cfg.n_routed_experts else cfg.n_layers
         if cfg.scan_layers:
             cell = _ScanCell
             if cfg.remat:
@@ -1145,7 +1517,7 @@ class TransformerLM(nn.Module):
                 split_rngs={"params": True},
                 in_axes=(nn.broadcast, 0),
                 length=cfg.n_layers,
-            )(cfg, decode, prefill, name="layers")
+            )(cfg, decode, prefill, routed_from == 0, name="layers")
             # the scope marks what lax.scan itself does around the cell:
             # it slices every stacked leaf (each layer's weights, a scanned
             # cache's slice) out by the layer index and stacks a scanned
@@ -1169,12 +1541,15 @@ class TransformerLM(nn.Module):
                 else Block
             )
             for i in range(cfg.n_layers):
+                routed = i >= routed_from
                 if ids is None:
-                    x = block_cls(cfg, name=f"block_{i}")(x, decode, prefill)
+                    x = block_cls(cfg, routed, name=f"block_{i}")(
+                        x, decode, prefill
+                    )
                 else:
                     # adapter_ids is positional arg 4 — TRACED (remat's
                     # static_argnums stays (2, 3): decode/prefill only)
-                    x = block_cls(cfg, name=f"block_{i}")(
+                    x = block_cls(cfg, routed, name=f"block_{i}")(
                         x, decode, prefill, ids
                     )
         if prefill or (decode and last_pos is not None):
@@ -1310,8 +1685,14 @@ _QUANTIZED_KERNELS = frozenset(
     {
         "q_proj", "k_proj", "v_proj", "o_proj",
         "gate_proj", "up_proj", "down_proj", "lm_head",
+        # latent attention (2-D kernels, the first axis contracted)
+        "q_down", "q_up", "kv_down", "kv_up",
     }
 )
+# the stacks of a dropless expert layer ("moe": bare (experts, in, out)
+# arrays): int8 with one scale a column of each expert; its router stays
+# float32
+_QUANTIZED_EXPERT_STACKS = frozenset({"w_gate", "w_up", "w_down"})
 
 
 def quantize_lm_params(params):
@@ -1353,6 +1734,11 @@ def quantize_lm_params(params):
                 # leading (n_layers,) axis that must not be mistaken for
                 # the contraction dim
                 out[name] = walk(sub, stacked=stacked or name == "layers")
+            elif name in _QUANTIZED_EXPERT_STACKS and "router" in tree:
+                # (..., experts, in, out): the reduction is over "in" alone,
+                # so the layer axis of a stack needs no loop
+                qp = quantize_int8(sub, channel_axis=-1, reduce_axis=-2)
+                out[name] = {"q": qp.q, "scale": qp.scale}
             else:
                 out[name] = sub
         return out
@@ -1395,6 +1781,12 @@ def stack_quantized_lm_params(params):
     if sorted(blocks) != list(range(n)):
         raise ValueError(f"non-contiguous block indices: {sorted(blocks)}")
     ordered = [blocks[i] for i in range(n)]
+    if len({jax.tree_util.tree_structure(b) for b in ordered}) > 1:
+        raise ValueError(
+            "the blocks are of more than one kind (leading dense layers "
+            "before layers of routed experts): one scan stacks one kind, "
+            "serve such a model unrolled"
+        )
     rest["layers"] = {
         "block": jax.tree_util.tree_map(
             lambda *leaves: jnp.stack(leaves), *ordered

@@ -55,14 +55,23 @@ class Int8Param(struct.PyTreeNode):
         return self.q.astype(jnp.float32) * self.scale
 
 
-def quantize_int8(w: jax.Array, channel_axis: int = -1) -> Int8Param:
+def quantize_int8(
+    w: jax.Array, channel_axis: int = -1, reduce_axis: int | None = None
+) -> Int8Param:
     """absmax/127 per-channel symmetric quantization (the bitsandbytes
     vector-wise scheme). ``channel_axis`` is the output-feature axis that
-    keeps its own scale (-1 for a Dense kernel (in, out))."""
+    keeps its own scale (-1 for a Dense kernel (in, out)). With
+    ``reduce_axis`` the absmax is taken over that axis alone and every
+    other axis keeps its own scales too: a stack of kernels
+    ``(..., in, out)`` with ``reduce_axis=-2`` is each kernel quantized on
+    its own."""
     w = jnp.asarray(w, jnp.float32)
-    reduce_axes = tuple(
-        a for a in range(w.ndim) if a != channel_axis % w.ndim
-    )
+    if reduce_axis is not None:
+        reduce_axes = (reduce_axis % w.ndim,)
+    else:
+        reduce_axes = tuple(
+            a for a in range(w.ndim) if a != channel_axis % w.ndim
+        )
     absmax = jnp.max(jnp.abs(w), axis=reduce_axes, keepdims=True)
     scale = jnp.maximum(absmax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(w / scale), -127, 127).astype(jnp.int8)
@@ -459,3 +468,125 @@ class Int8DenseGeneral(nn.Module):
         )
         axes = self.axis if isinstance(self.axis, tuple) else (self.axis,)
         return _int8_affine(self, x, feats, len(axes), self.use_bias)
+
+
+def _grouped_int8_kernel(te_ref, x_ref, q_ref, sw_ref, out_ref, acc_ref, *,
+                         n_k: int):
+    """One (TM, TN, TK) tile of :func:`grouped_int8_matmul`: the
+    ``_int8_matmul_kernel`` body against the weight block of the row
+    tile's own expert (``te_ref`` only steers the index maps)."""
+    del te_ref
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[:].astype(jnp.float32)  # (TM, TK)
+    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    sx = jnp.maximum(absmax, 1e-8) / 127.0
+    xq = jnp.clip(jnp.round(x / sx), -127, 127).astype(jnp.int8)
+    part = jnp.dot(xq, q_ref[0], preferred_element_type=jnp.int32)
+    acc_ref[:] += part.astype(jnp.float32) * sx
+
+    @pl.when(kk == n_k - 1)
+    def _flush():
+        out_ref[:] = (acc_ref[:] * sw_ref[0]).astype(out_ref.dtype)
+
+
+def _dividing_block(dim: int, cap: int) -> int:
+    """Largest multiple of 128 that divides ``dim`` (itself a multiple of
+    128) and is at most ``cap``."""
+    lanes = dim // 128
+    best = max(f for f in range(1, min(lanes, cap // 128) + 1) if lanes % f == 0)
+    return 128 * best
+
+
+def grouped_int8_matmul(
+    x: jax.Array,
+    q: jax.Array,
+    scale: jax.Array,
+    tile_expert: jax.Array,
+    n_tiles: jax.Array,
+    *,
+    block_m: int,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Row tile ``i`` of ``x`` times the int8 weights of expert
+    ``tile_expert[i]``, for the first ``n_tiles`` row tiles: the grouped
+    product of an expert layer whose rows are sorted by expert, each
+    expert's rows padded to a multiple of ``block_m``
+    (:func:`..models.moe.plan_dispatch` makes the plan).
+
+    ``x``: (M, K) float with M a multiple of ``block_m``; ``q``: (E, K, N)
+    int8 with ``scale`` (E, 1, N) float32 a column; ``tile_expert``:
+    (M // block_m,) int32; ``n_tiles``: int32 scalar, TRACED. The grid's
+    leading bound is ``n_tiles`` itself, so the work is the tiles that hold
+    routed rows, not the static worst case M; rows of the tiles beyond are
+    left unwritten (whatever the buffer held: mask them, do not multiply
+    them by nought). Same numerics as :func:`int8_matmul`: activations
+    rounded to int8 per (row, K tile), int8 x int8 -> int32 on the MXU,
+    float32 accumulation, one scale a column. K and N are padded to
+    multiples of 128 where they are not (toy widths only: a copy of the
+    weights).
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    m, k = x.shape
+    e, kq, n = q.shape
+    assert k == kq and m % block_m == 0, (x.shape, q.shape, block_m)
+    assert tuple(scale.shape) == (e, 1, n), (scale.shape, q.shape)
+    pad_k, pad_n = (-k) % 128, (-n) % 128
+    if pad_k:
+        x = jnp.pad(x, ((0, 0), (0, pad_k)))
+    if pad_k or pad_n:
+        q = jnp.pad(q, ((0, 0), (0, pad_k), (0, pad_n)))
+        scale = jnp.pad(scale, ((0, 0), (0, 0), (0, pad_n)), constant_values=1.0)
+    block_k = _dividing_block(k + pad_k, 2048)
+    block_n = _dividing_block(n + pad_n, 1024)
+    n_k = (k + pad_k) // block_k
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_tiles, (n + pad_n) // block_n, n_k),
+        in_specs=[
+            pl.BlockSpec((block_m, block_k), lambda i, j, kk, te: (i, kk)),
+            pl.BlockSpec(
+                (1, block_k, block_n), lambda i, j, kk, te: (te[i], kk, j)
+            ),
+            pl.BlockSpec((1, 1, block_n), lambda i, j, kk, te: (te[i], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk, te: (i, j)),
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_int8_kernel, n_k=n_k),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n + pad_n), x.dtype),
+        name="grouped_int8_matmul",
+        interpret=interpret,
+    )(tile_expert, x, q, scale)
+    return out[:, :n] if pad_n else out
+
+
+class Int8ExpertStack(nn.Module):
+    """The int8 weights of the experts a chip holds for one projection,
+    ``q`` (experts, k, n) with ``scale`` (experts, 1, n), applied to rows
+    sorted by expert through :func:`grouped_int8_matmul`. Serving only,
+    like :class:`Int8Dense`."""
+
+    experts: int
+    k: int
+    n: int
+
+    @nn.compact
+    def __call__(self, rows, tile_expert, n_tiles, block_m: int):
+        q = self.param(
+            "q", nn.initializers.zeros, (self.experts, self.k, self.n), jnp.int8
+        )
+        scale = self.param(
+            "scale", nn.initializers.ones, (self.experts, 1, self.n),
+            jnp.float32,
+        )
+        return grouped_int8_matmul(
+            rows, q, scale, tile_expert, n_tiles, block_m=block_m
+        )

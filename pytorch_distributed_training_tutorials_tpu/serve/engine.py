@@ -472,6 +472,28 @@ class ServeEngine:
                     kv_cache_dtype="int4" if kv_bits == 4 else jnp.int8,
                 )
             )
+        if getattr(model.cfg, "latent", False):
+            # a latent cache (one [c | k_rope] row a token, shared by all
+            # heads) is known to the whole-slot engine alone so far
+            refused = [
+                what for what, on in (
+                    ("paged=True (the page pool holds heads of K and V)",
+                     paged),
+                    ("prefix_cache_bytes (the prefix cache's segments)",
+                     prefix_cache_bytes > 0),
+                    ("a tensor-parallel strategy (the slot rules shard "
+                     "K and V by head)", self._shard),
+                    ("speculative_k", speculative_k > 0),
+                    ("kv_bits (a quantized cache)", kv_bits is not None),
+                    ("an adapter bank", self._adapters),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    "a model with latent attention (kv_lora_rank > 0) is "
+                    "served from whole slots only; this engine was asked "
+                    "for " + "; ".join(refused)
+                )
         self._kv_bits = {None: 0, "int8": 8, "int4": 4}[
             _kv_quant_mode(model.cfg.kv_cache_dtype)
         ]

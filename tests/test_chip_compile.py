@@ -39,8 +39,12 @@ from pytorch_distributed_training_tutorials_tpu.ops.fused_optim import (
 from pytorch_distributed_training_tutorials_tpu.ops.paged_attention import (
     paged_attention,
 )
+from pytorch_distributed_training_tutorials_tpu.ops.latent_attention import (
+    latent_decode_attention,
+)
 from pytorch_distributed_training_tutorials_tpu.ops.quant import (
     Int8Param,
+    grouped_int8_matmul,
     int8_matmul,
     int8_matmul_tp,
 )
@@ -152,6 +156,41 @@ def test_int8_matmul_compiles(one_chip, m, k, n):
     w = Int8Param(q=_sds((k, n), jnp.int8), scale=_sds((1, n), jnp.float32))
     _compile(
         lambda x, w: int8_matmul(x, w, interpret=False), one_chip, x, w
+    )
+
+
+@pytest.mark.parametrize(
+    "rows,block_m,k,n",
+    [
+        (768, 16, 7680, 2048),  # 64 slots x 8 choices + 16 tiles of padding
+        (768, 16, 2048, 7680),
+        (18432, 128, 7680, 2048),  # a 2,048-token prefill, the worst case
+        (18432, 128, 2048, 7680),
+    ],
+    ids=["decode_up", "decode_down", "prefill_up", "prefill_down"],
+)
+def test_grouped_int8_matmul_compiles(one_chip, rows, block_m, k, n):
+    """16 held experts at openPangu-Ultra-MoE's widths (ISSUE 30): the
+    grid's leading bound is a traced scalar."""
+    _compile(
+        lambda x, q, s, te, nt: grouped_int8_matmul(
+            x, q, s, te, nt, block_m=block_m, interpret=False),
+        one_chip, _sds((rows, k), jnp.bfloat16), _sds((16, k, n), jnp.int8),
+        _sds((16, 1, n), jnp.float32), _sds((rows // block_m,), jnp.int32),
+        _sds((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("layers", [6, 1], ids=["stack", "one_layer"])
+def test_latent_decode_attention_compiles(one_chip, layers):
+    """128 heads over rows of 640 (512 + 64, five lane tiles), 64 slots x
+    4,096 positions, read in the carried stack at a traced layer index."""
+    _compile(
+        lambda q, c, layer, pos: latent_decode_attention(
+            q, c, layer, pos, sm_scale=192 ** -0.5, interpret=False),
+        one_chip, _sds((64, 128, 640), jnp.bfloat16),
+        _sds((layers, 64, 4096, 640), jnp.bfloat16), _sds((), jnp.int32),
+        _sds((64,), jnp.int32),
     )
 
 

@@ -37,6 +37,8 @@ KERNELS = {
     "fused_adamw": "fused_optim.py",
     "paged_attention": "paged_attention.py",
     "int8_matmul": "quant.py",
+    "grouped_int8_matmul": "quant.py",
+    "latent_decode_attention": "latent_attention.py",
 }
 
 
@@ -141,3 +143,53 @@ def test_program_names_are_pinned(engine, trainer, which, module):
     """What the trace's ``XLA Modules`` line and ``hlo_module`` stat carry,
     and a part of the compile cache's key: renamed by nobody."""
     assert f"module @{module} " in _lowered(which, engine, trainer).as_text()
+
+
+def _latent_engine(**kw):
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=128, n_layers=3, n_heads=4, d_ff=128,
+        max_seq_len=32, quantized=True,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, sandwich_norm=True,
+        n_routed_experts=8, experts_held=4, experts_per_token=2,
+        expert_d_ff=128, n_shared_experts=1, **kw,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    return ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+
+
+@pytest.fixture(scope="module")
+def latent_engine():
+    """Latent attention, a leading dense layer and layers of dropless
+    routed experts with a shared one, int8, the layers unrolled: what the
+    benchmark's cell runs (ISSUE 30)."""
+    return _latent_engine(n_dense_layers=1, scan_layers=False)
+
+
+@pytest.mark.parametrize("which", ["chain", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "moe_router", "moe_dispatch", "moe_experts", "moe_shared", "latent_attn",
+    "kv_cache", "mlp",
+])
+def test_latent_and_expert_scopes_are_in_the_lowered_text(
+        latent_engine, which, scope):
+    """What ``moe_share.serve``, ``moe_dispatch_share.serve`` and
+    ``latent_attention_share.serve`` read (``benchmark/layer_metrics``)."""
+    text = _lowered(which, latent_engine, None).as_text(debug_info=True)
+    assert re.search(rf'[/"(]{scope}\)*/', text), scope
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["unrolled", "scan"])
+def test_latent_cache_rows_are_written_under_kv_cache(scan_layers):
+    """A latent cache's new rows go in under ``kv_cache`` and the two new
+    kernels keep their names, unrolled and, for layers of experts alone,
+    under the layer scan (where ``layer_scan_share.*`` tells a layer's own
+    work from the scan's slicing by the scope ``layers``)."""
+    engine = _latent_engine(n_dense_layers=0, scan_layers=scan_layers)
+    text = _lowered("chain", engine, None).as_text(debug_info=True)
+    layer = "layers/block" if scan_layers else "block_2"
+    assert f'{layer}/attn/kv_cache/scatter"' in text
+    assert bool(re.search(r'[/"(]layer_scan\)*/', text)) is scan_layers
+    assert "grouped_int8_matmul" in text and "latent_decode_attention" in text

@@ -276,7 +276,7 @@ def test_quantized_rejects_moe():
         vocab_size=32, d_model=32, n_layers=2, n_heads=2,
         quantized=True, moe_experts=2,
     )
-    with pytest.raises(ValueError, match="dense blocks only"):
+    with pytest.raises(ValueError, match="capacity-dropping MoEFFN"):
         TransformerLM(cfg).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
         )

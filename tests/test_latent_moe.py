@@ -1,0 +1,456 @@
+"""Latent attention, dropless routed experts as one chip's share, sandwich
+norms and leading dense layers (ISSUE 30), at toy widths on the CPU: the
+program against the plain reference of ``benchmark/blocks/mla_moe`` on
+seeded weights, through ``TransformerLM`` and through ``ServeEngine``.
+
+Tolerances, with their reasons:
+
+- float32 weights: 2e-4 of the logits' deviation. Both sides compute in
+  float32, in another order (the program absorbs ``W_ukv`` at decode and
+  sorts rows by expert); seen 2e-6. bfloat16 anywhere (8 bits of mantissa,
+  4e-3 a product) reads two orders above.
+- int8 weights: 0.2 of the deviation at three positions of four. The
+  program rounds each product's input rows to int8 (W8A8, 1 / 254 a value)
+  where the reference keeps float32 activations; seen 0.08-0.11 over the
+  layers of the toy, whose products are 128 wide. That noise moves a router's score by a thousandth, and
+  a token whose second and third scores lie closer than that takes another
+  expert on the two sides (at the toy one or two of 24 tokens): such a
+  position differs by one expert's whole contribution, so every position
+  is held to 3 deviations only. The reference with int4 weights, one
+  precision lower, reads over three times the tolerance (0.77-0.83 seen)
+  at three positions of four. A served token may for the same reason lie
+  up to a deviation under the reference's best (0.59 seen on a flip).
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.lib import harness, serve_kind, weights  # noqa: E402
+from pytorch_distributed_training_tutorials_tpu.models.moe import (  # noqa: E402
+    RoutedExperts,
+    plan_dispatch,
+)
+from pytorch_distributed_training_tutorials_tpu.models.sampling import (  # noqa: E402
+    greedy_token,
+)
+from pytorch_distributed_training_tutorials_tpu.models.transformer import (  # noqa: E402
+    LatentAttention,
+    TransformerConfig,
+    TransformerLM,
+    quantize_lm_params,
+    stack_quantized_lm_params,
+)
+from pytorch_distributed_training_tutorials_tpu.ops.latent_attention import (  # noqa: E402
+    latent_decode_attention,
+    latent_decode_attention_reference,
+)
+from pytorch_distributed_training_tutorials_tpu.ops.quant import (  # noqa: E402
+    grouped_int8_matmul,
+)
+from pytorch_distributed_training_tutorials_tpu.serve import (  # noqa: E402
+    Request,
+    ServeEngine,
+)
+
+CONFIG = "openpangu-ultra-moe-718b-ep16-7of61"
+TOLERANCE = {"float32": 2e-4, "int8": 0.2}
+TOKEN_GAP = {"float32": 1e-3, "int8": 1.0}  # a served token under the best
+
+
+def assert_logits_close(got, want, weights_dtype: str, std: float) -> None:
+    """``got`` (..., V) against ``want`` by the file's tolerances."""
+    err = np.abs(np.asarray(got) - np.asarray(want)).max(-1).reshape(-1)
+    tol = TOLERANCE[weights_dtype] * std
+    if weights_dtype == "float32":
+        assert err.max() <= tol, (err.max(), tol)
+    else:
+        assert np.quantile(err, 0.75) <= tol, (np.sort(err), tol)
+        assert err.max() <= 3 * std, (err.max(), std)
+
+
+def toy_config(**over) -> dict:
+    """The benchmark configuration at its own rehearsal widths."""
+    config = harness.read_json(
+        os.path.join(harness.BENCH, "configs", CONFIG + ".json"))
+    for k, v in config.pop("rehearse").items():
+        config[k] = {**config[k], **v} if isinstance(v, dict) else v
+    config["serve"]["compute_dtype"] = "float32"
+    config["serve"].pop("kv_cache_dtype")
+    config.update(over)
+    return config
+
+
+def build(weights_dtype: str, seed: int = 5, **over):
+    config = toy_config(**over)
+    config["serve"]["weights_dtype"] = weights_dtype
+    block = harness.Block(config["block"])
+    shape = block.reference.Shape.from_config(config)
+    ref_params = weights.make(
+        block.reference.leaf_shapes(shape), seed, weights_dtype, 0.05)
+    model = block.program.model(config, "serve", 64)
+    return block, shape, ref_params, model, block.program.to_program(ref_params, shape)
+
+
+@pytest.mark.parametrize("weights_dtype", ["float32", "int8"])
+def test_prefill_then_decode_through_the_cache_is_the_references_forward(
+        weights_dtype):
+    block, shape, ref_params, model, params = build(weights_dtype)
+    tokens = np.random.default_rng(3).integers(0, 512, 24)
+    want = np.asarray(block.reference.logits(ref_params, jnp.asarray(tokens), shape))
+    std = float(want.std())
+
+    full = model.apply({"params": params}, jnp.asarray(tokens)[None])[0]
+    assert_logits_close(full, want, weights_dtype, std)
+
+    n_prompt = 15
+    logits, upd = model.apply(
+        {"params": params}, jnp.asarray(tokens[:n_prompt])[None], prefill=True,
+        mutable=["cache"])
+    served = [logits[0, -1]]
+    cache = upd["cache"]
+    step = jax.jit(lambda p, c, t: model.apply(
+        {"params": p, "cache": c}, t, decode=True, mutable=["cache"]))
+    for i in range(n_prompt, len(tokens)):  # one token at a time: the kernel
+        logits, upd = step(params, cache, jnp.asarray(tokens[i:i + 1])[None])
+        cache = upd["cache"]
+        served.append(logits[0, 0])
+    assert_logits_close(np.stack(served), want[n_prompt - 1:], weights_dtype, std)
+    # and the control stands clear: int4 weights read far over the tolerance
+    if weights_dtype == "int8":
+        low = block.reference.logits(
+            ref_params, jnp.asarray(tokens), shape, weight_bits=4)
+        err = np.abs(np.asarray(low) - want).max(-1)
+        assert np.quantile(err, 0.25) > 3 * TOLERANCE["int8"] * std
+
+
+@pytest.mark.parametrize("weights_dtype", ["float32", "int8"])
+def test_serve_engine_serves_the_references_tokens(weights_dtype):
+    block, shape, ref_params, model, params = build(weights_dtype)
+    engine = ServeEngine(model, params, n_slots=3, tokens_per_launch=4)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (9, 17, 30, 12, 21)]
+    for p in prompts:
+        engine.submit(Request(prompt=p, max_new_tokens=7))
+    done = {tuple(c.prompt): c for c in engine.run_until_idle()}
+    assert len(done) == len(prompts)
+    served = [(list(p), list(done[tuple(p)].tokens)) for p in prompts]
+    assert all(len(t) == 7 and max(t) < 512 for _, t in served)
+    gaps, compared = serve_kind.token_gaps(block, shape, ref_params, served, 64)
+    assert compared == 35
+    # a served token is the reference's best or within the tolerance of it
+    assert max(gaps) <= TOKEN_GAP[weights_dtype], gaps
+
+
+def latent_cfg(**over):
+    kw = dict(
+        vocab_size=64, d_model=64, n_layers=2, n_heads=4, max_seq_len=32,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, norm_eps=1e-5, rope_theta=1e4)
+    kw.update(over)
+    return TransformerConfig(**kw)
+
+
+def test_absorbed_decode_equals_the_up_projected_path():
+    """One layer of latent attention three ways: K and V up-projected for
+    every position (no cache), a chunk continued through the cache (plain
+    einsums over the latents) and a token at a time (the kernel)."""
+    cfg = latent_cfg()
+    attn = LatentAttention(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 64))
+    params = attn.init(jax.random.PRNGKey(1), x)["params"]
+    want = attn.apply({"params": params}, x)
+    out, upd = attn.apply({"params": params}, x[:, :5], prefill=True, mutable=["cache"])
+    np.testing.assert_allclose(out, want[:, :5], atol=1e-5)
+    cache = jax.tree_util.tree_map(lambda a: a, upd["cache"])
+    assert cache["cached_latent"].shape == (2, 32, 128)  # 24 -> a lane tile
+    out, upd = attn.apply(  # a chunk of four: S > 1
+        {"params": params, "cache": cache}, x[:, 5:9], decode=True, mutable=["cache"])
+    np.testing.assert_allclose(out, want[:, 5:9], atol=1e-5)
+    cache = upd["cache"]
+    for i in range(9, 12):  # S == 1
+        out, upd = attn.apply(
+            {"params": params, "cache": cache}, x[:, i:i + 1], decode=True,
+            mutable=["cache"])
+        cache = upd["cache"]
+        np.testing.assert_allclose(out, want[:, i:i + 1], atol=1e-5)
+    assert int(cache["cache_index"]) == 12
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_latent_decode_kernel_is_its_reference(dtype):
+    rng = np.random.default_rng(0)
+    L, B, W, C, H = 3, 4, 256, 128, 8
+    cache = jnp.asarray(rng.normal(size=(L, B, W, C)), dtype)
+    q = jnp.asarray(rng.normal(size=(B, H, C)), dtype)
+    pos = jnp.asarray([0, 5, 130, 255], jnp.int32)
+    got = latent_decode_attention(
+        q, cache, jnp.int32(1), pos, sm_scale=0.1, block_w=128)
+    valid = (jnp.arange(W)[None, None, :] <= pos[:, None, None])
+    want = latent_decode_attention_reference(
+        q[:, None], cache[1], valid, sm_scale=0.1)[:, 0]
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2  # bf16 weights on the rows
+    np.testing.assert_allclose(got, want, atol=tol)
+
+
+def routed(held=4, offset=0, quantized=False, **kw):
+    return RoutedExperts(
+        n_routed=8, held=held, offset=offset, top_k=2, d_ff=128, scaling=2.5,
+        quantized=quantized, **kw)
+
+
+def dense_routed(x, p, lo, hi, top_k=2, scaling=2.5):
+    """``sum_{i in top-k, lo <= i < hi} g_i E_i(x)`` a token at a time."""
+    scores = jax.nn.sigmoid(x @ p["router"])
+    top, ids = jax.lax.top_k(scores, top_k)
+    gate = scaling * top / (top.sum(-1, keepdims=True) + 1e-20)
+    out = jnp.zeros_like(x)
+    for e in range(lo, hi):
+        w = jnp.sum(jnp.where(ids == e, gate, 0.0), -1, keepdims=True)
+        h = jax.nn.silu(x @ p["w_gate"][e - lo]) * (x @ p["w_up"][e - lo])
+        out = out + w * (h @ p["w_down"][e - lo])
+    return out
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_no_token_is_dropped_when_every_token_picks_the_same_held_experts(
+        quantized):
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 40, 128))
+    layer = routed(held=4)
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    # a router that sends every token to held experts 1 and 2: the worst
+    # case, 240 pairs on two experts, 15 times what they expect
+    router = jnp.full((128, 8), 0.0).at[:, 1].set(0.05).at[:, 2].set(0.04)
+    params = dict(params, router=jnp.abs(router) * jnp.sign(x.mean()))
+    x = jnp.abs(x)  # so that x @ router is largest on experts 1 and 2
+    params["router"] = jnp.abs(router)
+    want = dense_routed(x, params, 0, 4)
+    assert float(jnp.abs(want).min(-1).max()) > 0  # every token routed here
+    if quantized:
+        qp = quantize_lm_params({"moe": params})["moe"]
+        got = routed(held=4, quantized=True).apply({"params": qp}, x)
+        tol = 0.15 * float(jnp.std(want))  # W8A8 against float32, 3 products
+    else:
+        got = layer.apply({"params": params}, x)
+        tol = 1e-4 * float(jnp.std(want))
+    np.testing.assert_allclose(got, want, atol=tol)
+    # none left out: each token's result is the sum of BOTH its experts
+    ids = jax.lax.top_k(jax.nn.sigmoid(x @ params["router"]), 2)[1]
+    assert set(np.unique(np.asarray(ids))) == {1, 2}
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four chips hold two of the eight experts each. The parts their
+    shares give, the shared expert counted once, are the uncut layer, which
+    is the uncut reference's."""
+    config = toy_config(n_routed_experts=8)  # the reference, uncut
+    block = harness.Block(config["block"])
+    shape = block.reference.Shape.from_config(config)
+    name = block.reference.layer_name(shape.num_hidden_layers - 1)
+    spec = block.reference.leaf_shapes(shape)[name]
+    lp = weights.make({name: spec}, 7, "float32", 0.05)[name]
+    m = jax.random.normal(jax.random.PRNGKey(2), (48, 128))
+    lin = lambda x, w: x @ w  # noqa: E731
+    want = block.reference.routed_ffn(m, lp, shape, lin)
+    shared = block.reference.swiglu(
+        m, lp["shared_gate"], lp["shared_up"], lp["shared_down"], lin)
+
+    parts = []
+    for chip in range(4):
+        lo = 2 * chip
+        params = {
+            "router": lp["router"],
+            "w_gate": lp["experts_gate"][lo:lo + 2],
+            "w_up": lp["experts_up"][lo:lo + 2],
+            "w_down": lp["experts_down"][lo:lo + 2],
+        }
+        parts.append(routed(held=2, offset=lo).apply({"params": params}, m))
+        # and the reference given the same share gives the same part
+        share = dataclasses.replace(shape, n_routed_experts=2, expert_offset=lo)
+        ref_part = block.reference.routed_ffn(
+            m, dict(lp, experts_gate=params["w_gate"], experts_up=params["w_up"],
+                    experts_down=params["w_down"]), share, lin) - shared
+        np.testing.assert_allclose(parts[-1], ref_part, atol=1e-5)
+    assert all(float(jnp.abs(p).max()) > 0 for p in parts)
+    np.testing.assert_allclose(sum(parts) + shared, want, atol=2e-5)
+    whole = {"router": lp["router"], "w_gate": lp["experts_gate"],
+             "w_up": lp["experts_up"], "w_down": lp["experts_down"]}
+    np.testing.assert_allclose(
+        routed(held=8).apply({"params": whole}, m) + shared, want, atol=2e-5)
+
+
+def test_plan_dispatch_gives_every_held_pair_a_row_of_its_experts_tile():
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 8, (37, 2)), jnp.int32)
+    plan = plan_dispatch(ids, held=3, offset=2, block_m=8)
+    row, here = np.asarray(plan["row"]), np.asarray(plan["here"])
+    local = np.asarray(ids).reshape(-1) - 2
+    assert (here == ((local >= 0) & (local < 3))).all()
+    rows = row[here]
+    assert len(set(rows)) == len(rows) == here.sum()  # a row a pair
+    assert (row[~here] == len(np.asarray(plan["row_token"]))).all()
+    tiles = np.asarray(plan["tile_expert"])
+    assert (tiles[rows // 8] == local[here]).all()
+    assert (np.asarray(plan["row_token"])[rows] == np.nonzero(here)[0] // 2).all()
+    assert int(plan["n_tiles"]) == sum(-(-int((local == e).sum()) // 8) for e in range(3))
+
+
+def test_grouped_int8_matmul_runs_the_tiles_in_use_alone():
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.integers(-127, 128, (3, 128, 256)), jnp.int8)
+    scale = jnp.asarray(rng.uniform(0.5, 1.5, (3, 1, 256)) * 1e-2, jnp.float32)
+    x = jnp.asarray(rng.normal(size=(64, 128)), jnp.float32)
+    tiles = jnp.asarray([0, 2, 2, 1], jnp.int32)
+    out = grouped_int8_matmul(x, q, scale, tiles, jnp.int32(3), block_m=16)
+    for i, e in enumerate([0, 2, 2]):
+        want = x[16 * i:16 * i + 16] @ (q[e].astype(jnp.float32) * scale[e])
+        np.testing.assert_allclose(
+            out[16 * i:16 * i + 16], want, atol=0.05 * float(jnp.std(want)))
+
+
+@pytest.mark.parametrize("kw,word", [
+    (dict(paged=True, page_size=8, pool_pages=16), "paged"),
+    (dict(prefix_cache_bytes=1 << 20), "prefix"),
+    (dict(speculative_k=2), "speculative"),
+    (dict(kv_bits=8), "kv_bits"),
+    ("tp", "tensor-parallel"),
+])
+def test_an_engine_that_cannot_hold_a_latent_cache_refuses_in_words(kw, word):
+    cfg = latent_cfg(scan_layers=True)
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    if kw == "tp":
+        from pytorch_distributed_training_tutorials_tpu import create_mesh
+        from pytorch_distributed_training_tutorials_tpu.parallel import (
+            TensorParallel,
+        )
+
+        from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+            TP_RULES,
+        )
+
+        kw = dict(strategy=TensorParallel(
+            create_mesh({"data": 4, "model": 2}), TP_RULES))
+    with pytest.raises(ValueError, match="latent attention.*whole slots.*" + word):
+        ServeEngine(model, params, n_slots=2, **kw)
+
+
+def test_a_model_that_cannot_run_latent_or_routed_says_so():
+    toks = jnp.zeros((1, 4), jnp.int32)
+    with pytest.raises(ValueError, match="paged KV cache"):
+        TransformerLM(latent_cfg(kv_pages=4, kv_page_size=8)).init(
+            jax.random.PRNGKey(0), toks)
+    with pytest.raises(ValueError, match="experts_held"):
+        TransformerLM(TransformerConfig(
+            n_routed_experts=8, experts_held=4, expert_offset=6,
+            experts_per_token=2, expert_d_ff=32)).init(jax.random.PRNGKey(0), toks)
+    with pytest.raises(ValueError, match="capacity-dropping"):
+        TransformerLM(TransformerConfig(moe_experts=4, quantized=True)).init(
+            jax.random.PRNGKey(0), toks)
+
+
+def test_a_model_with_both_kinds_of_layer_refuses_the_layer_scan_in_words():
+    """One ``nn.scan`` is layers of one kind: leading dense layers before
+    layers of routed experts run unrolled, as the benchmark's cell does."""
+    config = toy_config()
+    block = harness.Block(config["block"])
+    cfg = block.program.model(config, "serve", 32).cfg
+    assert cfg.n_dense_layers == 1 and not cfg.scan_layers
+    toks = jnp.zeros((1, 8), jnp.int32)
+    with pytest.raises(ValueError, match="scan_layers=True.*one kind.*unrolled"):
+        TransformerLM(dataclasses.replace(cfg, scan_layers=True)).init(
+            jax.random.PRNGKey(0), toks)
+    # and the unrolled tree of such a model does not stack
+    params = TransformerLM(dataclasses.replace(cfg, quantized=False)).init(
+        jax.random.PRNGKey(0), toks)["params"]
+    with pytest.raises(ValueError, match="more than one kind"):
+        stack_quantized_lm_params(quantize_lm_params(params))
+
+
+@pytest.mark.parametrize("scan_layers,n_dense", [(True, 0), (False, 0), (False, 1)])
+def test_quantize_lm_params_gives_the_quantized_models_tree(scan_layers, n_dense):
+    config = toy_config()
+    block = harness.Block(config["block"])
+    cfg = dataclasses.replace(
+        block.program.model(config, "serve", 32).cfg, quantized=False,
+        scan_layers=scan_layers, n_dense_layers=n_dense)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    params = TransformerLM(cfg).init(jax.random.PRNGKey(0), toks)["params"]
+    qcfg = dataclasses.replace(cfg, quantized=True)
+    got = quantize_lm_params(params)
+    want = jax.eval_shape(TransformerLM(qcfg).init, jax.random.PRNGKey(0), toks)["params"]
+    shapes = lambda t: jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), t)  # noqa: E731
+    assert shapes(got) == shapes(jax.tree_util.tree_map(lambda a: a, dict(want)))
+    f32 = TransformerLM(cfg).apply({"params": params}, toks + 3)
+    int8 = TransformerLM(qcfg).apply({"params": got}, toks + 3)
+    assert float(jnp.abs(f32 - int8).max()) < 0.3 * float(jnp.std(f32))
+    if not scan_layers and not n_dense:  # layers of one kind stack into the scan
+        stacked = stack_quantized_lm_params(got)
+        scfg = dataclasses.replace(qcfg, scan_layers=True)
+        want = jax.eval_shape(TransformerLM(scfg).init, jax.random.PRNGKey(0), toks)
+        assert shapes(stacked) == shapes(
+            jax.tree_util.tree_map(lambda a: a, dict(want["params"])))
+        np.testing.assert_allclose(
+            TransformerLM(scfg).apply({"params": stacked}, toks + 3), int8, atol=1e-5)
+
+
+@pytest.mark.parametrize("weights_dtype", ["float32", "int8"])
+def test_the_scanned_expert_layers_serve_what_the_unrolled_ones_do(weights_dtype):
+    """Layers of routed experts alone under the layer scan, the latent
+    cache carried as one stack (the kernel reads its layer's rows in it):
+    ``ServeEngine`` serves the tokens the unrolled model serves."""
+    block, shape, ref_params, model, params = build(
+        weights_dtype, first_k_dense_replace=0)
+    stacked = stack_quantized_lm_params(params)
+    scanned = TransformerLM(dataclasses.replace(model.cfg, scan_layers=True))
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (9, 17, 30, 12)]
+    served = []
+    for m, p in ((model, params), (scanned, stacked)):
+        engine = ServeEngine(m, p, n_slots=3, tokens_per_launch=4)
+        for prompt in prompts:
+            engine.submit(Request(prompt=prompt, max_new_tokens=6))
+        done = {tuple(c.prompt): list(c.tokens) for c in engine.run_until_idle()}
+        served.append([done[tuple(prompt)] for prompt in prompts])
+    assert served[0] == served[1]
+    pairs = [(p, t) for p, t in zip(prompts, served[1])]
+    gaps, compared = serve_kind.token_gaps(block, shape, ref_params, pairs, 64)
+    assert compared == 24 and max(gaps) <= TOKEN_GAP[weights_dtype], gaps
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_greedy_token_is_the_lowest_index_maximum_and_never_the_sentinel(dtype):
+    rng = np.random.default_rng(0)
+    logits = jnp.asarray(rng.normal(size=(6, 1000)), dtype)
+    logits = logits.at[0, 7].set(50.0).at[0, 400].set(50.0)  # an exact tie
+    logits = logits.at[1, :].set(0.25)  # all equal
+    got = np.asarray(jax.jit(greedy_token)(logits))
+    want = np.asarray(logits.astype(jnp.float32)).argmax(-1)  # first occurrence
+    assert got.dtype == np.int32 and (got == want).all()
+    assert got[0] == 7 and got[1] == 0 and (got < 1000).all()
+    # through a rounding the compiler may or may not keep (the chip's fault)
+    head = jnp.asarray(rng.normal(size=(64, 1000)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(6, 64)), jnp.float32)
+    served = jax.jit(
+        lambda x: greedy_token((x @ head).astype(dtype).astype(jnp.float32)))(x)
+    assert (np.asarray(served) < 1000).all()
+
+
+SPEC = harness.read_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+
+
+@pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
+def test_benchmark_configuration_resolves_to_a_block_with_the_programs_leaves(entry):
+    """``benchmark/tests/test_blocks.py``'s check of every configuration,
+    counted among the tier-1 tests (PERF.md Open question 6)."""
+    from benchmark.tests import test_blocks
+
+    test_blocks.test_configuration_resolves_to_a_block_with_the_programs_leaves(entry)
