@@ -599,7 +599,7 @@ def main():
 
     params = device_materialize(params)
     serve_cfg = dataclasses.replace(
-        cfg, quantized=True, int8_mesh=mesh, scan_layers=scan_layers
+        cfg, quantized=True, tp_mesh=mesh, scan_layers=scan_layers
     )
     lm = TransformerLM(serve_cfg)
     receipt["scan_layers"] = scan_layers
@@ -722,7 +722,7 @@ def _serving_strategy(lm):
     each chip holds 1/tp of the cache and the decode chain's only
     collectives are the forward's existing all-reduces. None (the
     replicated engine, byte-identical off-path) without a model axis."""
-    mesh = getattr(lm.cfg, "int8_mesh", None)
+    mesh = getattr(lm.cfg, "tp_mesh", None)
     if mesh is None or mesh.shape.get("model", 1) <= 1:
         return None
     from pytorch_distributed_training_tutorials_tpu.models.transformer import (

@@ -127,15 +127,20 @@ class TransformerConfig:
     # traced-control-flow pins the anti-pattern). Decode-only, like
     # kv_pages itself; requires kv_pages > 0 to have any effect.
     paged_kernel: bool = False
-    # Tensor-parallel int8 serving: a mesh with a 'model' axis routes every
-    # quantized matmul through the shard_map-wrapped kernel
-    # (ops.quant.int8_matmul_tp) in the Megatron column/row layout; q/scale
-    # params shard per INT8_TP_RULES. Requires n_heads, ff_dim, vocab_size
-    # and d_model divisible by the model-axis size (and n_kv_heads for a
-    # GQA model; a non-divisible dim falls back to replication under the
-    # float TP rules — parallel.tensor_parallel.spec_for_path drops the
-    # axis shape-aware). None = single-device / replicated serving.
-    int8_mesh: "jax.sharding.Mesh | None" = None
+    # The mesh a tensor-parallel model is served under (None = one device,
+    # or replicated): what the model's Pallas kernels look at, since a bare
+    # pallas_call is refused on operands GSPMD has sharded. ServeEngine
+    # sets it from its strategy (tp > 1); a caller that runs generate() on
+    # sharded params sets it themselves. Under it the decode step keeps
+    # the plain head-sharded einsums (ops.decode_attention is not called),
+    # and a quantized model (int8 serving) routes every matmul through the
+    # shard_map-wrapped kernel (ops.quant.int8_matmul_tp) in the Megatron
+    # column/row layout, with q/scale params sharded per INT8_TP_RULES:
+    # that requires n_heads, ff_dim, vocab_size and d_model divisible by
+    # the model-axis size (and n_kv_heads for a GQA model; a non-divisible
+    # dim falls back to replication under the float TP rules —
+    # parallel.tensor_parallel.spec_for_path drops the axis shape-aware).
+    tp_mesh: "jax.sharding.Mesh | None" = None
     # Multi-tenant LoRA (adapters/): > 0 equips every attention/MLP
     # projection with a stacked (lora_adapters, ..., lora_rank) delta bank
     # gathered per batch row by an adapter-id VECTOR inside the compiled
@@ -542,6 +547,25 @@ def rewind_cache_index(cache, steps):
     return jax.tree_util.tree_map_with_path(upd, cache)
 
 
+def park_cache_index(cache, parked, window: int):
+    """Set every ``cache_index`` counter of the rows where ``parked``
+    ``(B,)`` is true to ``window``, one past the last position: the depth
+    at which a row's writes already drop (``_store_decode_kv``) and which
+    ``ops.decode_attention`` reads as "nothing here", so a slot that holds
+    no live sequence costs a decode step no rows of its cache. The serving
+    chain (``ServeEngine._chain_impl``) applies it before every step to the
+    slots without budget; a refill writes the slot's real depth
+    (``serve.slots.write_slot``). ``parked`` broadcasts over the leading
+    layer axis of ``scan_layers``-stacked ``(L, B)`` counters."""
+
+    def upd(path, leaf):
+        if _is_cache_index(path):
+            return jnp.where(parked, jnp.asarray(window, leaf.dtype), leaf)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(upd, cache)
+
+
 def widen_cache_index(cache, n_rows: int):
     """Widen scalar ``cache_index`` counters to per-row ``(n_rows,)``
     vectors (trailing axis — ``(L,) -> (L, n_rows)`` under
@@ -761,11 +785,11 @@ class Attention(nn.Module):
             # (its input arrives head-sharded) with one psum per branch
             proj = lambda name, heads: Int8DenseGeneral(  # noqa: E731
                 (heads, d), axis=-1, use_bias=False, name=name,
-                mesh=cfg.int8_mesh, shard_kind="column",
+                mesh=cfg.tp_mesh, shard_kind="column",
             )
             out_proj = Int8DenseGeneral(
                 cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj",
-                mesh=cfg.int8_mesh, shard_kind="row",
+                mesh=cfg.tp_mesh, shard_kind="row",
             )
         else:
             proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
@@ -892,35 +916,65 @@ class Attention(nn.Module):
             if quant:
                 _store_decode_kv(k_scale, k_s, pos, layer)
                 _store_decode_kv(v_scale, v_s, pos, layer)
-            with jax.named_scope("kv_cache"):
-                k_read = _decode_kv(
-                    _layer_value(cached_k, layer),
-                    _layer_value(k_scale, layer) if quant else None,
-                    quant, k.dtype,
-                )
-                v_read = _decode_kv(
-                    _layer_value(cached_v, layer),
-                    _layer_value(v_scale, layer) if quant else None,
-                    quant, v.dtype,
-                )
             _store_cache_index(idx, pos + s, layer)
-            # attend over the whole cache: query token i (global position
-            # pos + i) masks positions beyond pos + i — same math as
-            # training/prefill (a masked-out cache column contributes an
-            # exact softmax zero, so window-vs-prompt-sized reductions
-            # agree bitwise). GQA: the cache holds kv_heads and is read
-            # UN-expanded (grouped einsums) — per-step cache traffic
-            # scales with n_kv_heads, the point of the layout
-            qpos = (pos[..., None] if pos.ndim else pos) + jnp.arange(s)
-            valid = (
-                jnp.arange(cfg.max_seq_len) <= qpos[..., :, None]
-            )  # (S, max_len) shared — or (B, S, max_len) per slot
-            if valid.ndim == 2:
-                valid = valid[None]
-            out = grouped_masked_attention(
-                q, k_read, v_read,
-                valid[:, None, :, :],
+            from pytorch_distributed_training_tutorials_tpu.ops.decode_attention import (  # noqa: E501
+                decode_attention,
+                decode_block,
             )
+
+            if (
+                s == 1 and quant is None and cfg.tp_mesh is None
+                and decode_block(
+                    cfg.max_seq_len, kv, d, cached_k.value.dtype
+                )
+            ):
+                # a step, a cache stored as floats, no mesh, tiles the
+                # kernel takes: K and V are read where they lie, in the
+                # carried stack, up to each row's own depth; a row whose
+                # depth is the window (park_cache_index) reads nothing
+                with jax.named_scope("decode_attn"):
+                    k_all, v_all = cached_k.value, cached_v.value
+                    out = decode_attention(
+                        q[:, 0],
+                        k_all if layer is not None else k_all[None],
+                        v_all if layer is not None else v_all[None],
+                        0 if layer is None else layer,
+                        jnp.broadcast_to(pos, (b,)),
+                    )[:, None]
+            else:
+                # everything else (a chunk of several positions, int8 and
+                # int4 storage, tensor-parallel serving, head widths that
+                # are no whole lane tiles): plain einsums over a copy of
+                # the layer's window
+                with jax.named_scope("kv_cache"):
+                    k_read = _decode_kv(
+                        _layer_value(cached_k, layer),
+                        _layer_value(k_scale, layer) if quant else None,
+                        quant, k.dtype,
+                    )
+                    v_read = _decode_kv(
+                        _layer_value(cached_v, layer),
+                        _layer_value(v_scale, layer) if quant else None,
+                        quant, v.dtype,
+                    )
+                # attend over the whole cache: query token i (global
+                # position pos + i) masks positions beyond pos + i — same
+                # math as training/prefill (a masked-out cache column
+                # contributes an exact softmax zero, so window-vs-prompt-
+                # sized reductions agree bitwise). GQA: the cache holds
+                # kv_heads and is read UN-expanded (grouped einsums) —
+                # per-step cache traffic scales with n_kv_heads, the point
+                # of the layout
+                qpos = (pos[..., None] if pos.ndim else pos) + jnp.arange(s)
+                valid = (
+                    jnp.arange(cfg.max_seq_len) <= qpos[..., :, None]
+                )  # (S, max_len) shared — or (B, S, max_len) per slot
+                if valid.ndim == 2:
+                    valid = valid[None]
+                out = grouped_masked_attention(
+                    q, k_read, v_read,
+                    valid[:, None, :, :],
+                )
         else:
             q = apply_rope(q_raw, cfg.rope_theta)
             k = apply_rope(k_raw, cfg.rope_theta)
@@ -1241,7 +1295,7 @@ class SwiGLU(nn.Module):
             # gate/up column-split over d_ff, down row-split (Megatron MLP)
             dense = lambda f, name, kind: Int8Dense(  # noqa: E731
                 f, use_bias=False, name=name,
-                mesh=cfg.int8_mesh, shard_kind=kind,
+                mesh=cfg.tp_mesh, shard_kind=kind,
             )
         else:
             dense = lambda f, name, kind: nn.Dense(  # noqa: E731
@@ -1306,7 +1360,7 @@ def _check_new_block_fields(cfg: TransformerConfig) -> None:
             )
         for field, what in (
             ("kv_pages", "a paged KV cache"),
-            ("int8_mesh", "tensor-parallel int8 serving"),
+            ("tp_mesh", "tensor-parallel serving"),
             ("lora_adapters", "LoRA adapters"),
             ("attention_fn", "a custom attention_fn"),
         ):
@@ -1345,7 +1399,9 @@ def _check_new_block_fields(cfg: TransformerConfig) -> None:
                 "before its layers of routed experts runs unrolled "
                 "(scan_layers=False)"
             )
-        if cfg.lora_adapters or cfg.int8_mesh is not None:
+        if cfg.lora_adapters or (
+            cfg.quantized and cfg.tp_mesh is not None
+        ):
             raise ValueError(
                 "the dropless expert layer runs with neither LoRA adapters "
                 "nor tensor-parallel int8 serving"
@@ -1593,7 +1649,7 @@ class TransformerLM(nn.Module):
 
             return Int8Dense(
                 cfg.vocab_size, use_bias=False, name="lm_head",
-                mesh=cfg.int8_mesh, shard_kind="column",
+                mesh=cfg.tp_mesh, shard_kind="column",
             )(x)
         return nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="lm_head"
@@ -1858,7 +1914,7 @@ def load_quantized_lm(path, mesh=None, *, materialize=True):
     as soon as it is produced — the ``device_map="auto"`` + 8-bit + *bigger
     than one chip* combination: host peak stays one-leaf-bounded AND no
     device ever holds more than its 1/M shard of the int8 weights. Pass
-    ``dataclasses.replace(cfg, quantized=True, int8_mesh=mesh)`` to serve.
+    ``dataclasses.replace(cfg, quantized=True, tp_mesh=mesh)`` to serve.
     """
     import orbax.checkpoint as ocp
 

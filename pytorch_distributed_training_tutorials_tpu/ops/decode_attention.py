@@ -1,0 +1,205 @@
+"""Decode attention over the carried K and V stacks, read where they lie.
+
+``Attention``'s decode branch (``models/transformer.py``) keeps K and V as
+``(B, W, KV, D)`` a layer, stacked ``(L, B, W, KV, D)`` when the layer scan
+carries the cache. Written as plain einsums, a step copies the layer's
+slice of each out of the stack (a ``dynamic-slice`` the size of the slice)
+and then reads the copy twice, over the whole window whatever the slots'
+depths: at 32 slots x 2,048 positions some 0.8 GB of traffic a layer where
+the live slots' rows are a twentieth of that.
+
+This kernel walks ``(slot, block of positions)`` with the layer index and
+the slots' depths as **scalar-prefetch** operands, as
+:mod:`.latent_attention` does for a latent cache: the K and V block index
+maps point into the stacks at ``[layer, slot, block]`` (no slice is
+copied), a block past a slot's depth is neither fetched nor computed, and
+the softmax runs as the streaming ``(m, l, acc)`` recurrence in float32.
+**A slot that holds no live sequence costs no rows**: the depth ``W`` (one
+past the last position, where a slot's writes already drop) means
+"nothing here", the slot's index map repeats a block the pipeline already
+holds and its result is zeros (``ServeEngine``'s chain pins the depth of a
+slot without budget there: ``park_cache_index``).
+
+A block is taken as stored, ``(rows, KV, D)``: its ``(KV, D)`` slabs are the
+tiles the chip keeps (``(8, 128)`` in bfloat16), so reshaping or transposing
+the stack outside the kernel would copy all of it. Inside, the block is the
+matrix ``(rows * KV, D)`` and **every query head multiplies every KV head's
+rows**; the scores of the wrong heads are masked like the rows past the
+depth. The rows pass the matrix unit once either way (a product of ``H / KV``
+query rows a head loads the same tiles), the softmax works on ``KV`` times
+the numbers, and that measured twice as fast as reading each head's rows out
+of the block by a strided load (``PERF.md`` section 6, PR 31).
+
+One query position a slot (the serving chain's step, ``generate()``'s
+step). The products take their operands at the cache's dtype and
+accumulate in float32, as the plain einsums do on the chip; the plain path
+(:func:`..models.transformer.grouped_masked_attention`) is this kernel's
+numerics reference.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = float("-inf")  # plain float: no jax arrays at import time
+
+_BLOCK_BYTES = 1 << 20  # K and V, two buffers each: 4 MiB of VMEM
+
+
+def decode_block(w: int, kv: int, d: int, dtype) -> int | None:
+    """Rows of a block for a window of ``w`` positions of ``(kv, d)`` at
+    ``dtype``: the largest of 512, 256, 128 that divides ``w`` and keeps a
+    block at most 1 MiB (512 for 8 bfloat16 heads of 128; blocks of 256
+    measured the same there, of 1,024 a third slower). None where the
+    kernel takes no such cache: ``d`` not a whole number of 128-lane tiles,
+    or no block that fits. The kernel's every-head-times-every-KV-head
+    form does ``kv`` times the score and softmax work: it was measured at
+    8 KV heads (bfloat16) and 16 (float32), where the block's DMA holds
+    the time; a cache of more KV heads has no measurement behind it."""
+    if d % 128:
+        return None
+    row_bytes = kv * d * jnp.dtype(dtype).itemsize
+    for rows in (512, 256, 128):
+        if w % rows == 0 and rows * row_bytes <= _BLOCK_BYTES:
+            return rows
+    return None
+
+
+def block_bounds(pos: jax.Array, w: int, block_w: int):
+    """Per slot, the slot whose blocks it fetches and the last block index
+    it may ask for: a live slot its own blocks up to ``pos // block_w``; a
+    dead one (``pos >= w``) that one block only, of the live slot before it
+    (the first block of the live slot after it where none is before), which
+    the pipeline holds already, so nothing new is fetched."""
+    b = pos.shape[0]
+    live = pos < w
+    idx = jnp.arange(b, dtype=jnp.int32)
+    prev = jax.lax.cummax(jnp.where(live, idx, -1))
+    nxt = jax.lax.cummin(jnp.where(live, idx, b), reverse=True)
+    last = jnp.where(live, pos // block_w, 0)
+    src = jnp.where(prev >= 0, prev, jnp.where(nxt < b, nxt, 0))
+    hi = jnp.where(prev >= 0, last[jnp.maximum(prev, 0)], 0)
+    return src, hi
+
+
+def decode_attention(
+    q: jax.Array,
+    k_stack: jax.Array,
+    v_stack: jax.Array,
+    layer: jax.Array,
+    pos: jax.Array,
+    *,
+    block_w: int | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """``softmax(q k^T / sqrt(D)) v`` a slot, over the slot's rows
+    ``[0, pos]`` of ``k_stack[layer]`` and ``v_stack[layer]``.
+
+    ``q``: (B, H, D), one query position a slot; ``k_stack``, ``v_stack``:
+    (L, B, W, KV, D) with H a multiple of KV (head ``h`` reads KV head
+    ``h // (H / KV)``); ``layer``: int32 scalar and ``pos``: (B,) int32,
+    both traced. Position ``t`` is attended iff ``t <= pos[b]`` (the new
+    token's own row is written before the call); ``pos[b] >= W`` is a slot
+    with nothing in it: no row of it is read and its result is zeros.
+    Returns (B, H, D) at ``q``'s dtype. ``block_w`` (:func:`decode_block`'s
+    by default) must divide ``W``.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, d = q.shape
+    n_layers, bc, w, kv, dc = k_stack.shape
+    assert v_stack.shape == k_stack.shape and (bc, dc) == (b, d), (
+        q.shape, k_stack.shape, v_stack.shape
+    )
+    assert h % kv == 0, (h, kv)
+    grp = h // kv
+    if block_w is None:
+        block_w = decode_block(w, kv, d, k_stack.dtype)
+    assert block_w and w % block_w == 0 and d % 128 == 0, (w, block_w, d)
+    n_blocks = w // block_w
+    n = block_w * kv  # a block as a matrix: row t * kv + c is KV head c at t
+    # the CPU backend has no bf16 x bf16 -> f32 product of this form: the
+    # interpreter computes in float32 what the chip computes from bfloat16
+    compute = jnp.float32 if interpret else k_stack.dtype
+    sm_scale = d ** -0.5
+    pos = pos.astype(jnp.int32)
+    src, hi = block_bounds(pos, w, block_w)
+
+    def kernel(layer_ref, pos_ref, src_ref, hi_ref,
+               q_ref, k_ref, v_ref, o_ref, acc, m, l):
+        del layer_ref, src_ref, hi_ref
+        bb, j = pl.program_id(0), pl.program_id(1)
+        depth = pos_ref[bb]
+
+        @pl.when(j == 0)
+        def _init():
+            acc[:] = jnp.zeros_like(acc)
+            m[:] = jnp.full_like(m, NEG_INF)
+            l[:] = jnp.zeros_like(l)
+
+        @pl.when(jnp.logical_and(depth < w, j * block_w <= depth))
+        def _block():
+            k2 = k_ref[0, 0].reshape(n, d).astype(compute)
+            v2 = v_ref[0, 0].reshape(n, d).astype(compute)
+            scores = jax.lax.dot_general(
+                q_ref[0].astype(compute), k2, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale  # (H, n)
+            col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
+            head = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
+            own = jnp.logical_and(
+                col % kv == head // grp, j * block_w + col // kv <= depth
+            )
+            scores = jnp.where(own, scores, NEG_INF)
+            m_prev = m[:, :1]
+            # a block that runs holds t = j * block_w <= depth for every head
+            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+            pexp = jnp.exp(scores - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l[:, :1] = l[:, :1] * corr + pexp.sum(axis=-1, keepdims=True)
+            acc[:] = acc[:] * corr + jax.lax.dot(
+                pexp.astype(compute), v2, preferred_element_type=jnp.float32
+            )
+            m[:, :1] = m_new
+
+        @pl.when(j == n_blocks - 1)
+        def _flush():
+            lv = l[:, :1]
+            o_ref[0] = (
+                acc[:] / jnp.where(lv == 0.0, 1.0, lv)  # a dead slot: zeros
+            ).astype(o_ref.dtype)
+
+    def rows_map(bb, j, layer_ref, pos_ref, src_ref, hi_ref):
+        # a block past the depth is the last one needed again, and a dead
+        # slot asks for that one alone: the pipeline fetches nothing for an
+        # index it already holds
+        last = hi_ref[bb]
+        blk = jnp.where(pos_ref[bb] < w, jnp.minimum(j, last), last)
+        return (layer_ref[0], src_ref[bb], blk, 0, 0)
+
+    q_spec = pl.BlockSpec((1, h, d), lambda bb, j, *_: (bb, 0, 0))
+    rows_spec = pl.BlockSpec((1, 1, block_w, kv, d), rows_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, n_blocks),
+        in_specs=[q_spec, rows_spec, rows_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        name="decode_attention",
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), pos, src, hi,
+        q, k_stack, v_stack,
+    )

@@ -16,9 +16,11 @@ This kernel walks ``(slot, block of positions)`` with the layer index and
 the slots' depths as **scalar-prefetch** operands: the cache block's index
 map points into the carried stack ``(L, B, W, C)`` at ``[layer, slot]``
 (no slice is copied), blocks past a slot's depth are neither fetched nor
-computed, every head reads the one block (the heads are the rows of one
-matrix product), and the softmax runs as the streaming ``(m, l, acc)``
-recurrence of :mod:`.paged_attention`. One query position a slot (the
+computed, a slot whose depth is the window holds nothing and costs no rows
+(:func:`.decode_attention.block_bounds`, as for heads of K and V), every
+head reads the one block (the heads are the rows of one matrix product),
+and the softmax runs as the streaming ``(m, l, acc)`` recurrence of
+:mod:`.paged_attention`. One query position a slot (the
 serving chain's step, ``generate()``'s step); a chunk of several goes
 through the plain einsums, which are also this kernel's numerics
 reference (:func:`latent_decode_attention_reference`).
@@ -30,6 +32,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from pytorch_distributed_training_tutorials_tpu.ops.decode_attention import (
+    block_bounds,
+)
 
 NEG_INF = float("-inf")  # plain float: no jax arrays at import time
 
@@ -50,10 +56,11 @@ def latent_decode_attention(
     ``q``: (B, H, C) absorbed queries, a row a head, as wide as a cache
     row; ``cache``: (L, B, W, C); ``layer``: int32 scalar and ``pos``: (B,)
     int32, both traced (position ``t`` is attended iff ``t <= pos[b]``: the
-    new token's own row is written before the call). Returns (B, H, C)
-    float32: the weighted sum of whole rows (the caller keeps the first
-    ``rank`` numbers). ``W`` must be a multiple of the block
-    (``min(block_w, W)``), C a multiple of 128.
+    new token's own row is written before the call; ``pos[b] >= W`` is a
+    slot with nothing in it: no row of it is read and its result is
+    zeros). Returns (B, H, C) float32: the weighted sum of whole rows (the
+    caller keeps the first ``rank`` numbers). ``W`` must be a multiple of
+    the block (``min(block_w, W)``), C a multiple of 128.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -66,9 +73,12 @@ def latent_decode_attention(
     # the CPU backend has no bf16 x bf16 -> f32 product of this form: the
     # interpreter computes in float32 what the chip computes from bfloat16
     compute = jnp.float32 if interpret else cache.dtype
+    pos = pos.astype(jnp.int32)
+    src, hi = block_bounds(pos, w, block_w)
 
-    def kernel(layer_ref, pos_ref, q_ref, rows_ref, o_ref, acc, m, l):
-        del layer_ref
+    def kernel(layer_ref, pos_ref, src_ref, hi_ref,
+               q_ref, rows_ref, o_ref, acc, m, l):
+        del layer_ref, src_ref, hi_ref
         bb, j = pl.program_id(0), pl.program_id(1)
         depth = pos_ref[bb]
 
@@ -78,7 +88,7 @@ def latent_decode_attention(
             m[:] = jnp.full_like(m, NEG_INF)
             l[:] = jnp.zeros_like(l)
 
-        @pl.when(j * block_w <= depth)
+        @pl.when(jnp.logical_and(depth < w, j * block_w <= depth))
         def _block():
             rows = rows_ref[0, 0].astype(compute)  # (block_w, C)
             scores = jax.lax.dot_general(
@@ -102,15 +112,19 @@ def latent_decode_attention(
 
         @pl.when(j == n_blocks - 1)
         def _flush():
-            o_ref[0] = acc[:] / l[:, :1]
+            lv = l[:, :1]
+            o_ref[0] = acc[:] / jnp.where(lv == 0.0, 1.0, lv)  # dead: zeros
 
-    def rows_map(bb, j, layer_ref, pos_ref):
-        # a block past the slot's depth is the last one needed again: the
-        # pipeline fetches nothing for an index it already holds
-        return (layer_ref[0], bb, jnp.minimum(j, pos_ref[bb] // block_w), 0)
+    def rows_map(bb, j, layer_ref, pos_ref, src_ref, hi_ref):
+        # a block past the slot's depth is the last one needed again, and
+        # a dead slot asks for that one alone: the pipeline fetches nothing
+        # for an index it already holds
+        last = hi_ref[bb]
+        blk = jnp.where(pos_ref[bb] < w, jnp.minimum(j, last), last)
+        return (layer_ref[0], src_ref[bb], blk, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(b, n_blocks),
         in_specs=[
             pl.BlockSpec((1, h, c), lambda bb, j, *_: (bb, 0, 0)),
@@ -130,8 +144,7 @@ def latent_decode_attention(
         name="latent_decode_attention",
         interpret=interpret,
     )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        jnp.minimum(pos.astype(jnp.int32), w - 1), q, cache,
+        jnp.asarray(layer, jnp.int32).reshape(1), pos, src, hi, q, cache,
     )
 
 

@@ -149,6 +149,7 @@ from pytorch_distributed_training_tutorials_tpu.models.sampling import (
 )
 from pytorch_distributed_training_tutorials_tpu.models.transformer import (
     _kv_quant_mode,
+    park_cache_index,
     rewind_cache_index,
 )
 from pytorch_distributed_training_tutorials_tpu.parallel.tensor_parallel import (
@@ -471,6 +472,13 @@ class ServeEngine:
                     model.cfg,
                     kv_cache_dtype="int4" if kv_bits == 4 else jnp.int8,
                 )
+            )
+        if self._shard and model.cfg.tp_mesh is None:
+            # the model sees the mesh it is served under: its decode step
+            # keeps the plain head-sharded einsums (a bare pallas_call is
+            # refused on the stack GSPMD has sharded)
+            model = type(model)(
+                cfg=dataclasses.replace(model.cfg, tp_mesh=strategy.mesh)
             )
         if getattr(model.cfg, "latent", False):
             # a latent cache (one [c | k_rope] row a token, shared by all
@@ -1562,10 +1570,12 @@ class ServeEngine:
     def _chain_fn(self, params, state):
         """``tokens_per_launch`` decode steps as one ``lax.scan`` — one
         launch, one (S, T) token block out. Every slot steps every time
-        (fixed shapes); inactive slots re-emit their last token, their
-        K/V writes land at advancing positions whose reads are never
-        consumed (and drop once past the window — ``_store_decode_kv``
-        in models/transformer.py), and refill rewrites the whole slot
+        (fixed shapes); inactive slots re-emit their last token. Before
+        each step their depth is set to the window
+        (``park_cache_index``): their K/V writes drop
+        (``_store_decode_kv`` in models/transformer.py), the decode
+        kernel reads no row of them, what the plain path computes for
+        them is never consumed, and refill rewrites the whole slot
         anyway.
 
         With the adapter bank on, the per-slot adapter-id vector rides
@@ -1600,6 +1610,10 @@ class ServeEngine:
         def step(carry, x):
             cache, tok, keys, remaining = carry
             active = remaining > 0
+            # a slot without budget holds no live sequence: its depth reads
+            # "nothing here" (the window: its writes drop, and the decode
+            # kernel reads no row of it) until a refill writes a real one
+            cache = park_cache_index(cache, ~active, self.window)
             # _dec_model IS self.model unless paged (then it's the
             # pool+page-table twin) — unpaged chains trace byte-identical
             logits, upd = self._dec_model.apply(

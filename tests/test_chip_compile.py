@@ -39,6 +39,9 @@ from pytorch_distributed_training_tutorials_tpu.ops.fused_optim import (
 from pytorch_distributed_training_tutorials_tpu.ops.paged_attention import (
     paged_attention,
 )
+from pytorch_distributed_training_tutorials_tpu.ops.decode_attention import (
+    decode_attention,
+)
 from pytorch_distributed_training_tutorials_tpu.ops.latent_attention import (
     latent_decode_attention,
 )
@@ -194,6 +197,42 @@ def test_latent_decode_attention_compiles(one_chip, layers):
     )
 
 
+@pytest.mark.parametrize(
+    "stack, heads, dtype",
+    [
+        ((24, 32, 2048, 8, 128), 16, jnp.bfloat16),
+        ((32, 8, 4096, 8, 128), 32, jnp.bfloat16),
+        ((16, 8, 512, 16, 128), 16, jnp.float32),
+    ],
+    ids=["chat", "long", "1b_mha_f32"],
+)
+def test_decode_attention_compiles(one_chip, stack, heads, dtype):
+    """The chat and long cells' carried K and V stacks in bfloat16, read at
+    a traced layer index in blocks stored ``(512, 8, 128)`` (and the ``1b``
+    preset's float32 cache of 16 heads, ``chip_smoke.py``'s engine, in
+    blocks of 128 rows): the call takes the stacks as they lie (no copy
+    or change of layout of either, which would be 3.2 GB), and allocates
+    nothing beside its result."""
+    fn = jax.jit(
+        lambda q, k, v, layer, pos: decode_attention(
+            q, k, v, layer, pos, interpret=False)
+    )
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in [
+            ((stack[1], heads, stack[4]), jnp.float32),
+            (stack, dtype), (stack, dtype),
+            ((), jnp.int32), ((stack[1],), jnp.int32),
+        ]
+    ]
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "decode_attention" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    dims = ",".join(str(n) for n in stack)
+    assert not re.findall(rf"= \w+\[{dims}\]\S* (?:copy|fusion)\(", hlo)
+
+
 @pytest.mark.parametrize("s", [1, 16], ids=["decode", "chunk16"])
 @pytest.mark.parametrize("quant", [None, "int8", "int4"],
                          ids=["bf16", "int8kv", "int4kv"])
@@ -327,8 +366,11 @@ def test_serve_chain_carries_the_cache_in_place(
     slice back into the stack on every decode step
     (``bitcast_dynamic-update-slice_fusion`` with a ``bf16[32,2048,8,128]``
     update, half of the chat cell's device time before ISSUE 28): this is
-    the test that fails if that comes back. Nothing is allocated at that
-    size: params and the engine's slot state are shapes."""
+    the test that fails if that comes back. The ``bf16`` case, the chat
+    cell's own, also holds the read side (ISSUE 31): the other storages,
+    the paged pool and the speculative chain keep the plain path and its
+    read copy. Nothing is allocated at that size: params and the engine's
+    slot state are shapes."""
     from pytorch_distributed_training_tutorials_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
@@ -394,3 +436,98 @@ def test_serve_chain_carries_the_cache_in_place(
     assert analysis.alias_size_in_bytes >= cache_bytes
     # nor copied whole inside (half of it is all of K)
     assert analysis.temp_size_in_bytes < temp_share * cache_bytes
+    if options:
+        return
+    # the cell's own storage: the step's attention is the kernel, which
+    # reads K and V in the carried stack. Nothing in the chain yields a
+    # layer's slice of either (the read copy and its change of layout,
+    # half of the cell's device time before ISSUE 31): every bfloat16
+    # result of that size or more is a whole stack, updated in place.
+    # (What is left of ``temp_size_in_bytes``, 190 MB, is the int8 head
+    # padded to s8[2048, 92672], as it was before.)
+    assert re.search(
+        r"%decode_attention[\w.]* = f32\[32,16,128\]\S* custom-call\(", hlo
+    ), "no decode_attention kernel in the chain"
+    big = [
+        (op, dims) for dims, op in re.findall(
+            r"= bf16\[([\d,]+)\]\S* ([\w\-]+)\(", hlo
+        )
+        if math.prod(int(n) for n in dims.split(",")) >= layer_slice
+    ]
+    assert big and all(
+        dims == "24,32,2048,8,128" and op not in ("copy", "dynamic-slice")
+        for op, dims in big
+    ), big
+
+
+def test_float_tp_serve_chain_compiles_on_a_mesh(topo, monkeypatch):
+    """The chat cell's model in floats, served tensor-parallel over the
+    four described chips (16 heads and 8 KV heads of 128 split four ways,
+    a window of whole blocks: every width the ``decode_attention`` kernel
+    takes): the engine serves the model with the strategy's mesh on its
+    config, so the chain's step keeps the head-sharded plain einsums. A
+    bare ``pallas_call`` on the stack GSPMD has sharded is what the chip's
+    compiler refuses (or feeds by gathering the whole stack). Params and
+    slot state are shapes with their shardings."""
+    import numpy as np
+
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        TP_RULES,
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_training_tutorials_tpu.parallel import (
+        TensorParallel,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+    from pytorch_distributed_training_tutorials_tpu.serve import (
+        engine as engine_module,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve.slots import (
+        init_slot_state,
+    )
+
+    def as_shapes(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda leaf, sh: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sh
+            ),
+            tree, shardings,
+        )
+
+    strategy = TensorParallel(
+        Mesh(np.array(topo.devices), ("model",)), TP_RULES
+    )
+    # a kernel that asks the backend whether to interpret would be Mosaic
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        strategy, "shard_state",
+        lambda tree: as_shapes(tree, strategy.variable_shardings(tree)),
+    )
+
+    def slot_shapes(model, params, *a, strategy=None, **kw):
+        state = jax.eval_shape(
+            lambda p: init_slot_state(model, p, *a, **kw), params
+        )
+        return as_shapes(state, strategy.slot_shardings(state))
+
+    monkeypatch.setattr(engine_module, "init_slot_state", slot_shapes)
+    model = TransformerLM(TransformerConfig(**CHAT))
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    engine = ServeEngine(
+        model, params, n_slots=CHAT_SLOTS, tokens_per_launch=8,
+        strategy=strategy,
+    )
+    assert engine.model.cfg.tp_mesh is strategy.mesh
+    key = engine._state["cache"]["layers"]["block"]["attn"]["cached_key"]
+    assert key.sharding.shard_shape(key.shape) == (24, 32, 2048, 2, 128)
+    hlo = (
+        jax.jit(engine._chain_fn, donate_argnums=(1,))
+        .lower(engine.params, engine._state).compile().as_text()
+    )
+    assert "decode_attention" not in hlo and "tpu_custom_call" not in hlo
+    # K and V stay split by head: the Megatron all-reduces and no gather
+    assert "all-reduce" in hlo and "all-gather" not in hlo

@@ -39,6 +39,7 @@ KERNELS = {
     "int8_matmul": "quant.py",
     "grouped_int8_matmul": "quant.py",
     "latent_decode_attention": "latent_attention.py",
+    "decode_attention": "decode_attention.py",
 }
 
 
@@ -132,6 +133,27 @@ def test_cache_ops_keep_the_module_path_under_the_scan(
     scan treats the cache."""
     text = _lowered(which, engine, trainer).as_text(debug_info=True)
     assert f'"layers/block/attn/kv_cache/{op}"' in text
+    assert re.search(r'[/"(]layer_scan\)*/', text)
+
+
+def test_decode_kernel_is_under_its_scope_in_the_chain():
+    """At ``head_dim`` 128 the chain's step reads K and V through the
+    ``decode_attention`` kernel under ``layers/block/attn/decode_attn``
+    (``decode_attention_share.*``), and ``cache[layer]`` is read by no
+    ``dynamic_slice``; the new rows' ``scatter`` stays under ``kv_cache``."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=256, n_layers=2, n_heads=2, n_kv_heads=1,
+        d_ff=64, max_seq_len=128, scan_layers=True,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    engine = ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+    text = engine._chain.lower(engine.params, engine._state).as_text(
+        debug_info=True)
+    assert '"layers/block/attn/decode_attn/' in text
+    assert '"layers/block/attn/kv_cache/scatter"' in text
+    assert '"layers/block/attn/kv_cache/dynamic_slice"' not in text
     assert re.search(r'[/"(]layer_scan\)*/', text)
 
 

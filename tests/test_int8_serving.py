@@ -173,7 +173,7 @@ def test_tp_quantized_serving_matches_replicated():
     mesh = create_mesh({"data": 2, "model": 4})
     rep = TransformerLM(dataclasses.replace(cfg, quantized=True))
     tp = TransformerLM(
-        dataclasses.replace(cfg, quantized=True, int8_mesh=mesh)
+        dataclasses.replace(cfg, quantized=True, tp_mesh=mesh)
     )
 
     lg_rep = rep.apply({"params": qparams}, tokens)
@@ -220,7 +220,7 @@ def test_tp_stacked_quantized_serving_matches_replicated():
     rep = TransformerLM(dataclasses.replace(cfg, quantized=True))
     tp_stacked = TransformerLM(
         dataclasses.replace(
-            cfg, quantized=True, scan_layers=True, int8_mesh=mesh
+            cfg, quantized=True, scan_layers=True, tp_mesh=mesh
         )
     )
     prompt = tokens[:, :4]
@@ -264,7 +264,7 @@ def test_load_quantized_lm_shards_over_mesh(tmp_path):
 
     # and the sharded tree serves through the TP model
     tp = TransformerLM(
-        dataclasses.replace(cfg, quantized=True, int8_mesh=mesh)
+        dataclasses.replace(cfg, quantized=True, tp_mesh=mesh)
     )
     out = generate(tp, loaded, tokens[:, :4], max_new_tokens=4)
     assert out.shape == (2, 8)

@@ -200,6 +200,15 @@ def test_latent_decode_kernel_is_its_reference(dtype):
         q[:, None], cache[1], valid, sm_scale=0.1)[:, 0]
     tol = 1e-5 if dtype == jnp.float32 else 2e-2  # bf16 weights on the rows
     np.testing.assert_allclose(got, want, atol=tol)
+    # a slot whose depth is the window holds nothing: zeros out, none of
+    # its rows read (poisoned here), the others' results as they were
+    dead = latent_decode_attention(
+        q, cache.at[:, 1].set(jnp.nan), jnp.int32(1), pos.at[1].set(W),
+        sm_scale=0.1, block_w=128)
+    assert not np.asarray(dead[1]).any()
+    keep = np.array([0, 2, 3])
+    np.testing.assert_array_equal(
+        np.asarray(dead)[keep], np.asarray(got)[keep])
 
 
 def routed(held=4, offset=0, quantized=False, **kw):

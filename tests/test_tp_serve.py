@@ -167,6 +167,43 @@ def test_tp2_token_exact_and_kv_sharded(model_params):
     assert {s.data.shape for s in idx.addressable_shards} == {idx.shape}
 
 
+def test_tp2_at_head_dim_128_keeps_the_plain_decode_path(monkeypatch):
+    """At real head widths (``head_dim`` 128, a window in whole blocks) a
+    replicated engine's step runs the ``decode_attention`` kernel; a
+    sharded engine serves the model with the strategy's mesh on its config
+    (``tp_mesh``), so its step keeps the head-sharded plain einsums: a bare
+    ``pallas_call`` is refused on a stack GSPMD has sharded. Tokens equal
+    ``generate()``'s either way."""
+    from pytorch_distributed_training_tutorials_tpu.ops import (
+        decode_attention as kernel_module,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=256, n_layers=2, n_heads=2, max_seq_len=128,
+        scan_layers=True,
+    )
+    model, params = _make(cfg)
+    reqs = [(_prompt(8150 + i, p), m) for i, (p, m) in enumerate(REQS[:3])]
+    calls = []
+    real = kernel_module.decode_attention
+    monkeypatch.setattr(
+        kernel_module, "decode_attention",
+        lambda *a, **kw: (calls.append(1), real(*a, **kw))[1],
+    )
+    eng_r, out_r = _run_stream(model, params, reqs)
+    assert calls and eng_r.model.cfg.tp_mesh is None
+    del calls[:]
+    strat = _tp(2)
+    eng_t, out_t = _run_stream(model, params, reqs, strategy=strat)
+    assert not calls and eng_t.model.cfg.tp_mesh is strat.mesh
+    for (p, m), r, t in zip(reqs, out_r, out_t):
+        assert r.tokens == t.tokens == _reference(model, params, p, m)
+    kv = _kv_leaf(eng_t)
+    assert {s.data.shape for s in kv.addressable_shards} == {
+        (2, 2, 128, 1, 128)
+    }
+
+
 def test_tp2_fetch_budget_and_zero_recompile(model_params, monkeypatch):
     """Sharding must not change the fetch discipline: one batched fetch
     per chain + one scalar per prefill at tp=2, and a second wave of
