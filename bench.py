@@ -77,9 +77,7 @@ def main() -> None:
     from pytorch_distributed_training_tutorials_tpu.train import Trainer
 
     # the canonical workload (uint8-resident MNIST, bf16 cifar-stem
-    # ResNet-18, SGD+momentum) — shared with scripts/profile_step.py and
-    # scripts/step_time_experiment.py so the profiler measures exactly what
-    # this headline reports
+    # ResNet-18, SGD+momentum)
     setup = make_headline_setup(per_device_batch=512, quiet=args.quiet)
     mesh, ds, loader, trainer = (
         setup.mesh, setup.dataset, setup.loader, setup.trainer

@@ -301,9 +301,7 @@ def main():
         "(ISSUE 19). On by default — host-only counters watching the "
         "zero-steady-recompile, fetch-budget, and no-host-numpy "
         "contracts at runtime; a violation auto-dumps a flight "
-        "snapshot and the receipt carries sentry_* fields. regress.py "
-        "fingerprints `sentry`, so bare and instrumented rounds never "
-        "gate each other",
+        "snapshot and the receipt carries sentry_* fields",
     )
     ap.add_argument(
         "--pipeline-depth", type=int, default=1, dest="pipeline_depth",
@@ -489,8 +487,7 @@ def main():
         mesh = create_mesh({"model": args.tp})
 
     t0 = time.perf_counter()
-    # kv_bits/paged_kernel ride every receipt (0/False off) — regress.py
-    # fingerprints them so int4/kernel rounds never gate int8/gather ones
+    # kv_bits/paged_kernel ride every receipt (0/False off)
     receipt = {
         "preset": args.preset, "tp": args.tp,
         "kv_bits": args.kv_bits or 0,
@@ -1084,8 +1081,7 @@ def serve_request_stream(args, cfg, lm, params, receipt: dict) -> None:
     # carries sentry_steady_recompiles / sentry_fetch_budget_ok /
     # sentry_reupload_bytes and a contract break on the real chip
     # auto-dumps a flight snapshot instead of silently eating the round.
-    # --no-sentry reverts to the bare engine (regress.py fingerprints
-    # the `sentry` field, so the two never gate each other).
+    # --no-sentry reverts to the bare engine.
     sentry = None
     if not args.no_sentry:
         from pytorch_distributed_training_tutorials_tpu.obs import ContractSentry

@@ -764,8 +764,9 @@ Dense causal attention materializes a `(B, H, S, S)` float32 score tensor
 
 - **Pallas flash attention** (`ops.flash_attention`): blockwise online
   softmax — scores only ever exist as VMEM tiles, temp memory flat in S
-  (round 4, v5e, not re-measured since: ~2x faster training at S=4096,
-  2.1 GB of dense temps avoided).
+  (dense needs 2.1 GB of score temps at S=4096); on the chip the
+  benchmark's training cell reads `flash_attention_roofline` 31.7
+  (ledger, PR 31).
 - **Ring attention** (`parallel.ring_attention`): shard the *sequence*
   over a mesh axis; K/V blocks rotate via `ppermute` while each device
   folds them into the same online-softmax state — context length scales
@@ -809,16 +810,19 @@ prompt in one forward, decodes through a KV cache sized to the *request*
 path only for prompt lengths that don't divide the seq axis.
 """),
     ("md", """
-## Tuning an LM train step for the MXU — the knobs that matter
+## Configuring an LM train step for the MXU — the knobs that matter
 
-Round 5 (`TRAIN_LLM_r05.json`) measured a 1.01B-param model at **50% MFU** on one
-v5e chip. Three configuration choices did the work (in order of effect):
-flash attention over dense (+16.6 MFU points at S=2048), **unrolled**
-layers over `nn.scan` for *training* (+2 points AND less memory — the
-scan's stacked activation saves compile to badly-laid-out update-slice
-copies), and `remat_policy="dots"` (save matmul outputs, recompute only
-the cheap elementwise ops; full remat re-runs every matmul in the
-backward, and *no* remat cannot even fit real batches). The same config
+Three configuration choices shape an LM train step on a TPU: flash
+attention over dense (the (S, S) scores never exist in HBM), layers
+**unrolled** or under `nn.scan` (a scan keeps the program O(1) in depth;
+unrolling trades compile time for the scan's stacked activation saves),
+and `remat_policy="dots"` (save matmul outputs, recompute only the cheap
+elementwise ops; full remat re-runs every matmul in the backward, and
+*no* remat cannot fit real batches). What each is worth on today's chip
+is for the benchmark to say: its training cell
+(`train-internlm2-1.8b-s2048` in `BENCHMARK.json`: flash attention,
+scanned layers, `dots`) reads `mfu.train` 41.3 (ledger, PR 31), and
+`PERF.md` has the account. The same config
 object expresses all three:
 """),
     ("code", """
@@ -828,7 +832,7 @@ from pytorch_distributed_training_tutorials_tpu.train.trainer import TrainState,
 train_cfg = TransformerConfig(
     vocab_size=64, d_model=32, n_layers=2, n_heads=4, max_seq_len=64,
     attention_fn=make_flash_attention(16, 16),  # 1. flash, not dense
-    scan_layers=False,                          # 2. unrolled for training
+    scan_layers=False,                          # 2. unrolled here
     remat=True, remat_policy="dots",            # 3. save the matmuls
 )
 lm = TransformerLM(train_cfg)
@@ -840,7 +844,7 @@ step = make_train_step("cross_entropy")  # the jitted donated SPMD step
 state, metrics = step(state, (toks[:, :-1], toks[:, 1:]))
 print("LM train step (flash x unrolled x dots-remat) loss:",
       float(metrics["loss"]))
-# the real-chip receipt: python -m pytorch_distributed_training_tutorials_tpu.bench.lm_headline
+# on the chip: python3 benchmark/run.py --workload train-internlm2-1.8b-s2048
 """),
     ("md", """
 (Serving flips choice 2: `scan_layers=True` keeps the *program* O(1) in

@@ -1,12 +1,11 @@
 """Benchmark harness (twin of reference C17).
 
 Re-exports are PEP 562 lazy (same pattern as the top-level package
-init): importing ``pytorch_distributed_training_tutorials_tpu.bench`` does not import jax, so the
-jax-free :mod:`.regress` receipt gate can live here without dragging a
-backend into CI. Heavyweight legs stay import-lazy too: bench.headline /
-bench.scaling / bench.lm_headline are CLI modules (``python -m ...``)
-and import jax state on use, not at package import
-(tests/test_import_purity.py).
+init): importing ``pytorch_distributed_training_tutorials_tpu.bench`` does not import jax.
+Heavyweight legs stay import-lazy too: bench.headline and bench.scaling
+import jax state on use, not at package import
+(tests/test_import_purity.py). What the repo's speed is judged by is not
+here: ``benchmark/run.py`` at the root of the checkout.
 """
 
 import importlib

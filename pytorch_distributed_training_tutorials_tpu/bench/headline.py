@@ -1,11 +1,8 @@
 """The canonical headline-benchmark recipe, in one place.
 
-``bench.py``, ``scripts/profile_step.py``, and
-``scripts/step_time_experiment.py`` all measure the same program — the
-ResNet-18 bs512 bf16 MNIST data-parallel train step (BASELINE.json's north
-star). This module owns that setup so a change to the workload (batch,
-transform, optimizer) cannot silently desynchronize what the profiler or
-an experiment script measures from what the headline bench reports.
+``bench.py`` measures one program — the ResNet-18 bs512 bf16 MNIST
+data-parallel train step (BASELINE.json's north star). This module owns
+that setup (batch, transform, optimizer), apart from the timing legs.
 """
 
 from __future__ import annotations

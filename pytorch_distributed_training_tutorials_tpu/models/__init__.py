@@ -35,7 +35,6 @@ from pytorch_distributed_training_tutorials_tpu.models.moe import (  # noqa: F40
     moe_aux_loss,
 )
 from pytorch_distributed_training_tutorials_tpu.models.utils import (  # noqa: F401
-    model_flops_per_token,
     model_size,
 )
 from pytorch_distributed_training_tutorials_tpu.models.generate import (  # noqa: F401

@@ -2,14 +2,10 @@
 
 The reference tutorial's observability is one rank-tagged print
 (ddp_gpus.py:44); this repo's replacement grew as scattered scripts plus
-CLAUDE.md prose. ``obs`` is that lore as library code, in four pillars:
+CLAUDE.md prose. ``obs`` is that lore as library code, in three pillars:
 
 - :mod:`.metrics` — :class:`MetricsLogger`: typed step/epoch events, ring
   buffer + JSONL, process-0 gated, no per-step host sync;
-- :mod:`.trace` — :class:`StepReport`: trace-classified "where did the
-  step go" breakdowns (the round-4 profile analysis as one call), fusion
-  classes HLO-verified so the ``convert_reduce_fusion`` misread cannot
-  recur;
 - :mod:`.timing` — :class:`MinOfN` (stall flagging), :class:`DriftBracket`
   (the ``h2d_window_drift`` pattern), :func:`launch_overhead_fit`
   (``wall = fixed + per_op * len``);
@@ -35,19 +31,20 @@ here: ``ServeEngine`` and ``Trainer`` wrap their phases in
 :func:`..utils.profiling.annotate` (``prog:<phase>``, integer fields) —
 always there, free while nothing traces, on the profiler's own clock
 beside the device's ``XLA Ops`` line, and read by the benchmark's
-per-layer readers (``benchmark/lib/program_trace.py``).
+per-layer readers (``benchmark/lib/program_trace.py``, which also answers
+"where did the step go" from a traced run's ``.xplane.pb``).
 :class:`FlightRecorder` is the opt-in ring for post-mortems: its own
 ``perf_counter`` clock, jax-free by contract, read by ``flight_stats()``
 and ``scripts/flight_view.py``. The spans are named after its
 ``EVENT_KINDS`` where a kind exists, so both name the same boundaries.
 
-``python -m pytorch_distributed_training_tutorials_tpu.obs --selftest`` smoke-runs all four on a
+``python -m pytorch_distributed_training_tutorials_tpu.obs --selftest`` smoke-runs them on a
 tiny CPU-mesh workload.
 
 The re-exports below are PEP 562 LAZY (same pattern as the top-level
 package init): importing ``pytorch_distributed_training_tutorials_tpu.obs`` does not import
-jax, so jax-free tooling (``bench.regress``, receipt validation in CI)
-can reach :mod:`.receipt` without initializing a backend.
+jax, so jax-free tooling (receipt validation in CI) can reach
+:mod:`.receipt` without initializing a backend.
 """
 
 import importlib
@@ -55,8 +52,6 @@ import importlib
 # name -> submodule; resolved on first access via __getattr__.
 _LAZY_EXPORTS = {
     "MetricsLogger": "pytorch_distributed_training_tutorials_tpu.obs.metrics",
-    "StepReport": "pytorch_distributed_training_tutorials_tpu.obs.trace",
-    "classify_hlo": "pytorch_distributed_training_tutorials_tpu.obs.trace",
     "BracketResult": "pytorch_distributed_training_tutorials_tpu.obs.timing",
     "DriftBracket": "pytorch_distributed_training_tutorials_tpu.obs.timing",
     "LaunchFit": "pytorch_distributed_training_tutorials_tpu.obs.timing",

@@ -1,16 +1,15 @@
 """``python -m pytorch_distributed_training_tutorials_tpu.obs --selftest``: end-to-end smoke of the
 observability layer on a tiny workload.
 
-Exercises all five pillars against whatever backend is available (the
+Exercises every pillar against whatever backend is available (the
 tier-1 test runs it on the forced 8-device CPU mesh): trains a few steps
-with a JSONL-sinked :class:`MetricsLogger`, captures a real profiler trace
-of a jitted step chain, classifies it with :class:`StepReport` (HLO-
-verified), drives the flight-recorder pillar (histogram sharding/merge vs
-numpy percentiles, a full lifecycle span, a ``graft-flightlog/v1`` dump
-round-tripped through disk and re-validated), and emits an
-``obs_selftest`` receipt through the schema'd writer. Prints exactly one
-JSON line on stdout and exits non-zero on any validation failure — a
-living receipt that the pipeline works.
+with a JSONL-sinked :class:`MetricsLogger`, times a jitted step chain
+with :class:`MinOfN`, drives the flight-recorder pillar (histogram
+sharding/merge vs numpy percentiles, a full lifecycle span, a
+``graft-flightlog/v1`` dump round-tripped through disk and re-validated),
+and emits an ``obs_selftest`` receipt through the schema'd writer. Prints
+exactly one JSON line on stdout and exits non-zero on any validation
+failure — a living receipt that the pipeline works.
 """
 
 from __future__ import annotations
@@ -32,13 +31,11 @@ def selftest(json_path: str | None = None) -> dict:
     from pytorch_distributed_training_tutorials_tpu.obs import (
         MetricsLogger,
         MinOfN,
-        StepReport,
         make_receipt,
         validate_receipt,
     )
     from pytorch_distributed_training_tutorials_tpu.parallel.mesh import create_mesh
     from pytorch_distributed_training_tutorials_tpu.train import Trainer
-    from pytorch_distributed_training_tutorials_tpu.utils import profiling
 
     problems: list[str] = []
     workdir = tempfile.mkdtemp(prefix="obs-selftest-")
@@ -68,7 +65,7 @@ def selftest(json_path: str | None = None) -> dict:
             f"({len(metrics.events)})"
         )
 
-    # pillar 3: MinOfN on a fetch-closed chain (warmup primes first fetch)
+    # pillar 2: MinOfN on a fetch-closed chain (warmup primes first fetch)
     steps = 4
     batch = next(iter(loader))
 
@@ -85,22 +82,7 @@ def selftest(json_path: str | None = None) -> dict:
     if timing.best_s <= 0:
         problems.append("MinOfN produced a non-positive sample")
 
-    # pillar 2: a real trace, classified against the compiled HLO
-    logdir = os.path.join(workdir, "trace")
-    with profiling.trace(logdir):
-        jax.block_until_ready(compiled(trainer.state, batch))
-    report = StepReport.from_trace(
-        logdir, hlo=compiled.as_text(), steps=steps
-    )
-    if report.total_us <= 0:
-        problems.append("trace captured no device time")
-    if report.unclassified_fraction > 0.10:
-        problems.append(
-            f"{100 * report.unclassified_fraction:.1f}% of device time "
-            "unclassified (>10%)"
-        )
-
-    # pillar 5: flight recorder + streaming histograms (ISSUE 10) —
+    # pillar 3: flight recorder + streaming histograms (ISSUE 10) —
     # jax-free, so this leg runs identically on any backend
     import math
     import random
@@ -172,7 +154,6 @@ def selftest(json_path: str | None = None) -> dict:
             "last_epoch": metrics.last_epoch,
             "n_events": len(metrics.events),
             "timing": timing.to_dict(),
-            "step_report": report.to_dict(),
             "flight": fsum,
             "hist_rel_error_bound": whole.rel_error_bound,
             "problems": problems,
@@ -204,17 +185,6 @@ def main(argv: list[str] | None = None) -> int:
     if not args.selftest:
         parser.print_help()
         return 2
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""
-        ):
-            # a bare 1-device XLA:CPU run executes ops inline (no tf_XLA
-            # executor threads), so the profiler trace carries no device
-            # lanes; the forced mesh is also what tier-1 exercises
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + " --xla_force_host_platform_device_count=8"
-            ).strip()
     from pytorch_distributed_training_tutorials_tpu.utils.compile_cache import (
         enable_compile_cache,
     )

@@ -1,7 +1,7 @@
 """Serving flight recorder: request-lifecycle events, spans, fault dumps.
 
 PR 3 built the *benchmarking* observability pillar (MinOfN, DriftBracket,
-StepReport, receipts). This module is its production twin: when the
+receipts). This module is its production twin: when the
 engine is serving a live request stream, the question is no longer "how
 fast is a step" but "what was the engine doing when slot 3 went
 nonfinite" — exactly the post-mortem ISSUE 9's quarantine/deadline paths

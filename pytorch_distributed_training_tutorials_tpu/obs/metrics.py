@@ -40,9 +40,9 @@ class MetricsLogger:
         scalars (the Trainer's defer contract) — only :meth:`flush` does.
     flops_per_token / peak_flops / tokens_per_sample: when set, epoch
         events gain ``tokens_per_sec`` and ``mfu`` derived from
-        ``samples_per_sec`` (the analytic-FLOPs MFU convention —
-        models.utils.model_flops_per_token, never cost_analysis on a
-        scanned model, round 5).
+        ``samples_per_sec``. The caller hands in its own analytic FLOPs
+        a token (never ``cost_analysis`` on a scanned model: XLA counts
+        a scan body once, not times its trip count).
     flight: optional :class:`..obs.flight.FlightRecorder`. Skip-step
         observations become ``step_skipped`` flight events AT DRAIN TIME
         — the skip flag already rides the batched fetch, so the recorder

@@ -30,11 +30,7 @@ SCHEMA = "graft-receipt/v1"
 # Known receipt kinds — one per number-producing entry point.
 KINDS = frozenset({
     "bench_headline",    # bench.py
-    "lm_headline",       # bench/lm_headline.py
-    "llm_mfu_sweep",     # scripts/train_llm_mfu.py
     "serving",           # examples/serve_llm_int8.py
-    "profile_step",      # scripts/profile_step.py
-    "profile_decode",    # scripts/profile_decode.py
     "launch_probe",      # scripts/launch_overhead_probe.py
     "obs_selftest",      # python -m ...obs --selftest
     "serve_selftest",    # python -m ...serve --selftest
@@ -113,8 +109,8 @@ def validate_receipt(obj, kind: str | None = None) -> list[str]:
 
     - schema'd (``schema`` key present): envelope keys are checked in
       full — known kind, env stamp with jax_version/backend/device_count;
-    - legacy (no ``schema`` key): the pre-schema payloads checked in as
-      ``BENCH_r0*.json`` / ``TRAIN_LLM_r05.json``. Those are still
+    - legacy (no ``schema`` key): the pre-schema payloads of rounds 1-5
+      (``MULTICHIP_r0*.json``, ``ACCURACY_r04.json``). Those are still
       required to be non-empty dicts carrying at least one numeric
       measurement — retroactive validation, not a rubber stamp.
     """

@@ -322,8 +322,7 @@ class ContractSentry:
 
     def summary(self) -> dict:
         """Flat receipt-ready aggregate (``sentry_*`` keys). ``sentry``
-        itself is CONFIG (regress.py fingerprints it so instrumented and
-        bare rounds never gate each other); the rest are outcomes."""
+        itself is CONFIG; the rest are outcomes."""
         return {
             "sentry": 1,
             "sentry_compiles": self.n_compiles,

@@ -3430,9 +3430,8 @@ class ServeEngine:
         """Robustness counters for the serving receipt (same pattern as
         :meth:`spec_stats` — host bookkeeping, no device fetch):
         configured deadline/guard/chaos state plus how much traffic each
-        failure path handled. The counters are OUTCOMES, not config —
-        regress.py fingerprints only ``chaos``/``deadline_s``/
-        ``guard_nonfinite`` so chaos rounds never gate clean rounds."""
+        failure path handled. ``chaos``/``deadline_s``/``guard_nonfinite``
+        are config; the counters are OUTCOMES."""
         return {
             "deadline_s": float(self._deadline or 0.0),
             "guard_nonfinite": int(self._guard),
@@ -3492,8 +3491,7 @@ class ServeEngine:
         """Flight-recorder aggregate for the serving receipt: event /
         span / dump counters + the streaming-histogram percentiles
         (``ttft_p95_s``-style keys). ``{"flight": 0}`` when the recorder
-        is off — regress.py fingerprints the flag so instrumented and
-        bare rounds never gate each other. Host bookkeeping only."""
+        is off. Host bookkeeping only."""
         if self._flight is None:
             return {"flight": 0}
         return self._flight.summary()
@@ -3501,10 +3499,9 @@ class ServeEngine:
     def pipeline_stats(self) -> dict[str, int | float]:
         """Pipelining counters for the serving receipt (ISSUE 11):
         configured depth / prefill quantum plus how many prefill chunks
-        ran. regress.py fingerprints ``pipeline_depth`` /
-        ``prefill_chunk`` so pipelined and serial rounds never gate each
-        other; ``n_chunks`` is an outcome and stays out. Host
-        bookkeeping only — no device fetch."""
+        ran. ``pipeline_depth`` / ``prefill_chunk`` are config;
+        ``n_chunks`` is an outcome. Host bookkeeping only — no device
+        fetch."""
         return {
             "pipeline_depth": self._depth,
             "prefill_chunk": self._chunk,
@@ -3513,15 +3510,13 @@ class ServeEngine:
 
     def page_stats(self) -> dict[str, int | float]:
         """Paged-KV counters for the serving receipt (ISSUE 13): pool
-        geometry (config — regress.py fingerprints ``paged`` /
-        ``page_size`` / ``pool_pages``) plus occupancy outcomes
-        (``pages_*`` counters, excluded from the fingerprint).
+        geometry (config: ``paged`` / ``page_size`` / ``pool_pages``)
+        plus occupancy outcomes (``pages_*`` counters).
         ``hbm_high_water_bytes`` is the pool HBM high-water mark —
         ``high_water`` pages priced at the per-page leaf footprint —
         the number the oversubscription win is stated in. ``kv_bits``
-        (0 = full precision) and ``paged_kernel`` joined the
-        fingerprint in ISSUE 17 so int4/kernel rounds never gate
-        int8/gather ones; ``page_bytes`` already prices quantized
+        (0 = full precision) and ``paged_kernel`` are config too
+        (ISSUE 17); ``page_bytes`` already prices quantized
         leaves honestly (int4's packed uint8 + bf16 scales halve it vs
         int8 exactly). Host bookkeeping only — no device fetch."""
         if not self._paged:
@@ -3563,13 +3558,11 @@ class ServeEngine:
 
     def tp_stats(self) -> dict[str, int | float | str | bool]:
         """Sharded-serving fields for the receipt (ISSUE 15): tp size +
-        mesh shape (config — regress.py fingerprints ``tp`` /
-        ``mesh_shape`` so sharded and replicated rounds never gate each
-        other) and the PER-CHIP KV footprint (shard sizes, the honest
-        HBM claim). ``tp_collectives`` / ``tp_hlo_ok`` appear only
-        after an explicit :meth:`audit_decode_hlo` (outcomes, excluded
-        from the fingerprint). Host metadata only — sharding math, no
-        device fetch."""
+        mesh shape (config: ``tp`` / ``mesh_shape``) and the PER-CHIP
+        KV footprint (shard sizes, the honest HBM claim).
+        ``tp_collectives`` / ``tp_hlo_ok`` appear only after an explicit
+        :meth:`audit_decode_hlo` (outcomes). Host metadata only —
+        sharding math, no device fetch."""
         if not self._shard:
             return {"tp": 1}
         out: dict[str, int | float | str | bool] = {
@@ -3589,10 +3582,8 @@ class ServeEngine:
 
     def role_stats(self) -> dict[str, int | str]:
         """Disaggregation fields for the receipt (ISSUE 18): the
-        engine's role (config — regress.py fingerprints ``role`` so
-        disaggregated and monolithic rounds never gate each other)
-        plus the handoff counters (outcomes, excluded from the
-        fingerprint). ``{"role": 0}`` when monolithic."""
+        engine's role (config) plus the handoff counters (outcomes).
+        ``{"role": 0}`` when monolithic."""
         if self._role is None:
             return {"role": 0}
         return {
@@ -3603,22 +3594,19 @@ class ServeEngine:
 
     def sentry_stats(self) -> dict[str, int | float]:
         """Contract-sentry fields for the receipt (ISSUE 19): the
-        ``sentry`` flag is config (regress.py fingerprints it so
-        instrumented and bare rounds never gate each other); compile /
-        fetch / re-upload counters are outcomes. ``{"sentry": 0}`` when
-        off. A fleet sharing ONE sentry reports fleet-global numbers —
-        ``FleetRouter.stats()`` dedupes by sentry identity instead of
-        summing the same counters once per replica."""
+        ``sentry`` flag is config; compile / fetch / re-upload counters
+        are outcomes. ``{"sentry": 0}`` when off. A fleet sharing ONE
+        sentry reports fleet-global numbers — ``FleetRouter.stats()``
+        dedupes by sentry identity instead of summing the same counters
+        once per replica."""
         if self._sentry is None:
             return {"sentry": 0}
         return self._sentry.summary()
 
     def slo_stats(self) -> dict[str, int | float]:
         """SLO-tier fields for the receipt (ISSUE 20):
-        ``priority_classes`` / ``preemption`` are config (regress.py
-        fingerprints both so SLO rounds never gate FIFO rounds); the
-        swap counters are outcomes (excluded from the fingerprint).
-        ``{"priority_classes": 0}`` when off."""
+        ``priority_classes`` / ``preemption`` are config; the swap
+        counters are outcomes. ``{"priority_classes": 0}`` when off."""
         if not self._slo:
             return {"priority_classes": 0}
         return {

@@ -1075,16 +1075,14 @@ class FleetRouter:
             self._flight.record(kind, **fields)
 
     def router_stats(self) -> Dict[str, Any]:
-        """The fleet part of the receipt. Config fields (``n_replicas``,
-        ``hedge``, ``affinity``) are fingerprinted by regress.py so
-        fleet and single-engine rounds never gate each other; the
-        health/ledger counters are OUTCOMES and deliberately stay out of
-        the fingerprint, mirroring the chaos precedent."""
+        """The fleet part of the receipt. ``n_replicas``, ``hedge`` and
+        ``affinity`` are CONFIG (they say which fleet ran); the
+        health/ledger counters are OUTCOMES."""
         states = self.replica_states()
         roles = [r.role for r in self._replicas]
         if isinstance(self._hedge_after_s, dict):
             # class-indexed hedging (ISSUE 20): serialized as a stable
-            # "class:seconds" string so the fingerprint stays hashable
+            # "class:seconds" string so the field stays a scalar
             hedge: Any = ",".join(
                 f"{k}:{v}" for k, v in sorted(self._hedge_after_s.items())
             )
@@ -1098,8 +1096,8 @@ class FleetRouter:
                 for k, v in sorted((self._class_deadline_s or {}).items())
             ),
             "affinity": self._affinity_depth,
-            # disaggregation geometry (ISSUE 18): config, fingerprinted
-            # by regress.py; 0/0 = monolithic fleet
+            # disaggregation geometry (ISSUE 18): config; 0/0 =
+            # monolithic fleet
             "n_prefill_replicas": roles.count("prefill"),
             "n_decode_replicas": roles.count("decode"),
             "handoffs_moved": self.n_handoffs_moved,

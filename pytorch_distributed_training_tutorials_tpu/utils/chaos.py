@@ -87,8 +87,8 @@ class ChaosConfig:
       Fires exactly ONCE (the engine latches the firing); the victim
       resumes through the ordinary swap-in path, token-exact. Requires
       ``priority_classes > 0`` on the engine; ignored otherwise.
-    - ``seed`` rides into receipts/fingerprints so chaos runs are
-      self-describing; the injectors themselves are deterministic.
+    - ``seed`` rides into receipts so chaos runs are self-describing;
+      the injectors themselves are deterministic.
     """
 
     nan_logit_slot: int = -1
@@ -153,8 +153,7 @@ class FleetChaosConfig:
       router skips stepping it for ``stall_rounds`` scheduling rounds —
       a progress freeze (heartbeat ages, suspicion and hedging fire)
       with no wall-clock sleep, so chaos tests stay fast and flake-free.
-    - ``seed`` rides into receipts/fingerprints; the injectors are
-      deterministic.
+    - ``seed`` rides into receipts; the injectors are deterministic.
 
     The poison-a-replica path needs no new injector: hand ONE replica's
     engine an engine-level :class:`ChaosConfig` with

@@ -64,66 +64,3 @@ def annotate(name: str, **fields: int):
     """
     return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **fields)
 
-
-def device_op_durations(logdir: str) -> dict[str, float]:
-    """Aggregate on-device op durations (microseconds) from a trace dir.
-
-    Parses the ``.trace.json.gz`` files :func:`trace` wrote, keeps only
-    complete events on device lanes (``/device:TPU:*`` / GPU — host python
-    frames are excluded), and sums duration per op name. This is the
-    programmatic answer to "where did the step time actually go" — naive
-    wall-clock timing of individual dispatches measures the enqueue, while
-    the device trace is ground truth.
-
-    Returns ``{op_name: total_us}``, descending. Top-level module wrappers
-    (``jit_*``) are included, so ``durations["jit_train_step(...)"] /
-    num_calls`` gives honest per-step device time.
-    """
-    import collections
-    import glob
-    import gzip
-    import json
-
-    events: list[dict] = []
-    for f in glob.glob(
-        os.path.join(logdir, "**", "*.trace.json.gz"), recursive=True
-    ):
-        with gzip.open(f, "rt") as fh:
-            events.extend(json.load(fh).get("traceEvents", []))
-    pid_names = {
-        e["pid"]: e["args"].get("name", "")
-        for e in events
-        if e.get("ph") == "M" and e.get("name") == "process_name"
-    }
-    device_pids = {
-        p
-        for p, n in pid_names.items()
-        if "/device:" in n or "TPU" in n or "GPU" in n
-    }
-    totals: collections.Counter = collections.Counter()
-    if device_pids:
-        for e in events:
-            if (
-                e.get("ph") == "X"
-                and e.get("pid") in device_pids
-                and "dur" in e
-            ):
-                totals[e.get("name", "?")] += e["dur"]
-    else:
-        # XLA:CPU (tests, virtual meshes): op events live on the host
-        # process's executor threads, named "tf_XLA..."
-        xla_threads = {
-            (e["pid"], e["tid"])
-            for e in events
-            if e.get("ph") == "M"
-            and e.get("name") == "thread_name"
-            and e["args"].get("name", "").startswith("tf_XLA")
-        }
-        for e in events:
-            if (
-                e.get("ph") == "X"
-                and (e.get("pid"), e.get("tid")) in xla_threads
-                and "dur" in e
-            ):
-                totals[e.get("name", "?")] += e["dur"]
-    return dict(totals.most_common())

@@ -2,10 +2,7 @@
 
 import pytest
 import glob
-import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import optax
@@ -114,41 +111,6 @@ def test_profiler_trace_produces_artifacts(tmp_path):
             t.train(2)
     files = glob.glob(os.path.join(logdir, "**", "*"), recursive=True)
     assert any("trace" in os.path.basename(f) for f in files), files
-
-
-_DURATIONS_CHILD = """
-import json, sys
-import conftest  # the eight virtual CPU devices
-from test_checkpoint_resume import _trainer, profiling
-t = _trainer()
-t.train(1)
-with profiling.trace(sys.argv[1]):
-    t.train(2)
-print(json.dumps(profiling.device_op_durations(sys.argv[1])))
-"""
-
-
-def test_device_op_durations_parses_trace(tmp_path):
-    """The trace-analysis utility finds device lanes and aggregates op
-    time. In a process of its own: one that has loaded the TPU library
-    (the xdist worker that ran tests/test_chip_compile.py, which describes
-    a topology) traces an empty ``/device:CUSTOM:Megascale Trace`` lane on
-    the CPU, and the utility then looks for the ops there."""
-    tests = os.path.dirname(os.path.abspath(__file__))
-    path = os.pathsep.join(
-        [tests, os.path.dirname(tests), os.environ.get("PYTHONPATH", "")]
-    )
-    out = subprocess.run(
-        [sys.executable, "-c", _DURATIONS_CHILD, str(tmp_path / "trace2")],
-        env=dict(os.environ, PYTHONPATH=path), capture_output=True, text=True,
-        timeout=300,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    durations = json.loads(out.stdout.strip().splitlines()[-1])
-    assert durations  # found device events
-    assert all(v > 0 for v in durations.values())
-    vals = list(durations.values())
-    assert vals == sorted(vals, reverse=True)  # descending
 
 
 # ------------------------------------- atomic checkpoints + rollback (ISSUE 9)

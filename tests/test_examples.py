@@ -51,7 +51,7 @@ def test_int8_serving_example_runs(tmp_path):
 
 @pytest.mark.slow
 def test_int8_serving_long_context_flash(tmp_path):
-    """The long-context serving composition (SERVING_r04_long.json): the
+    """The long-context serving composition: the
     same checkpoint served at a different window (--max_seq_len) with
     flash prefill (--flash) and the unrolled fallback (--unrolled) all
     drive to completion."""

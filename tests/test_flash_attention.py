@@ -3,8 +3,8 @@
 The kernel must be a drop-in ``attention_fn`` — same math as
 ``causal_attention`` (reference has no attention of its own; SURVEY.md
 section 5.7), different memory story. Interpreter mode runs the identical
-kernel code path on the CPU mesh (real-TPU perf/memory evidence lives in
-a report produced by ``scripts/flash_bench.py``).
+kernel code path on the CPU mesh (what the kernel reaches on the chip is
+``flash_attention_roofline`` in the benchmark's training cell).
 """
 
 import jax

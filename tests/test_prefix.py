@@ -1,7 +1,7 @@
 """serve/prefix.py: the host-side radix prefix index, in isolation.
 
 Pure host code — no engine, no model, no jax (the subprocess test pins
-the jax-free property the same way the scheduler's and regress's do).
+the jax-free property the same way the scheduler's does).
 Handles are plain Python objects here: the index must treat them as
 opaque, so anything hashable works as a stand-in for a device cache tree.
 """
