@@ -23,6 +23,7 @@ decoder (RMSNorm, rotary positions, SwiGLU) written for XLA:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections.abc import Callable
 
 import jax
@@ -768,12 +769,15 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(
         self, x, decode: bool = False, prefill: bool = False,
-        adapter_ids=None, layer=None,
+        adapter_ids=None, layer=None, stacks=None,
     ):
         # ``layer``: this layer's index when the layer scan carries the
         # stacked cache (TransformerLM) — every cache variable is then the
-        # whole stack, written and read at ``[layer]``; None = own variables
+        # whole stack, written and read at ``[layer]``; None = own variables.
+        # ``stacks``: under the layer scan of a quantized model, each
+        # projection's ``(stacked Int8Param, layer index)`` by its name
         cfg = self.cfg
+        stacks = stacks or {}
         assert not (decode and prefill), "decode and prefill are exclusive"
         h, kv, d = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         if cfg.quantized:
@@ -783,13 +787,19 @@ class Attention(nn.Module):
 
             # Megatron layout: q/k/v column-split over heads, o row-split
             # (its input arrives head-sharded) with one psum per branch
-            proj = lambda name, heads: Int8DenseGeneral(  # noqa: E731
-                (heads, d), axis=-1, use_bias=False, name=name,
-                mesh=cfg.tp_mesh, shard_kind="column",
+            proj = lambda name, heads: functools.partial(  # noqa: E731
+                Int8DenseGeneral(
+                    (heads, d), axis=-1, use_bias=False, name=name,
+                    mesh=cfg.tp_mesh, shard_kind="column",
+                ),
+                stacked=stacks.get(name),
             )
-            out_proj = Int8DenseGeneral(
-                cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj",
-                mesh=cfg.tp_mesh, shard_kind="row",
+            out_proj = functools.partial(
+                Int8DenseGeneral(
+                    cfg.d_model, axis=(-2, -1), use_bias=False, name="o_proj",
+                    mesh=cfg.tp_mesh, shard_kind="row",
+                ),
+                stacked=stacks.get("o_proj"),
             )
         else:
             proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
@@ -1090,8 +1100,9 @@ class LatentUp(nn.Module):
                 "kernel", nn.initializers.lecun_normal(), shape
             )
 
-    def project(self, c: jax.Array) -> jax.Array:
-        """``c`` (B, S, rank) -> ``[k_nope | v]`` (B, S, H, nope + v)."""
+    def project(self, c: jax.Array, stacked=None) -> jax.Array:
+        """``c`` (B, S, rank) -> ``[k_nope | v]`` (B, S, H, nope + v).
+        ``stacked``: as :func:`..ops.quant._int8_affine` takes it."""
         cfg = self.cfg
         c2 = c.reshape(-1, c.shape[-1])
         if cfg.quantized:
@@ -1100,7 +1111,9 @@ class LatentUp(nn.Module):
                 int8_matmul,
             )
 
-            out = int8_matmul(c2, Int8Param(q=self.q, scale=self.scale))
+            out = int8_matmul(
+                c2, *(stacked or (Int8Param(q=self.q, scale=self.scale),))
+            )
         else:
             out = c2.astype(cfg.dtype) @ self.kernel.astype(cfg.dtype)
         return out.astype(c.dtype).reshape(
@@ -1178,9 +1191,10 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(
         self, x, decode: bool = False, prefill: bool = False,
-        adapter_ids=None, layer=None,
+        adapter_ids=None, layer=None, stacks=None,
     ):
         cfg = self.cfg
+        stacks = stacks or {}  # as Attention's
         assert not (decode and prefill), "decode and prefill are exclusive"
         h, rank = cfg.n_heads, cfg.kv_lora_rank
         nope, rope_d, vd = (
@@ -1191,8 +1205,9 @@ class LatentAttention(nn.Module):
                 Int8Dense,
             )
 
-            dense = lambda f, name: Int8Dense(  # noqa: E731
-                f, use_bias=False, name=name
+            dense = lambda f, name: functools.partial(  # noqa: E731
+                Int8Dense(f, use_bias=False, name=name),
+                stacked=stacks.get(name),
             )
         else:
             dense = lambda f, name: nn.Dense(  # noqa: E731
@@ -1271,7 +1286,7 @@ class LatentAttention(nn.Module):
                 )
                 _store_cache_index(idx, jnp.asarray(s, jnp.int32), layer)
             with jax.named_scope("latent_attn"):
-                k_v = up.project(c)  # (B, S, H, nope + v)
+                k_v = up.project(c, stacks.get("kv_up"))  # (B, S, H, nope + v)
                 k = jnp.concatenate(
                     [k_v[..., :nope],
                      jnp.broadcast_to(k_rope, (b, s, h, rope_d))], -1
@@ -1286,16 +1301,20 @@ class SwiGLU(nn.Module):
     d_ff: int | None = None  # None: cfg.ff_dim (a shared expert gives its own)
 
     @nn.compact
-    def __call__(self, x, adapter_ids=None):
+    def __call__(self, x, adapter_ids=None, stacks=None):
         cfg = self.cfg
+        stacks = stacks or {}  # as Attention's
         ff_dim = self.d_ff if self.d_ff is not None else cfg.ff_dim
         if cfg.quantized:
             from pytorch_distributed_training_tutorials_tpu.ops.quant import Int8Dense
 
             # gate/up column-split over d_ff, down row-split (Megatron MLP)
-            dense = lambda f, name, kind: Int8Dense(  # noqa: E731
-                f, use_bias=False, name=name,
-                mesh=cfg.tp_mesh, shard_kind=kind,
+            dense = lambda f, name, kind: functools.partial(  # noqa: E731
+                Int8Dense(
+                    f, use_bias=False, name=name,
+                    mesh=cfg.tp_mesh, shard_kind=kind,
+                ),
+                stacked=stacks.get(name),
             )
         else:
             dense = lambda f, name, kind: nn.Dense(  # noqa: E731
@@ -1418,13 +1437,15 @@ class Block(nn.Module):
     @nn.compact
     def __call__(
         self, x, decode: bool = False, prefill: bool = False,
-        adapter_ids=None, layer=None,
+        adapter_ids=None, layer=None, stacks=None,
     ):
         cfg = self.cfg
+        stacks = stacks or {}  # by submodule, as Attention's by projection
         attention = LatentAttention if cfg.latent else Attention
         y = attention(cfg, name="attn")(
             RMSNorm(cfg.norm_eps, name="attn_norm")(x), decode=decode,
             prefill=prefill, adapter_ids=adapter_ids, layer=layer,
+            stacks=stacks.get("attn"),
         )
         if cfg.sandwich_norm:
             y = RMSNorm(cfg.norm_eps, name="post_attn_norm")(y)
@@ -1442,7 +1463,7 @@ class Block(nn.Module):
                     y = y + SwiGLU(
                         cfg, d_ff=cfg.n_shared_experts * cfg.expert_d_ff,
                         name="shared",
-                    )(m)
+                    )(m, stacks=stacks.get("shared"))
         elif cfg.moe_experts > 0:
             # MoE blocks carry no LoRA hooks (TransformerLM rejects the
             # combination up front)
@@ -1456,7 +1477,7 @@ class Block(nn.Module):
                 name="moe",
             )(m)
         else:
-            y = SwiGLU(cfg, name="mlp")(m, adapter_ids)
+            y = SwiGLU(cfg, name="mlp")(m, adapter_ids, stacks.get("mlp"))
         if cfg.sandwich_norm:
             y = RMSNorm(cfg.norm_eps, name="post_mlp_norm")(y)
         return x + y
@@ -1471,16 +1492,51 @@ class _ScanCell(nn.Module):
     routed: bool = False
 
     @nn.compact
-    def __call__(self, x, ids, layer):
+    def __call__(self, x, ids, stacks, layers):
         # ``ids`` is the scan's nn.broadcast input: the per-row adapter-id
         # vector handed WHOLE to every layer (None when lora is off — an
-        # empty pytree, so the scanned program is unchanged). ``layer`` is
-        # the scanned layer index when the scan carries the cache, else
-        # None (also an empty pytree)
+        # empty pytree, so the scanned program is unchanged). ``stacks`` is
+        # broadcast too: the int8 weights as the scan's parameters hold
+        # them, stacked (None unless quantized). ``layers`` is the scanned
+        # layer index, once for the cache when the scan carries it and
+        # once for the weights when there are ``stacks``, else None (also
+        # an empty pytree)
+        layer, w_layer = layers
+        if stacks is not None:
+            from pytorch_distributed_training_tutorials_tpu.ops.quant import (
+                Int8Param,
+            )
+
+            stacks = jax.tree_util.tree_map(
+                lambda w: (w, w_layer), stacks,
+                is_leaf=lambda t: isinstance(t, Int8Param),
+            )
         return Block(self.cfg, self.routed, name="block")(
             x, decode=self.decode, prefill=self.prefill, adapter_ids=ids,
-            layer=layer,
+            layer=layer, stacks=stacks,
         ), None
+
+
+def _int8_stacks(block):
+    """The ``{'q', 'scale'}`` pairs of the scanned block's stacked
+    parameters as ``Int8Param`` under their modules' names, everything
+    else left out: what ``int8_matmul`` reads in place at a layer index, so
+    that ``lax.scan``'s slice of each layer's weights is dead (it was a
+    read and a write of every weight byte before the kernel read the
+    copy). None where there are none (``init``). The stacks of a routed
+    layer's experts (rank 4) stay with the slice."""
+    from pytorch_distributed_training_tutorials_tpu.ops.quant import Int8Param
+
+    def walk(tree):
+        if "q" in tree and "scale" in tree:
+            q = tree["q"]
+            if q.dtype == jnp.int8 and q.ndim == 3:
+                return Int8Param(q=q, scale=tree["scale"])
+            return None
+        out = {k: walk(v) for k, v in tree.items() if hasattr(v, "keys")}
+        return {k: v for k, v in out.items() if v is not None} or None
+
+    return walk(block)
 
 
 class TransformerLM(nn.Module):
@@ -1560,6 +1616,17 @@ class TransformerLM(nn.Module):
             # carried collection inside the scan; the stacked tree it
             # returns has the same paths, shapes and dtypes.
             carry_cache = "layers" in self.variables.get("cache", {})
+            # int8 weights are read where they lie in the stacked
+            # parameters, so the kernel is handed the stacks whole and the
+            # layer's index. Under a tensor-parallel mesh the layers keep
+            # the slice (_int8_affine: int8_matmul_tp's shard_map, a bare
+            # pallas_call cannot sit on a stack GSPMD has sharded)
+            stacks = None
+            if cfg.quantized and cfg.tp_mesh is None:
+                stacks = _int8_stacks(
+                    self.variables.get("params", {}).get("layers", {})
+                    .get("block", {})
+                )
             axes = {"params": 0, "losses": 0}
             if not carry_cache:
                 axes["cache"] = 0
@@ -1571,20 +1638,24 @@ class TransformerLM(nn.Module):
                 variable_axes=axes,
                 variable_carry="cache" if carry_cache else False,
                 split_rngs={"params": True},
-                in_axes=(nn.broadcast, 0),
+                in_axes=(nn.broadcast, nn.broadcast, 0),
                 length=cfg.n_layers,
             )(cfg, decode, prefill, routed_from == 0, name="layers")
             # the scope marks what lax.scan itself does around the cell:
-            # it slices every stacked leaf (each layer's weights, a scanned
-            # cache's slice) out by the layer index and stacks a scanned
-            # cache back. No line of the program does that, so no narrower
+            # it slices every stacked leaf (each layer's float weights,
+            # norms and scales, a scanned cache's slice) out by the layer
+            # index and stacks a scanned cache back. No line of the program does that, so no narrower
             # scope (weights_slice / kv_cache) can be put on it; an op under
             # layer_scan and not under the cell's "layers" is that slicing.
             # (A carried cache's reads and writes are the program's own
             # lines, under layers/block/attn/kv_cache.)
             with jax.named_scope("layer_scan"):
+                index = lambda on: (  # noqa: E731
+                    jnp.arange(cfg.n_layers) if on else None
+                )
                 x, _ = stack(
-                    x, ids, jnp.arange(cfg.n_layers) if carry_cache else None
+                    x, ids, stacks,
+                    (index(carry_cache), index(stacks is not None)),
                 )
         else:
             # decode/prefill are Python bools steering cache behavior — they

@@ -149,10 +149,12 @@ def dequantize_kv_int4(packed: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     ).astype(dtype)
 
 
-def _int8_matmul_kernel(x_ref, q_ref, sw_ref, out_ref, acc_ref, *, n_k: int):
+def _int8_matmul_kernel(*refs, n_k: int):
     """One (TM, TN, TK) tile: quantize the x tile per row, int8 MXU matmul,
     accumulate the dequantized partial in f32 VMEM scratch; write out on the
-    last K tile."""
+    last K tile. A scalar-prefetched layer index, where the weights are a
+    stack, comes first and only steers the index maps."""
+    x_ref, q_ref, sw_ref, out_ref, acc_ref = refs[-5:]
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -176,9 +178,10 @@ def _int8_matmul_kernel(x_ref, q_ref, sw_ref, out_ref, acc_ref, *, n_k: int):
 def int8_matmul(
     x: jax.Array,
     w: Int8Param,
+    layer: jax.Array | None = None,
     *,
     block_m: int = 256,
-    block_n: int = 256,
+    block_n: int | None = None,
     block_k: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -197,23 +200,46 @@ def int8_matmul(
     tile gets its own absmax), matched exactly by
     :func:`int8_matmul_reference` with the same ``block_k``.
 
-    All three dims are padded to tile multiples internally (zero rows/cols
-    contribute nothing and are sliced away), so any M, K, N works.
+    **The weights are read where they lie.** With ``layer`` (an int32
+    scalar, traced) ``w.q`` is a stack (L, K, N) with ``w.scale``
+    (L, 1, N), as ``nn.scan`` keeps the layers' weights, and the product is
+    layer ``layer``'s: the index rides scalar prefetch and the weight and
+    scale blocks are addressed at ``(layer, kk, j)`` in the stack itself,
+    viewed (L*K, N), so no slice of it is ever made. That needs K in whole
+    K tiles (rows past K would be the next layer's); a stack of another K
+    (toy widths) is sliced and taken as a (K, N) weight. A ragged N needs
+    no copy either: the last column block hangs over the edge (columns are
+    independent: what lies past N reaches no kept result). M is padded to
+    its tile, and K where it is no multiple of 128 (zero rows and columns
+    contribute nothing), so any M, K, N works.
+
+    ``block_n`` is no part of the arithmetic (a column's sum runs over the
+    same K tiles in the same order whatever columns share its block).
+    Unset it is 256 for a (K, N) weight and 2048 in a stack: there the
+    kernel is the only reader of the bytes (XLA staged the scan's slice in
+    fast memory, at 717 GB/s, for a kernel that then read it from there),
+    and at 128 KB a grid step it streams the stack at 264-284 GB/s, at
+    1 MB at 615-705 (one v5e chip, PERF.md section 6, PR 33).
     ``interpret=None`` auto-selects interpreter mode off-TPU so the same
     code path tests on CPU.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     m, k = x.shape
-    kq, n = w.q.shape
-    assert k == kq, (x.shape, w.q.shape)
-    if tuple(w.scale.shape) not in ((1, n), (n,)):
-        raise ValueError(
-            f"int8_matmul needs per-output-column scales of size {n} "
-            f"(quantize with channel_axis=-1); got scale shape "
-            f"{tuple(w.scale.shape)}"
+    kq, n = w.q.shape[-2:]
+    assert k == kq and w.q.ndim == (2 if layer is None else 3), (
+        x.shape, w.q.shape, layer
+    )
+    block_k = min(block_k, max(128, k))
+    block_k = -(-block_k // 128) * 128
+    pad_k = (-k) % block_k
+    if layer is not None and pad_k:
+        # rows past k in the last K tile would be the next layer's
+        return int8_matmul(
+            x, Int8Param(q=w.q[layer], scale=w.scale[layer]),
+            block_m=block_m, block_n=block_n, block_k=block_k,
+            interpret=interpret,
         )
-    scale_row = w.scale.reshape(1, n).astype(jnp.float32)
 
     # sublane alignment: f32 blocks need second-to-last dim % 8 == 0 on real
     # TPU (interpret mode would hide a violation); K tiles stay % 128 (lane
@@ -222,53 +248,69 @@ def int8_matmul(
     block_m = -(-block_m // 8) * 8
     # N is the lane dim of the output/q blocks: round up to 128 like K (an
     # odd-vocab lm_head must not hand the real-TPU kernel a sub-lane tile;
-    # pad_n below absorbs the rounding)
+    # the last block hangs over N)
+    if block_n is None:
+        block_n = 256 if layer is None else 2048
     block_n = min(block_n, max(128, n))
     block_n = -(-block_n // 128) * 128
-    block_k = min(block_k, max(128, k))
-    block_k = -(-block_k // 128) * 128
     pad_m = (-m) % block_m
-    pad_n = (-n) % block_n
-    pad_k = (-k) % block_k
+    want = (1, n) if layer is None else (w.q.shape[0], 1, n)
+    if tuple(w.scale.shape) not in (want, (n,)):
+        raise ValueError(
+            f"int8_matmul needs per-output-column scales of size {n} "
+            f"(quantize with channel_axis=-1); got scale shape "
+            f"{tuple(w.scale.shape)}"
+        )
+    scale = w.scale.reshape(want).astype(jnp.float32)
     if pad_m or pad_k:
         x = jnp.pad(x, ((0, pad_m), (0, pad_k)))
-    q = w.q
-    if pad_n or pad_k:
-        q = jnp.pad(q, ((0, pad_k), (0, pad_n)))
-    if pad_n:
-        scale_row = jnp.pad(
-            scale_row, ((0, 0), (0, pad_n)), constant_values=1.0
-        )
-    mp, np_, kp = m + pad_m, n + pad_n, k + pad_k
-    n_k = kp // block_k
+    q = jnp.pad(w.q, ((0, pad_k), (0, 0))) if pad_k else w.q
+    mp = m + pad_m
+    n_k = (k + pad_k) // block_k
 
+    if layer is None:
+        prefetch = ()
+        q_map = lambda i, j, kk: (kk, j)  # noqa: E731
+        scale_spec = pl.BlockSpec(
+            (1, block_n), lambda i, j, kk: (0, j), memory_space=pltpu.VMEM
+        )
+    else:
+        # rank 2: layer l's K tile kk is row block l * n_k + kk of the stack
+        prefetch = (jnp.asarray(layer, jnp.int32).reshape(1),)
+        q = q.reshape(-1, n)
+        q_map = lambda i, j, kk, l: (l[0] * n_k + kk, j)  # noqa: E731
+        scale_spec = pl.BlockSpec(
+            (None, 1, block_n), lambda i, j, kk, l: (l[0], 0, j),
+            memory_space=pltpu.VMEM,
+        )
     out = pl.pallas_call(
         functools.partial(_int8_matmul_kernel, n_k=n_k),
-        grid=(mp // block_m, np_ // block_n, n_k),
-        in_specs=[
-            pl.BlockSpec(
-                (block_m, block_k),
-                lambda i, j, kk: (i, kk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(mp // block_m, pl.cdiv(n, block_n), n_k),
+            in_specs=[
+                pl.BlockSpec(
+                    (block_m, block_k),
+                    lambda i, j, kk, *_: (i, kk),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (block_k, block_n), q_map, memory_space=pltpu.VMEM
+                ),
+                scale_spec,
+            ],
+            out_specs=pl.BlockSpec(
+                (block_m, block_n),
+                lambda i, j, kk, *_: (i, j),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(
-                (block_k, block_n),
-                lambda i, j, kk: (kk, j),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, block_n), lambda i, j, kk: (0, j), memory_space=pltpu.VMEM
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (block_m, block_n), lambda i, j, kk: (i, j), memory_space=pltpu.VMEM
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         name="int8_matmul",
         interpret=interpret,
-    )(x.astype(jnp.float32), q, scale_row)
-    return out[:m, :n] if (pad_m or pad_n) else out
+    )(*prefetch, x.astype(jnp.float32), q, scale)
+    return out[:m] if pad_m else out
 
 
 def int8_matmul_tp(
@@ -376,12 +418,17 @@ def int8_matmul_reference(
     return acc * w.scale.reshape(1, -1)
 
 
-def _int8_affine(mod: nn.Module, x, feats: tuple, n_in: int, use_bias: bool):
+def _int8_affine(
+    mod: nn.Module, x, feats: tuple, n_in: int, use_bias: bool, stacked=None
+):
     """The shared body of the int8 serving layers: flattened 2-D ``q`` +
     per-column ``scale`` params, the K-blocked MXU matmul, reshape, bias —
     one copy for Int8Dense and Int8DenseGeneral. With ``mod.mesh`` +
     ``mod.shard_kind`` set (and the axis really in the mesh), the matmul
-    runs tensor-parallel through :func:`int8_matmul_tp`."""
+    runs tensor-parallel through :func:`int8_matmul_tp`. ``stacked``, under
+    the layer scan, is ``(Int8Param, layer)``: the stack this layer's ``q``
+    and ``scale`` were sliced from and the layer's index, which the kernel
+    reads in place of the slices (they are dead then, and never made)."""
     in_dims = x.shape[x.ndim - n_in :]
     k = 1
     for d in in_dims:
@@ -405,6 +452,8 @@ def _int8_affine(mod: nn.Module, x, feats: tuple, n_in: int, use_bias: bool):
         out2 = int8_matmul_tp(
             x2, w, mesh, kind=mod.shard_kind, axis=mod.shard_axis
         )
+    elif stacked is not None:
+        out2 = int8_matmul(x2, *stacked)
     else:
         out2 = int8_matmul(x2, w)
     out = out2.reshape(*lead, *feats)
@@ -436,9 +485,9 @@ class Int8Dense(nn.Module):
     shard_axis: str = "model"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, stacked=None):
         return _int8_affine(
-            self, x, (self.features,), 1, self.use_bias
+            self, x, (self.features,), 1, self.use_bias, stacked
         )
 
 
@@ -460,14 +509,16 @@ class Int8DenseGeneral(nn.Module):
     shard_axis: str = "model"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, stacked=None):
         feats = (
             self.features
             if isinstance(self.features, tuple)
             else (self.features,)
         )
         axes = self.axis if isinstance(self.axis, tuple) else (self.axis,)
-        return _int8_affine(self, x, feats, len(axes), self.use_bias)
+        return _int8_affine(
+            self, x, feats, len(axes), self.use_bias, stacked
+        )
 
 
 def _grouped_int8_kernel(te_ref, x_ref, q_ref, sw_ref, out_ref, acc_ref, *,

@@ -54,3 +54,27 @@ def make_cls_dataset(n=256, dim=16, classes=4, seed=0, noise=0.1):
         np.float32
     )
     return ArrayDataset((x, labels))
+
+
+def pallas_operands(fn, *args, name=None):
+    """``[(dtype, shape), ...]`` of the operands of every ``pallas_call``
+    in the jaxpr of ``fn(*args)``, inner jaxprs (a scan's or a
+    ``shard_map``'s body) included; ``name`` keeps the kernels of that
+    ``name=`` alone."""
+    import jax
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call" and name in (
+                None, eqn.params["name"]
+            ):
+                found.append(
+                    [(str(v.aval.dtype), v.aval.shape) for v in eqn.invars]
+                )
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found

@@ -151,15 +151,82 @@ def test_fused_adamw_compiles(one_chip):
         (256, D_MODEL, D_FF),
         (8, D_FF, D_MODEL),
         (8, D_MODEL, VOCAB),
+        (32, D_MODEL, 92544),  # 723 x 128: no block of 256 divides it
+        (8, D_MODEL, 1000),  # no multiple of 128 either
     ],
-    ids=["decode_up", "prefill_up", "decode_down", "decode_head"],
+    ids=["decode_up", "prefill_up", "decode_down", "decode_head",
+         "chat_head", "ragged_head"],
 )
 def test_int8_matmul_compiles(one_chip, m, k, n):
+    """A (k, n) weight: three operands, x first (the call of every
+    unrolled layer and of the head). A ragged n is no reason for a copy:
+    the weight reaches the kernel as the argument it is (before ISSUE 33
+    the chat cell's 190 MB head was padded to 92,672 columns on every
+    chain)."""
     x = _sds((m, k), jnp.bfloat16)
     w = Int8Param(q=_sds((k, n), jnp.int8), scale=_sds((1, n), jnp.float32))
-    _compile(
+    hlo = _compile(
         lambda x, w: int8_matmul(x, w, interpret=False), one_chip, x, w
     )
+    call, = re.findall(r"= f32\[[\d,]+\]\S* custom-call\(([^)]*)\)", hlo)
+    assert len(call.split(",")) == 3  # no s32[1] layer index
+    assert " pad(" not in hlo
+    assert f"f32[{m},{n}]" in hlo  # the result is allocated (m, n)
+
+
+# (layers, slots or bucket, k, n): the chat and long cells' up and down
+# projections, a decode step and a prefill bucket
+STACKS = {
+    "chat_up": (24, 32, 2048, 8192),
+    "chat_down": (24, 32, 8192, 2048),
+    "long_up": (32, 8, 4096, 14336),
+    "long_down_prefill": (32, 2048, 14336, 4096),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STACKS))
+def test_int8_matmul_reads_the_stack_in_place(one_chip, case):
+    """The stacked form at the cells' widths: the (L, k, n) stack reaches
+    the kernel viewed (L*k, n), which the chip's compiler takes as a
+    bitcast (k is a whole number of its 32-row int8 tiles); nothing is
+    sliced, copied or allocated beside the result."""
+    layers, m, k, n = STACKS[case]
+    compiled = jax.jit(
+        lambda x, q, s, l: int8_matmul(
+            x, Int8Param(q=q, scale=s), l, interpret=False)
+    ).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in [
+            ((m, k), jnp.float32), ((layers, k, n), jnp.int8),
+            ((layers, 1, n), jnp.float32), ((), jnp.int32),
+        ]
+    ]).compile()
+    hlo = compiled.as_text()
+    assert re.search(
+        rf"%int8_matmul[\w.]* = f32\[{m},{n}\]\S* custom-call\(", hlo)
+    assert f"s32[1]{{0}}, f32[{m},{k}]{{1,0}}, s8[{layers * k},{n}]{{1,0}}" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    assert _s8_made(hlo) == []
+
+
+def _s8_made(hlo: str) -> list:
+    """(opcode, dims) of every instruction of an optimized HLO text that
+    MAKES one layer's int8 weight: an ``s8`` result of rank 2, or of rank 3
+    with a leading 1 (a layer's slice, a copy, the padded head, or the
+    fusion of one: ``dynamic-slice_bitcast_fusion`` was the scan's).
+    Parameters, tuple plumbing and bitcasts make nothing, and what the
+    compiler's own prefetch moves into fast memory ahead of its use
+    (``S(1)``: ``copy-start`` / ``copy-done`` of a weight small enough, at
+    toy sizes; ``slice-start`` of six layers of a whole stack at a time,
+    rank 3, once a launch) is not the program's copy; an int8 or int4
+    cache's rows are rank 4."""
+    quiet = ("parameter", "get-tuple-element", "bitcast", "copy-done")
+    return [
+        (op, dims)
+        for dims, op in re.findall(
+            r"= s8\[((?:1,)?\d+,\d+)\]\S* ([\w\-]+)\(", hlo)
+        if op not in quiet
+    ]
 
 
 @pytest.mark.parametrize(
@@ -415,6 +482,9 @@ def test_serve_chain_carries_the_cache_in_place(
     )
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo  # the int8 kernels, not their emulation
+    # the weights are read where they lie (ISSUE 33): no layer's int8
+    # slice, no copy, no padded head is made in any of these programs
+    assert _s8_made(hlo) == []
 
     attn = state["cache"]["layers"]["block"]["attn"]
     kv = attn[leaf]
@@ -443,8 +513,9 @@ def test_serve_chain_carries_the_cache_in_place(
     # layer's slice of either (the read copy and its change of layout,
     # half of the cell's device time before ISSUE 31): every bfloat16
     # result of that size or more is a whole stack, updated in place.
-    # (What is left of ``temp_size_in_bytes``, 190 MB, is the int8 head
-    # padded to s8[2048, 92672], as it was before.)
+    # Nothing else is left of ``temp_size_in_bytes`` (190 MB before ISSUE
+    # 33: the int8 head padded to s8[2048, 92672]).
+    assert analysis.temp_size_in_bytes < 1 << 20
     assert re.search(
         r"%decode_attention[\w.]* = f32\[32,16,128\]\S* custom-call\(", hlo
     ), "no decode_attention kernel in the chain"
@@ -458,6 +529,76 @@ def test_serve_chain_carries_the_cache_in_place(
         dims == "24,32,2048,8,128" and op not in ("copy", "dynamic-slice")
         for op, dims in big
     ), big
+
+
+TOY = dict(  # every k a whole K block; a head of 9 x 128 columns, ragged
+    vocab_size=1152, d_model=256, n_layers=3, n_heads=2, d_ff=512,
+    max_seq_len=256, dtype=jnp.float32, kv_cache_dtype=jnp.bfloat16,
+    quantized=True,
+)
+
+
+@pytest.mark.parametrize(
+    "scan_layers, program",
+    [(True, "_chain_fn"), (True, "_prefill_fn"), (False, "_chain_fn")],
+    ids=["scan_chain", "scan_prefill", "unrolled_chain"],
+)
+def test_int8_programs_copy_no_weight(
+    one_chip, monkeypatch, scan_layers, program
+):
+    """A quantized toy model through ``ServeEngine``: under the layer scan
+    the chain (which carries its cache) and the prefill (which creates it)
+    feed every layer's ``int8_matmul`` the stack and the ``s32[1]`` layer
+    index, the head the (k, n) weight as it is, ragged; no program makes a
+    layer's int8 weight (slice, copy or pad). The same model unrolled
+    compiles to the (k, n) calls alone: no layer index anywhere."""
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+    from pytorch_distributed_training_tutorials_tpu.serve import (
+        engine as engine_module,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve.slots import (
+        init_slot_state,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        engine_module, "init_slot_state",
+        lambda model, params, *a, **kw: jax.eval_shape(
+            lambda p: init_slot_state(model, p, *a, **kw), params
+        ),
+    )
+    model = TransformerLM(TransformerConfig(**TOY, scan_layers=scan_layers))
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    engine = ServeEngine(model, params, n_slots=4, tokens_per_launch=4)
+    args = (params, engine._state)
+    if program == "_prefill_fn":
+        i32 = _sds((), jnp.int32)
+        args += (_sds((1, 64), jnp.int32), i32, i32, i32, i32)
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        args,
+    )
+    hlo = (
+        jax.jit(getattr(engine, program), donate_argnums=(1,))
+        .lower(*placed).compile().as_text()
+    )
+    calls = re.findall(
+        r"%int8_matmul[\w.]* = f32\[[\d,]+\]\S* custom-call\(.*?"
+        r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", hlo)
+    stacked = [c for c in calls if c.startswith("s32[1]{0}, ")]
+    assert len(calls) - len(stacked) == (1 if scan_layers else 1 + 7 * 3)
+    assert len(stacked) == (7 if scan_layers else 0)
+    assert all(re.search(r", s8\[(768|1536),\d+\]\{1,0\}, f32\[3,1,", c)
+               for c in stacked), stacked
+    assert any(", s8[256,1152]{1,0}, f32[1,1152]" in c for c in calls)
+    assert _s8_made(hlo) == []
 
 
 def test_float_tp_serve_chain_compiles_on_a_mesh(topo, monkeypatch):

@@ -215,3 +215,25 @@ def test_latent_cache_rows_are_written_under_kv_cache(scan_layers):
     assert f'{layer}/attn/kv_cache/scatter"' in text
     assert bool(re.search(r'[/"(]layer_scan\)*/', text)) is scan_layers
     assert "grouped_int8_matmul" in text and "latent_decode_attention" in text
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["unrolled", "scan"])
+@pytest.mark.parametrize("which", ["chain", "prefill"])
+def test_int8_matmul_keeps_its_name_in_both_forms(which, scan_layers):
+    """``name="int8_matmul"`` under ``<layer>/mlp/up_proj/int8_matmul/
+    pallas_call`` whether the call reads a (k, n) weight (unrolled layers,
+    the head) or its layer in the scanned stack at a scalar-prefetched
+    index (ISSUE 33): what ``int8_matmul_stacked_roofline.*`` finds the
+    calls by, and the by-scope table counts under ``mlp``."""
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=128, n_layers=2, n_heads=2, d_ff=128,
+        max_seq_len=32, quantized=True, scan_layers=scan_layers,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    engine = ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+    text = _lowered(which, engine, None).as_text(debug_info=True)
+    layer = "layers/block" if scan_layers else "block_1"
+    assert f'{layer}/mlp/up_proj/int8_matmul/pallas_call"' in text
+    assert 'lm_head/int8_matmul/pallas_call"' in text

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from helpers import pallas_operands
 
 from pytorch_distributed_training_tutorials_tpu.models.generate import generate
 from pytorch_distributed_training_tutorials_tpu.models.transformer import (
@@ -519,3 +520,101 @@ def test_int8_kv_cache_composes_with_gqa_and_flash():
     out_i8 = np.asarray(generate(i8, params, tokens, max_new_tokens=8))
     np.testing.assert_array_equal(out_i8[:, :16], np.asarray(tokens))
     assert (out_f32 == out_i8).mean() >= 0.6
+
+
+STACK_READ_CASES = {
+    # widths in whole K blocks: every scanned weight is read in the stack
+    "d128": (dict(d_model=128, n_heads=2, d_ff=256), True),
+    "d256_gqa": (dict(d_model=256, n_heads=4, n_kv_heads=2, d_ff=384), True),
+    "d128_bf16_cache": (
+        dict(d_model=128, n_heads=2, d_ff=256, kv_cache_dtype=jnp.bfloat16),
+        True,
+    ),
+    "latent": (
+        dict(d_model=128, n_heads=2, d_ff=256, q_lora_rank=128,
+             kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+             v_head_dim=64),
+        True,
+    ),
+    # a toy width under the 128-lane K block: the fallback by shape
+    "d64_sliced": (dict(d_model=64, n_heads=2, d_ff=96), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STACK_READ_CASES))
+def test_scanned_int8_layers_read_the_stack_and_equal_unrolled(case):
+    """ISSUE 33: under the layer scan ``int8_matmul`` is handed the
+    stacked ``q`` / ``scale`` and the layer's index, and reads the layer's
+    weights in the stack. That moves no arithmetic: the unrolled model on
+    the same weights is the parent's arithmetic (rank-2 calls, which it
+    keeps), and a prefill that creates its cache (the scan runs over the
+    cache) and 8 decode steps that carry it give its logits to the last
+    bit. Which call ran is read off the jaxpr: a scalar ``int32[1]`` first
+    operand and the stack viewed (L*k, n) for every scanned layer, the
+    parent's three operands for the head, for the unrolled model and at a
+    width that is no whole K block."""
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        stack_quantized_lm_params,
+    )
+
+    overrides, reads_stack = STACK_READ_CASES[case]
+    cfg = TransformerConfig(
+        vocab_size=300, n_layers=3, max_seq_len=32, **overrides
+    )
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 300)
+    params = quantize_lm_params(
+        TransformerLM(cfg).init(jax.random.PRNGKey(0), tokens)["params"]
+    )
+    stacked = stack_quantized_lm_params(params)
+    unrolled = TransformerLM(dataclasses.replace(cfg, quantized=True))
+    scanned = TransformerLM(
+        dataclasses.replace(cfg, quantized=True, scan_layers=True)
+    )
+
+    def run(model, p):
+        prefill = jax.jit(lambda p, t: model.apply(
+            {"params": p}, t, prefill=True, mutable=["cache"]))
+        step = jax.jit(lambda p, c, t: model.apply(
+            {"params": p, "cache": c}, t, decode=True, mutable=["cache"]))
+        logits, upd = prefill(p, tokens)
+        out = [logits]
+        for _ in range(8):
+            tok = jnp.argmax(out[-1][:, -1], -1)[:, None]
+            logits, upd = step(p, upd["cache"], tok)
+            out.append(logits)
+        return out, upd["cache"], prefill, step
+
+    want, _, _, _ = run(unrolled, params)
+    got, cache, prefill, step = run(scanned, stacked)
+    for a, b in zip(got, want):
+        if reads_stack:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            # at this width the scanned and the unrolled program's float
+            # fusions already differed in the last digit before ISSUE 33
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+
+    tok = jnp.zeros((2, 1), jnp.int32)
+    for calls in (
+        pallas_operands(prefill, stacked, tokens, name="int8_matmul"),
+        pallas_operands(step, stacked, cache, tok, name="int8_matmul"),
+    ):
+        head, layers = calls[-1], calls[:-1]
+        # (k is padded to the 128-lane floor at the toy width; n never is)
+        assert len(head) == 3
+        assert head[1] == ("int8", (max(cfg.d_model, 128), 300))
+        assert layers
+        for ops in layers:
+            if reads_stack:
+                assert ops[0] == ("int32", (1,)), ops
+                assert ops[2][0] == "int8" and ops[2][1][0] % 3 == 0
+                assert ops[3][1][:2] == (3, 1)  # (L, 1, n) scales
+            else:
+                assert [o[0] for o in ops] == ["float32", "int8", "float32"]
+    for ops in pallas_operands(
+        lambda p, t: unrolled.apply(
+            {"params": p}, t, prefill=True, mutable=["cache"]),
+        params, tokens, name="int8_matmul",
+    ):
+        assert len(ops) == 3 and ops[0][0] != "int32"

@@ -3,6 +3,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from helpers import pallas_operands
 
 from pytorch_distributed_training_tutorials_tpu.ops.quant import (
     Int8Dense,
@@ -224,3 +226,93 @@ def test_int8_matmul_tp_validates():
         )
     with pytest.raises(ValueError, match="kind must be"):
         int8_matmul_tp(x, w, mesh2, kind="diag")
+
+
+def _stack(layers, k, n, seed=20):
+    """``layers`` kernels (k, n), each quantized on its own, stacked as
+    ``nn.scan`` keeps them: ``q`` (L, k, n), ``scale`` (L, 1, n)."""
+    return quantize_int8(_w((layers, k, n), seed=seed), reduce_axis=-2)
+
+
+# k of one K block (128: block_k is min(512, k)), of several (1024 = 2 x
+# 512), and n that no block of 256 divides (384 = 3 x 128, the ragged head
+# 92,544 = 723 x 128 scaled down; 200, no multiple of 128 either)
+STACKED = [(128, 256), (1024, 384), (256, 200)]
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("k,n", STACKED, ids=lambda v: str(v))
+def test_int8_matmul_reads_a_layer_in_the_stack(k, n, layer):
+    """The stacked form (scalar-prefetched layer index, the weight block
+    addressed in the stack viewed (L*k, n)) gives the rank-2 call's result
+    on the sliced layer to the last bit, traced index and all, and the
+    reference's math."""
+    x = jnp.asarray(_w((5, k), seed=21))
+    w = _stack(3, k, n)
+    got = jax.jit(lambda x, w, l: int8_matmul(x, w, l))(
+        x, w, jnp.int32(layer)
+    )
+    sliced = Int8Param(q=w.q[layer], scale=w.scale[layer])
+    assert got.shape == (5, n)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(int8_matmul(x, sliced))
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(int8_matmul_reference(x, sliced)),
+        rtol=2e-5, atol=1e-4,
+    )
+
+
+@pytest.mark.parametrize(
+    "k,stacked_call", [(128, True), (1024, True), (64, False), (640, False)],
+)
+def test_int8_matmul_stack_form_follows_k(k, stacked_call):
+    """What decides the call's form is the stack's shape: k in whole K
+    blocks takes the prefetch form on the stack viewed (L*k, n), no slice
+    made; a k that is no whole number of K blocks (toy widths under the
+    128-lane floor, 640 under a block of 512: rows past k would be the next
+    layer's) is sliced and padded as a rank-2 weight, and gives the same
+    numbers as that call."""
+    x = jnp.asarray(_w((8, k), seed=22))
+    w = _stack(2, k, 128)
+    layer = jnp.int32(1)
+    (ops,) = pallas_operands(lambda x, w, l: int8_matmul(x, w, l), x, w, layer)
+    if stacked_call:
+        assert ops[0] == ("int32", (1,)) and ops[2] == ("int8", (2 * k, 128))
+        assert ops[3] == ("float32", (2, 1, 128))
+    else:
+        assert [o[0] for o in ops] == ["float32", "int8", "float32"]
+        assert len(ops[1][1]) == 2
+    sliced = Int8Param(q=w.q[1], scale=w.scale[1])
+    np.testing.assert_array_equal(
+        np.asarray(int8_matmul(x, w, layer)),
+        np.asarray(int8_matmul(x, sliced)),
+    )
+
+
+@pytest.mark.parametrize("n", [384, 200, 96])
+def test_int8_matmul_ragged_n_makes_no_copy_of_the_weight(n):
+    """A ragged N hangs the last column block over the edge: the weight
+    and its scales reach the kernel as they are (no ``pad`` in the jaxpr,
+    the kernel's operands are the arguments' own shapes) and the result is
+    allocated (m, n)."""
+    x = jnp.asarray(_w((8, 128), seed=23))
+    w = quantize_int8(_w((128, n), seed=24))
+    fn = lambda x, w: int8_matmul(x, w)  # noqa: E731
+    assert "pad" not in str(jax.make_jaxpr(fn)(x, w))
+    (ops,) = pallas_operands(fn, x, w)
+    assert ops == [
+        ("float32", (8, 128)), ("int8", (128, n)), ("float32", (1, n))
+    ]
+    got = np.asarray(fn(x, w))
+    np.testing.assert_allclose(
+        got, np.asarray(int8_matmul_reference(x, w)), rtol=2e-5, atol=1e-4
+    )
+    # bit-equal to the product on the weight padded to whole blocks, which
+    # is how the wrapper fed the kernel before (N tiling is no arithmetic)
+    pad = (-n) % 128
+    padded = Int8Param(
+        q=jnp.pad(w.q, ((0, 0), (0, pad))),
+        scale=jnp.pad(w.scale, ((0, 0), (0, pad)), constant_values=1.0),
+    )
+    np.testing.assert_array_equal(got, np.asarray(fn(x, padded))[:, :n])

@@ -361,3 +361,47 @@ def test_tp2_paged_kernel_token_exact(model_params):
     _, out_k = _run_stream(model, params, reqs, strategy=_tp(2),
                            paged_kernel=True, **kw)
     assert [c.tokens for c in out_k] == [c.tokens for c in out_r]
+
+
+def test_tp2_int8_layers_keep_the_slice_under_the_scan():
+    """Int8 weights under ``cfg.tp_mesh``: a replicated scanned model hands
+    ``int8_matmul`` the stacked weights and the layer's index (ISSUE 33);
+    with the mesh on its config every layer keeps ``lax.scan``'s slice and
+    runs ``int8_matmul_tp`` on it (its ``shard_map`` splits a (k, n)
+    weight; a bare ``pallas_call`` cannot sit on a stack GSPMD has
+    sharded), at widths where the stack would otherwise be read in place.
+    The sharded logits are the replicated ones up to the row-parallel
+    layers' regrouped activation rounding."""
+    import numpy as np
+    from helpers import pallas_operands
+
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        place_int8_lm_params,
+        quantize_lm_params,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=128, n_layers=2, n_heads=2, d_ff=256,
+        max_seq_len=32, scan_layers=True,
+    )
+    model, params = _make(cfg)
+    qparams = quantize_lm_params(params)
+    mesh = create_mesh({"data": 4, "model": 2})
+    tokens = jnp.asarray([_prompt(9100, 8), _prompt(9101, 8)], jnp.int32)
+
+    def forms(model, p):
+        """First-operand dtype of every pallas_call, scan bodies and
+        shard_map bodies included."""
+        return [ops[0][0] for ops in pallas_operands(
+            lambda p: model.apply({"params": p}, tokens), p)]
+
+    rep = TransformerLM(dataclasses.replace(cfg, quantized=True))
+    tp = TransformerLM(dataclasses.replace(cfg, quantized=True, tp_mesh=mesh))
+    placed = place_int8_lm_params(qparams, mesh)
+    assert forms(rep, qparams).count("int32") == 7  # the scanned layers'
+    assert forms(tp, placed) and "int32" not in forms(tp, placed)
+    want = jax.jit(lambda p: rep.apply({"params": p}, tokens))(qparams)
+    got = jax.jit(lambda p: tp.apply({"params": p}, tokens))(placed)
+    spread = float(jnp.std(want))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=0.15 * spread)
