@@ -16,6 +16,10 @@ ends the run with a non-zero exit code:
 - ``serve``: all 16 layers in int8 through ``serve.ServeEngine`` with its
   default options, checked against ``models.generate``; then the paged
   engine with the Pallas page-walk kernel against the gather path.
+- ``serve_state``: one request through a model with recurrent state
+  (``mb_per_layer``: Phi-4-mini-flash-reasoning's widths, 8 of its 32
+  layers: Mamba state, a window ring and one shared cache in a slot), its
+  prompt longer than the attention window, against ``models.generate``.
 
 ``--chips 4`` runs, and runs only, the multi-chip phase: the ``train``
 model on device 0 alone, under ``DataParallel`` on ``create_mesh()`` and
@@ -47,12 +51,19 @@ REAL = dict(
     warm_lens=[16, 32, 64, 128, 256],
     prompt_lens=[16, 24, 40, 64, 96, 128, 160, 200, 256, 33, 77, 250],
     new_tokens=32, page_size=64, pool_pages=64,
+    # Phi-4-mini-flash-reasoning's widths at a toy depth (8 of 32 layers:
+    # two periods of Mamba and window layers, the Mamba and the full layer
+    # between, one period of Gated Memory Unit and cross-attention)
+    state=dict(d_model=2560, n_heads=40, n_kv_heads=20, d_ff=10240,
+               n_layers=8, window=1024, ring=512, prompt_len=600),
 )
 TOY = dict(
     vocab=256, d_model=64, n_heads=4, d_ff=128, n_layers=2,
     seq=64, train_layers=2, batch=4, steps=5, window=64,
     warm_lens=[8, 16, 32], prompt_lens=[8, 12, 20, 32, 9, 31],
     new_tokens=8, page_size=8, pool_pages=32,
+    state=dict(d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, n_layers=8,
+               window=64, ring=8, prompt_len=20),
 )
 DEPTH_CUT = (
     "one chip's 16 GB cannot hold 16 layers of f32 parameters with two "
@@ -628,6 +639,72 @@ def phase_serve(args, w: dict, log: CompileLog) -> None:
     )
 
 
+def phase_serve_state(args, w: dict, log: CompileLog) -> None:
+    """One request through a model with recurrent state (``mb_per_layer``:
+    Mamba state, a window ring and one shared cache in a slot), int8, its
+    prompt longer than the attention window: ``ServeEngine`` against
+    ``models.generate``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_distributed_training_tutorials_tpu.models import (
+        TransformerConfig, TransformerLM,
+    )
+    from pytorch_distributed_training_tutorials_tpu.models.generate import (
+        generate,
+    )
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        quantize_lm_params,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+
+    st = w["state"]
+    cfg = TransformerConfig(
+        vocab_size=w["vocab"], d_model=st["d_model"], n_heads=st["n_heads"],
+        n_kv_heads=st["n_kv_heads"], d_ff=st["d_ff"], n_layers=st["n_layers"],
+        max_seq_len=st["window"], norm_eps=1e-5, mb_per_layer=2,
+        sliding_window=st["ring"], tie_embeddings=True, scan_layers=True,
+        dtype=jnp.bfloat16,
+    )
+    params = jax.jit(TransformerLM(cfg).init)(
+        jax.random.PRNGKey(args.seed), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    params = jax.jit(
+        lambda p: quantize_lm_params(p, jnp.bfloat16), donate_argnums=0
+    )(params)
+    lm = TransformerLM(dataclasses.replace(cfg, quantized=True))
+    log.take()
+    engine = ServeEngine(lm, params, n_slots=2, tokens_per_launch=8)
+    prompt = prompts_for(w, [st["prompt_len"]], args.seed + 3)
+    t0 = time.perf_counter()
+    done = serve_requests(engine, prompt, w["new_tokens"])
+    wall_s = time.perf_counter() - t0
+    ref = np.asarray(
+        generate(lm, params, np.asarray(prompt, np.int32), w["new_tokens"])
+    )[0, st["prompt_len"]:].tolist()
+    gap = GreedyJudge({"window": st["window"]}, lm, params).gap(
+        prompt[0], done[0].tokens, ref
+    )
+    failed = []
+    if done[0].finish_reason != "length":
+        failed.append(f"finish reason {done[0].finish_reason}")
+    if not gap <= TOL_GREEDY_GAP:
+        failed.append("greedy decode differs from models.generate")
+    finish_phase(
+        {
+            "phase": "serve_state", "model": dict(st, vocab=w["vocab"]),
+            "weights": "int8, made on the device from --seed",
+            "prompt_len": st["prompt_len"], "new_tokens": w["new_tokens"],
+            "wall_s_with_builds": wall_s, "builds": log.take(),
+            "generate_tokens_equal": done[0].tokens == ref,
+            "generate_greedy_gap_rel": gap, **engine.stats("slot"),
+            "peak_bytes_in_use": peak_bytes(jax.devices()[0]),
+        },
+        failed,
+    )
+
+
 # -- four chips ------------------------------------------------------------
 
 
@@ -767,6 +844,7 @@ def main() -> None:
     else:
         phase_train(args, w, log)
         phase_serve(args, w, log)
+        phase_serve_state(args, w, log)
     emit(phase="total", ok=True, wall_s=time.perf_counter() - t0)
     emit(ok=True, device=device)
 

@@ -184,6 +184,27 @@ class TransformerConfig:
     n_shared_experts: int = 0
     routed_scaling: float = 1.0
     n_dense_layers: int = 0
+    # A decoder that reads its own memory twice (models/sambay.py; the
+    # published keys of Phi-4-mini-flash-reasoning): mb_per_layer > 0 makes
+    # every mb_per_layer-th layer a Mamba-1 mixer in the first half of the
+    # layers (and the one after it) and a Gated Memory Unit on that layer's
+    # scan output in the second; the layers between attend differentially,
+    # over the sliding_window newest positions in the first half, then once
+    # over the whole context, then by a query alone on that one layer's K
+    # and V. LayerNorm, biases on the attention projections, no rotary
+    # embedding; norm_eps is the LayerNorm's. The Mamba sizes are the
+    # published class's defaults (mamba_dt_rank None: ceil(d_model / 16)).
+    mb_per_layer: int = 0
+    sliding_window: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int | None = None
+    # logits = final_norm(x) E^T with E the embedding: no lm_head of its
+    # own. Served in int8 the head is the embedding's transpose quantized a
+    # vocabulary row (a head column), and the embedding those values
+    # dequantized, so the two stay one matrix (quantize_lm_params)
+    tie_embeddings: bool = False
 
     @property
     def latent(self) -> bool:
@@ -1368,8 +1389,64 @@ def _remat_policy(cfg: TransformerConfig):
 
 
 def _check_new_block_fields(cfg: TransformerConfig) -> None:
-    """Refuse, in words, the combinations latent attention and the
-    dropless experts do not run with yet."""
+    """Refuse, in words, the combinations latent attention, the dropless
+    experts and the layers with recurrent state do not run with yet."""
+    if cfg.tie_embeddings and not cfg.mb_per_layer:
+        raise ValueError(
+            "tie_embeddings is the head of the layout mb_per_layer gives "
+            "(models/sambay.py); the other layouts keep an lm_head of "
+            "their own"
+        )
+    if cfg.mb_per_layer:
+        if cfg.mb_per_layer != 2 or cfg.n_layers % 4 or cfg.n_layers < 8:
+            raise ValueError(
+                "mb_per_layer lays the layers out in periods of two (a "
+                "Mamba or Gated Memory Unit layer, then an attention "
+                "layer) around the two middle layers: it must be 2, and "
+                f"n_layers a multiple of 4 from 8 up; got mb_per_layer="
+                f"{cfg.mb_per_layer}, n_layers={cfg.n_layers}"
+            )
+        if cfg.sliding_window < 1 or not cfg.tie_embeddings:
+            raise ValueError(
+                "mb_per_layer needs sliding_window >= 1 (the window "
+                "layers' ring of K and V) and tie_embeddings=True (the "
+                "published head is the embedding)"
+            )
+        if cfg.n_heads % 2 or cfg.kv_heads % 2:
+            raise ValueError(
+                "differential attention pairs neighbouring heads: n_heads "
+                f"{cfg.n_heads} and n_kv_heads {cfg.kv_heads} must be even"
+            )
+        if not cfg.scan_layers:
+            raise ValueError(
+                "the layout mb_per_layer gives always runs as two layer "
+                "scans with its cache stacked a layer (models/sambay.py): "
+                "say so with scan_layers=True, which is what "
+                "serve.slots.write_slot reads"
+            )
+        if _kv_quant_mode(cfg.kv_cache_dtype):
+            raise ValueError(
+                "a model with recurrent state keeps its rings and its "
+                "shared cache as floats: kv_cache_dtype "
+                f"{cfg.kv_cache_dtype!r} is not supported"
+            )
+        for field, what in (
+            ("kv_pages", "a paged KV cache (a page holds heads of K and V "
+                         "at absolute positions: neither a state nor a ring)"),
+            ("tp_mesh", "tensor-parallel serving (the slot rules shard K "
+                        "and V by head and know no state leaf)"),
+            ("lora_adapters", "LoRA adapters"),
+            ("attention_fn", "a custom attention_fn"),
+            ("moe_experts", "the capacity-dropping MoEFFN"),
+            ("n_routed_experts", "the dropless routed experts"),
+            ("kv_lora_rank", "latent attention"),
+            ("remat", "remat (training through the selective scan)"),
+        ):
+            if getattr(cfg, field) not in (None, 0, False):
+                raise ValueError(
+                    f"layers with recurrent state (mb_per_layer) do not "
+                    f"run with {what} ({field})"
+                )
     if cfg.latent:
         if not (cfg.q_lora_rank and cfg.qk_nope_head_dim
                 and cfg.qk_rope_head_dim and cfg.v_head_dim):
@@ -1571,6 +1648,20 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 f"sequence length {tokens.shape[1]} exceeds "
                 f"max_seq_len {cfg.max_seq_len}"
+            )
+        if cfg.mb_per_layer:
+            # Mamba, window, full, Gated Memory Unit and cross-attention
+            # layers by their place: a model of its own under this name
+            from pytorch_distributed_training_tutorials_tpu.models import (
+                sambay,
+            )
+
+            if adapter_ids is not None:
+                raise ValueError(
+                    "layers with recurrent state run with no LoRA adapters"
+                )
+            return sambay.forward(
+                self, tokens, decode, prefill, return_hidden, last_pos
             )
         if adapter_ids is not None and not cfg.lora_adapters:
             raise ValueError(
@@ -1814,6 +1905,10 @@ _QUANTIZED_KERNELS = frozenset(
         "gate_proj", "up_proj", "down_proj", "lm_head",
         # latent attention (2-D kernels, the first axis contracted)
         "q_down", "q_up", "kv_down", "kv_up",
+        # models/sambay.py: the fused projections, the Mamba mixer's and
+        # the Gated Memory Unit's (all 2-D)
+        "qkv_proj", "gate_up_proj", "in_proj", "x_proj", "dt_proj",
+        "out_proj",
     }
 )
 # the stacks of a dropless expert layer ("moe": bare (experts, in, out)
@@ -1822,7 +1917,7 @@ _QUANTIZED_KERNELS = frozenset(
 _QUANTIZED_EXPERT_STACKS = frozenset({"w_gate", "w_up", "w_down"})
 
 
-def quantize_lm_params(params):
+def quantize_lm_params(params, embedding_dtype=None):
     """Convert trained f32 :class:`TransformerLM` params into the
     ``quantized=True`` serving layout: every matmul ``kernel`` becomes
     ``{'q': int8, 'scale': f32 per-column}`` (DenseGeneral kernels
@@ -1837,6 +1932,12 @@ def quantize_lm_params(params):
     quantized per layer, so every layer gets its own scales;
     ``quantize(stack(f32)) == stack(quantize(f32))`` exactly, pinned by
     ``tests/test_int8_serving.py``).
+
+    A model without an ``lm_head`` has tied embeddings (models/sambay.py):
+    its head becomes the embedding's transpose in int8 with one scale a
+    vocabulary row, and its embedding those values dequantized, cast to
+    ``embedding_dtype`` (the model's compute dtype, which is what the
+    quantized model keeps its lookup table in; None: float32).
     """
     from pytorch_distributed_training_tutorials_tpu.ops.quant import quantize_int8
 
@@ -1859,8 +1960,11 @@ def quantize_lm_params(params):
             elif isinstance(sub, Mapping):
                 # under the nn.scan stack ("layers"), kernels carry a
                 # leading (n_layers,) axis that must not be mistaken for
-                # the contraction dim
-                out[name] = walk(sub, stacked=stacked or name == "layers")
+                # the contraction dim ("layers_a", "layers_b": the two
+                # scans of models/sambay.py)
+                out[name] = walk(
+                    sub, stacked=stacked or name.startswith("layers")
+                )
             elif name in _QUANTIZED_EXPERT_STACKS and "router" in tree:
                 # (..., experts, in, out): the reduction is over "in" alone,
                 # so the layer axis of a stack needs no loop
@@ -1870,7 +1974,17 @@ def quantize_lm_params(params):
                 out[name] = sub
         return out
 
-    return walk(dict(params))
+    out = walk(dict(params))
+    if "lm_head" not in out and "tok_emb" in out:
+        # tied embeddings: the head is the embedding's transpose, one scale
+        # a vocabulary row, and the embedding those int8 values dequantized
+        qp = quantize_int8(jnp.asarray(out["tok_emb"]["embedding"]).T)
+        out["lm_head"] = {"q": qp.q, "scale": qp.scale.reshape(1, -1)}
+        table = (qp.q.astype(jnp.float32) * out["lm_head"]["scale"]).T
+        out["tok_emb"] = {
+            "embedding": table.astype(embedding_dtype or jnp.float32)
+        }
+    return out
 
 
 def stack_quantized_lm_params(params):

@@ -93,6 +93,7 @@ def decode_attention(
     pos: jax.Array,
     *,
     block_w: int | None = None,
+    heads_major: bool = False,
     interpret: bool | None = None,
 ) -> jax.Array:
     """``softmax(q k^T / sqrt(D)) v`` a slot, over the slot's rows
@@ -106,11 +107,22 @@ def decode_attention(
     with nothing in it: no row of it is read and its result is zeros.
     Returns (B, H, D) at ``q``'s dtype. ``block_w`` (:func:`decode_block`'s
     by default) must divide ``W``.
+
+    ``heads_major``: the stacks are (L, B, KV, W, D), a KV head's rows
+    together. That is how a cache whose KV heads are no whole sublane tile
+    has to lie (10 heads: the chip's tiled memory pads a (10, D) slab to
+    16 rows, and the compiler then moves the position axis inward itself,
+    copying the whole stack into and out of every program: seen in the
+    program compiled for a v5e, PR 34). A block is ``(KV, rows, D)`` and
+    the matrix row ``c * rows + t``; everything else is the same kernel.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
-    n_layers, bc, w, kv, dc = k_stack.shape
+    if heads_major:
+        n_layers, bc, kv, w, dc = k_stack.shape
+    else:
+        n_layers, bc, w, kv, dc = k_stack.shape
     assert v_stack.shape == k_stack.shape and (bc, dc) == (b, d), (
         q.shape, k_stack.shape, v_stack.shape
     )
@@ -120,7 +132,9 @@ def decode_attention(
         block_w = decode_block(w, kv, d, k_stack.dtype)
     assert block_w and w % block_w == 0 and d % 128 == 0, (w, block_w, d)
     n_blocks = w // block_w
-    n = block_w * kv  # a block as a matrix: row t * kv + c is KV head c at t
+    # a block as a matrix: row t * kv + c is KV head c at t (c * block_w + t
+    # where the heads come first)
+    n = block_w * kv
     # the CPU backend has no bf16 x bf16 -> f32 product of this form: the
     # interpreter computes in float32 what the chip computes from bfloat16
     compute = jnp.float32 if interpret else k_stack.dtype
@@ -150,9 +164,11 @@ def decode_attention(
             ) * sm_scale  # (H, n)
             col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
             head = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
-            own = jnp.logical_and(
-                col % kv == head // grp, j * block_w + col // kv <= depth
+            c, t = (
+                (col // block_w, col % block_w) if heads_major
+                else (col % kv, col // kv)
             )
+            own = jnp.logical_and(c == head // grp, j * block_w + t <= depth)
             scores = jnp.where(own, scores, NEG_INF)
             m_prev = m[:, :1]
             # a block that runs holds t = j * block_w <= depth for every head
@@ -178,10 +194,15 @@ def decode_attention(
         # index it already holds
         last = hi_ref[bb]
         blk = jnp.where(pos_ref[bb] < w, jnp.minimum(j, last), last)
+        if heads_major:
+            return (layer_ref[0], src_ref[bb], 0, blk, 0)
         return (layer_ref[0], src_ref[bb], blk, 0, 0)
 
     q_spec = pl.BlockSpec((1, h, d), lambda bb, j, *_: (bb, 0, 0))
-    rows_spec = pl.BlockSpec((1, 1, block_w, kv, d), rows_map)
+    rows_spec = pl.BlockSpec(
+        (1, 1, kv, block_w, d) if heads_major else (1, 1, block_w, kv, d),
+        rows_map,
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, n_blocks),

@@ -177,6 +177,7 @@ from pytorch_distributed_training_tutorials_tpu.serve.slots import (
     bucket_len,
     extract_segment,
     init_slot_state,
+    slot_bytes,
     seed_cache,
     tree_nbytes,
     tree_nbytes_sharded,
@@ -483,8 +484,8 @@ class ServeEngine:
         if getattr(model.cfg, "latent", False):
             # a latent cache (one [c | k_rope] row a token, shared by all
             # heads) is known to the whole-slot engine alone so far
-            refused = [
-                what for what, on in (
+            _whole_slots_only(
+                "a model with latent attention (kv_lora_rank > 0)", (
                     ("paged=True (the page pool holds heads of K and V)",
                      paged),
                     ("prefix_cache_bytes (the prefix cache's segments)",
@@ -494,14 +495,34 @@ class ServeEngine:
                     ("speculative_k", speculative_k > 0),
                     ("kv_bits (a quantized cache)", kv_bits is not None),
                     ("an adapter bank", self._adapters),
-                ) if on
-            ]
-            if refused:
-                raise ValueError(
-                    "a model with latent attention (kv_lora_rank > 0) is "
-                    "served from whole slots only; this engine was asked "
-                    "for " + "; ".join(refused)
-                )
+                ))
+        if getattr(model.cfg, "mb_per_layer", 0):
+            # recurrent state and rings of K and V beside the one shared
+            # cache (models/sambay.py): a slot holds them whole, and nothing
+            # that cuts a sequence's cache by position, rewinds it or moves
+            # it knows yet what a state or a ring is
+            _whole_slots_only(
+                "a model with recurrent state (mb_per_layer > 0)", (
+                    ("paged=True (a page holds heads of K and V at "
+                     "absolute positions: neither a state nor a ring)",
+                     paged),
+                    ("prefix_cache_bytes (a prefix segment is rows of K "
+                     "and V: it holds no state at its end)",
+                     prefix_cache_bytes > 0),
+                    ("prefill_chunk (a chunk continues a cache by "
+                     "position: the state would have to continue too)",
+                     prefill_chunk > 0),
+                    ("speculative_k (a rejected draft rewinds a depth; a "
+                     "state cannot be rewound)", speculative_k > 0),
+                    ("kv_bits (a quantized cache)", kv_bits is not None),
+                    ("a tensor-parallel strategy (the slot rules shard K "
+                     "and V by head and know no state leaf)", self._shard),
+                    ("an adapter bank", self._adapters),
+                    ("priority_classes (preemption swaps a slot's K and V "
+                     "out by position)", priority_classes > 0),
+                    ("role (a handoff ships a segment of K and V by "
+                     "position)", role is not None),
+                ))
         self._kv_bits = {None: 0, "int8": 8, "int4": 4}[
             _kv_quant_mode(model.cfg.kv_cache_dtype)
         ]
@@ -3618,9 +3639,20 @@ class ServeEngine:
             "swapped_now": len(self._swapped),
         }
 
+    def slot_stats(self) -> dict[str, int | float]:
+        """Bytes ONE slot holds of the cache tree, by what the leaf is
+        (:func:`.slots.slot_bytes`: from shapes, no device fetch):
+        ``slot_kv_bytes`` (K and V over the whole window: the heads of an
+        attention layer, a latent, the one shared cache of a model with
+        recurrent state), ``slot_ring_bytes`` (rings of the newest rows of
+        window layers) and ``slot_state_bytes`` (recurrent state and the
+        convolutions' tails). A paged pool is no slot's: its pages count
+        under ``pages``."""
+        return slot_bytes(self._state["cache"], self.n_slots)
+
     _STATS_PARTS = (
         "prefix", "spec", "adapters", "fault", "flight", "pipeline",
-        "pages", "tp", "role", "sentry", "slo",
+        "pages", "tp", "role", "sentry", "slo", "slot",
     )
 
     def stats(self, *parts: str) -> dict[str, int | float]:
@@ -3650,12 +3682,24 @@ class ServeEngine:
             "role": self.role_stats,
             "sentry": self.sentry_stats,
             "slo": self.slo_stats,
+            "slot": self.slot_stats,
         }
         out: dict[str, int | float] = {}
         for part in self._STATS_PARTS:
             if part in chosen:
                 out.update(fns[part]())
         return out
+
+
+def _whole_slots_only(model: str, asked) -> None:
+    """Refuse, in words, every ``(what, on)`` of ``asked`` that is on: the
+    cache of ``model`` is known to the whole-slot path alone so far."""
+    refused = [what for what, on in asked if on]
+    if refused:
+        raise ValueError(
+            f"{model} is served from whole slots only; this engine was "
+            "asked for " + "; ".join(refused)
+        )
 
 
 def _seed_history(state, tokens, p_len, slot, first):

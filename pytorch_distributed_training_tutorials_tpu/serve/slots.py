@@ -48,7 +48,11 @@ def bucket_len(p_len: int, window: int, floor: int = 8) -> int:
     attention makes positions ``[0, p_len)`` independent of the padding
     tail, and the next-token logits are gathered at ``p_len - 1``
     (``TransformerLM.__call__(last_pos=...)``), so bucketing changes
-    compile-cache hit rate, never results."""
+    compile-cache hit rate, never results. A recurrent state is not
+    independent of what follows it: a model that keeps one stops it at
+    ``p_len`` itself (``models/sambay.py``: past the prompt ``delta = 0``,
+    the identity on the state, and its rings take the rows before
+    ``p_len``), which the same ``last_pos`` tells it."""
     if p_len < 1:
         raise ValueError("p_len must be >= 1")
     b = floor
@@ -361,6 +365,29 @@ def tree_nbytes_sharded(tree) -> int:
         )
         total += math.prod(shape) * jnp.dtype(leaf.dtype).itemsize
     return total
+
+
+# what a whole-slot cache leaf is, by its name (models/transformer.py and
+# models/sambay.py declare them): K and V over the whole window, a ring of
+# the newest rows, recurrent state. Counters and page pools are no slot's.
+_SLOT_LEAF_KINDS = {
+    "cached_key": "kv", "cached_value": "kv", "cached_key_scale": "kv",
+    "cached_value_scale": "kv", "cached_latent": "kv",
+    "shared_key": "kv", "shared_value": "kv",
+    "window_key": "ring", "window_value": "ring",
+    "ssm_state": "state", "conv_state": "state",
+}
+
+
+def slot_bytes(cache, n_slots: int) -> dict[str, int]:
+    """``{"slot_kv_bytes", "slot_ring_bytes", "slot_state_bytes"}``: the
+    bytes one of ``n_slots`` slots holds of ``cache``, from shapes alone."""
+    out = {"slot_kv_bytes": 0, "slot_ring_bytes": 0, "slot_state_bytes": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        kind = _SLOT_LEAF_KINDS.get(_leaf_name(path))
+        if kind:
+            out[f"slot_{kind}_bytes"] += tree_nbytes(leaf) // n_slots
+    return out
 
 
 def _leaf_name(path) -> str:

@@ -45,6 +45,9 @@ from pytorch_distributed_training_tutorials_tpu.ops.decode_attention import (
 from pytorch_distributed_training_tutorials_tpu.ops.latent_attention import (
     latent_decode_attention,
 )
+from pytorch_distributed_training_tutorials_tpu.ops.selective_scan import (
+    selective_scan,
+)
 from pytorch_distributed_training_tutorials_tpu.ops.quant import (
     Int8Param,
     grouped_int8_matmul,
@@ -599,6 +602,138 @@ def test_int8_programs_copy_no_weight(
                for c in stacked), stacked
     assert any(", s8[256,1152]{1,0}, f32[1,1152]" in c for c in calls)
     assert _s8_made(hlo) == []
+
+
+@pytest.mark.parametrize("positions", [2048, 600], ids=["bucket", "ragged"])
+def test_selective_scan_compiles(one_chip, positions):
+    """One Mamba layer's scan at Phi-4-mini-flash-reasoning's sizes (5,120
+    channels, 16 states): a prompt bucket of 2,048, and ``generate()``'s
+    600 positions, padded to whole blocks with ``delta = 0``."""
+    hlo = _compile(
+        lambda u, d, a, b, c: selective_scan(u, d, a, b, c, interpret=False),
+        one_chip,
+        _sds((1, positions, 5120), jnp.float32),
+        _sds((1, positions, 5120), jnp.float32), _sds((16, 5120), jnp.float32),
+        _sds((1, positions, 16), jnp.float32),
+        _sds((1, positions, 16), jnp.float32),
+    )
+    assert re.search(r"%selective_scan[\w.]* = ", hlo)
+
+
+@pytest.mark.parametrize("program", ["_chain_fn", "_prefill_fn"])
+def test_recurrent_state_programs_copy_no_cache(one_chip, monkeypatch, program):
+    """``ServeEngine``'s chain and its 2,048 prefill for the layout
+    ``mb_per_layer`` gives, at Phi-4-mini-flash-reasoning's published widths
+    (32 layers, 64 slots x 4,096 positions, int8): nine Mamba states, eight
+    rings of 512 rows and the one shared cache ride the two layer scans as
+    carries, ``decode_attention`` reads the rings and the shared cache
+    where they lie (a KV pair's rows together: with the ten pairs innermost
+    the compiler relaid both stacks whole, twice a launch, 5.3 GB of
+    temporaries), every scanned product reads the stacked int8 weights at
+    its layer index, and no stack of the cache, no int8 weight and no
+    embedding table is copied (ISSUE 34). Shapes only."""
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+    from pytorch_distributed_training_tutorials_tpu.serve import (
+        engine as engine_module,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve.slots import (
+        init_slot_state,
+        tree_nbytes,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        engine_module, "init_slot_state",
+        lambda model, params, *a, **kw: jax.eval_shape(
+            lambda p: init_slot_state(model, p, *a, **kw), params
+        ),
+    )
+    model = TransformerLM(TransformerConfig(
+        vocab_size=200064, d_model=2560, n_layers=32, n_heads=40,
+        n_kv_heads=20, d_ff=10240, max_seq_len=4096, norm_eps=1e-5,
+        mb_per_layer=2, sliding_window=512, tie_embeddings=True,
+        scan_layers=True, quantized=True, dtype=jnp.bfloat16,
+        kv_cache_dtype=jnp.bfloat16,
+    ))
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    engine = ServeEngine(model, params, n_slots=64, tokens_per_launch=8)
+    cache = engine._state["cache"]
+    assert engine.stats("slot") == {
+        "slot_kv_bytes": 4096 * 5120, "slot_ring_bytes": 8 * 512 * 5120,
+        "slot_state_bytes": 9 * 5120 * (16 + 3) * 4,
+    }
+    args = (params, engine._state)
+    if program == "_prefill_fn":
+        i32 = _sds((), jnp.int32)
+        args += (_sds((1, 2048), jnp.int32), i32, i32, i32, i32)
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        args,
+    )
+    compiled = (
+        jax.jit(getattr(engine, program), donate_argnums=(1,))
+        .lower(*placed).compile()
+    )
+    hlo = compiled.as_text()
+    analysis = compiled.memory_analysis()
+    # the slot tree is donated into the result, and nothing the size of the
+    # shared cache's K (0.34 GB) is made beside it: the chain's temporaries
+    # are 8 MB; the prefill's are a bucket's activations
+    assert analysis.alias_size_in_bytes >= tree_nbytes(cache)
+    shared_k = tree_nbytes(cache["shared_key"])
+    limit = (1 << 24) if program == "_chain_fn" else 2 * shared_k
+    assert analysis.temp_size_in_bytes < limit, analysis.temp_size_in_bytes
+    # no layer's int8 weight is made: what is left are the 160 rows of
+    # ``dt_proj`` (no whole K tile: sliced and padded, 1.3 MB), ``x_proj``'s
+    # stack of 192 columns (no whole lane tiles: 7.9 MB relaid once a
+    # prefill) and what the compiler itself moves into fast memory ahead of
+    # its use (``S(1)``)
+    made = [
+        (op, dims) for dims, layout, op in re.findall(
+            r"= s8\[([\d,]+)\](\S*) ([\w\-]+)\(", hlo)
+        if op not in ("parameter", "get-tuple-element", "bitcast", "copy-done")
+        and "S(1)" not in layout
+        and math.prod(int(n) for n in dims.split(",")) >= 1 << 23
+    ]
+    assert made == [], made
+    # every bfloat16 result the size of a stack IS a stack, updated in place
+    big = {
+        (op, dims) for dims, op in re.findall(
+            r"= bf16\[([\d,]+)\]\S* ([\w\-]+)\(", hlo)
+        if math.prod(int(n) for n in dims.split(",")) * 2 >= shared_k
+        and op not in ("parameter", "get-tuple-element", "bitcast", "while",
+                       "tuple")
+    }
+    stacks = {"1,64,10,4096,128", "64,10,4096,128", "8,64,10,512,128"}
+    if program == "_prefill_fn":  # a bucket's fused gate|up, in bfloat16
+        stacks |= {"1,2048,20480", "2048,20480"}
+    assert big and all(
+        dims in stacks and op not in ("copy", "dynamic-slice", "transpose")
+        for op, dims in big
+    ), big
+    assert "200064,2560" not in "".join(
+        line for line in hlo.splitlines()
+        if " convert(" in line or " copy(" in line)
+    if program == "_prefill_fn":
+        # a Mamba layer's scan is one kernel: a period's (the compiler may
+        # peel the layer scan's first turn) and layer 16's
+        assert len(set(re.findall(r"%(selective_scan[\w.]*) = ", hlo))) >= 2
+    if program == "_chain_fn":
+        # the rings, layer 17 and the cross layers: three call sites
+        assert len(set(re.findall(
+            r"%(decode_attention[\w.]*) = bf16\[64,40,128\]", hlo))) == 3
+    stacked = re.findall(
+        r"%int8_matmul[\w.]* = f32\[[\d,]+\]\S* custom-call\(.*?"
+        r"operand_layout_constraints=\{(s32\[1\]\{0\}, .*?)\}, frontend", hlo)
+    # 6 + 4 products a period of layers_a, 4 + 4 of layers_b, less dt_proj
+    assert len(stacked) == 17, len(stacked)
 
 
 def test_float_tp_serve_chain_compiles_on_a_mesh(topo, monkeypatch):

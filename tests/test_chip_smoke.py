@@ -31,7 +31,7 @@ def test_rehearsal_runs_every_phase_and_names_the_cpu():
     phases = [l["phase"] for l in lines[:-1]]
     assert phases == [
         "device", "train", "serve", "serve_paged_gather",
-        "serve_paged_kernel", "total",
+        "serve_paged_kernel", "serve_state", "total",
     ]
     assert all(l["ok"] is True for l in lines), lines
     assert lines[-1] == {
@@ -45,6 +45,10 @@ def test_rehearsal_runs_every_phase_and_names_the_cpu():
     assert by["serve"]["n_prefill_errors"] == 0
     assert by["serve"]["builds_after_warmup"] == 0
     assert by["serve_paged_kernel"]["options"]["paged_kernel"] is True
+    state = by["serve_state"]  # a prompt longer than the ring, with state
+    assert state["prompt_len"] > state["model"]["ring"]
+    assert state["generate_greedy_gap_rel"] <= 0.1
+    assert state["slot_state_bytes"] > 0 and state["slot_ring_bytes"] > 0
 
 
 def test_four_chip_rehearsal_runs_only_the_multichip_phase():

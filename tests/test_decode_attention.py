@@ -71,6 +71,27 @@ def test_kernel_matches_plain_attention(h, kv, dtype, layer):
     np.testing.assert_array_equal(np.asarray(again), np.asarray(out))
 
 
+@pytest.mark.parametrize("h, kv", [(4, 2), (40, 10)], ids=["2to1", "40on10"])
+def test_heads_major_stack_gives_the_same_result(h, kv):
+    """A stack (L, B, KV, W, D), a KV head's rows together (ISSUE 34: ten
+    KV pairs are no whole sublane tile), read by the same kernel: what it
+    gives for the same numbers with the positions first."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    pos = jnp.array([3, BLOCK, W, W - 1], jnp.int32)
+    b = pos.shape[0]
+    q = jax.random.normal(keys[0], (b, h, D), jnp.float32)
+    k = jax.random.normal(keys[1], (2, b, W, kv, D), jnp.float32)
+    v = jax.random.normal(keys[2], (2, b, W, kv, D), jnp.float32)
+    want = decode_attention(q, k, v, jnp.int32(1), pos, block_w=BLOCK)
+    got = decode_attention(
+        q, jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3), jnp.int32(1), pos,
+        block_w=BLOCK, heads_major=True,
+    )
+    # the same scores and weights; a block's rows are summed in another order
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+    assert not np.asarray(got[2]).any()  # the dead slot
+
+
 def test_dead_slots_ask_for_blocks_already_held():
     """A dead slot's index map names the block the slot before it ended on
     (the first live slot's first block where none is before it), so the

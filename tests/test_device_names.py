@@ -40,6 +40,7 @@ KERNELS = {
     "grouped_int8_matmul": "quant.py",
     "latent_decode_attention": "latent_attention.py",
     "decode_attention": "decode_attention.py",
+    "selective_scan": "selective_scan.py",
 }
 
 
@@ -237,3 +238,56 @@ def test_int8_matmul_keeps_its_name_in_both_forms(which, scan_layers):
     layer = "layers/block" if scan_layers else "block_1"
     assert f'{layer}/mlp/up_proj/int8_matmul/pallas_call"' in text
     assert 'lm_head/int8_matmul/pallas_call"' in text
+
+
+@pytest.fixture(scope="module")
+def state_engine():
+    """Mamba, window, full, Gated Memory Unit and cross-attention layers by
+    their place, int8, two layer scans: what the benchmark's cell runs
+    (ISSUE 34)."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=128, n_layers=8, n_heads=4, n_kv_heads=2,
+        d_ff=128, max_seq_len=32, mb_per_layer=2, sliding_window=8,
+        tie_embeddings=True, scan_layers=True, quantized=True,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    return ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+
+
+@pytest.mark.parametrize("which", ["chain", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "ssm_conv", "ssm_scan", "gmu", "window_attn", "shared_kv_attn",
+    "diff_combine", "kv_cache", "layer_scan", "layers", "mlp", "lm_head",
+])
+def test_recurrent_state_scopes_are_in_the_lowered_text(
+        state_engine, which, scope):
+    """What ``ssm_share.serve``, ``shared_kv_attention_share.serve``,
+    ``window_attention_share.serve`` and ``gmu_share.serve`` read, and the
+    cell ``layers`` inside ``layer_scan`` that ``layer_scan_share.serve``
+    leaves out (``benchmark/layer_metrics``)."""
+    text = _lowered(which, state_engine, None).as_text(debug_info=True)
+    assert re.search(rf'[/"(]{scope}\)*/', text), scope
+
+
+@pytest.mark.parametrize("which,path", [
+    # a step's new row into a ring and into the shared cache, its state
+    ("chain", "layers_a/layers/window_block/attn/kv_cache/scatter"),
+    ("chain", "block_5/attn/kv_cache/scatter"),
+    ("chain", "layers_a/layers/kv_cache/scatter"),
+    # a prompt's ring, its K and V, its state
+    ("prefill", "layers_a/layers/window_block/attn/kv_cache/scatter"),
+    ("prefill", "block_5/attn/kv_cache/dynamic_update_slice"),
+    ("prefill", "layers_a/layers/kv_cache/scatter"),
+    # the products of both scans read the stacked weights by name
+    ("chain", "layers_a/layers/mamba_block/mixer/in_proj/int8_matmul/pallas_call"),
+    ("chain", "layers_b/layers/gmu_block/mixer/gmu/in_proj/int8_matmul/pallas_call"),
+    ("chain", "layers_b/layers/cross_block/attn/shared_kv_attn/"),
+])
+def test_recurrent_state_writes_keep_their_paths(state_engine, which, path):
+    """The ring's, the shared cache's and the state's writes lie under
+    ``kv_cache`` at their layer's path (a scan's body names its own from
+    the scanned module down: ``layers_a/layers/...``)."""
+    text = _lowered(which, state_engine, None).as_text(debug_info=True)
+    assert path in text, path
