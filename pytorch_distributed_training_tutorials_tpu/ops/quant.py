@@ -175,6 +175,33 @@ def _int8_matmul_kernel(*refs, n_k: int):
         out_ref[:] = acc_ref[:] * sw_ref[:]
 
 
+# what a grid step of int8_matmul should move, and what its blocks may take
+# of the 16 MB of fast memory the chip's compiler gives a kernel (the rest
+# is for the kernel body's own int32 and float32 temporaries)
+_STEP_BYTES = 1 << 20
+_VMEM_BYTES = 12 << 20
+
+
+def _n_block(block_m: int, block_k: int) -> int:
+    """The N block :func:`int8_matmul` takes where the caller names none
+    (the call clamps it to N): the columns that make a weight tile of
+    ``_STEP_BYTES`` at this K tile (2048 at 512 rows), halved while the
+    call's blocks (x, weight, scale and result tiles double-buffered, the
+    float32 accumulator) exceed ``_VMEM_BYTES``. A grid step costs about
+    0.35 us whatever it moves: at 128 KB a step the kernel streams int8
+    weights at 260-285 GB/s, at 1 MB at 615-705 (one v5e chip, PERF.md
+    section 6, PR 33 and PR 35)."""
+
+    def blocks_bytes(bn):
+        tiles = 4 * block_m * block_k + block_k * bn + 4 * bn + 4 * block_m * bn
+        return 2 * tiles + 4 * block_m * bn
+
+    block_n = max(128, _STEP_BYTES // block_k // 128 * 128)
+    while block_n > 128 and blocks_bytes(block_n) > _VMEM_BYTES:
+        block_n //= 2
+    return block_n
+
+
 def int8_matmul(
     x: jax.Array,
     w: Int8Param,
@@ -215,11 +242,8 @@ def int8_matmul(
 
     ``block_n`` is no part of the arithmetic (a column's sum runs over the
     same K tiles in the same order whatever columns share its block).
-    Unset it is 256 for a (K, N) weight and 2048 in a stack: there the
-    kernel is the only reader of the bytes (XLA staged the scan's slice in
-    fast memory, at 717 GB/s, for a kernel that then read it from there),
-    and at 128 KB a grid step it streams the stack at 264-284 GB/s, at
-    1 MB at 615-705 (one v5e chip, PERF.md section 6, PR 33).
+    Unset, :func:`_n_block` works it out from the M and K tiles, the same
+    for a (K, N) weight and for a stack, and N in whole lane tiles caps it.
     ``interpret=None`` auto-selects interpreter mode off-TPU so the same
     code path tests on CPU.
     """
@@ -250,7 +274,7 @@ def int8_matmul(
     # odd-vocab lm_head must not hand the real-TPU kernel a sub-lane tile;
     # the last block hangs over N)
     if block_n is None:
-        block_n = 256 if layer is None else 2048
+        block_n = _n_block(block_m, block_k)
     block_n = min(block_n, max(128, n))
     block_n = -(-block_n // 128) * 128
     pad_m = (-m) % block_m

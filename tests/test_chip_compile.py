@@ -156,16 +156,28 @@ def test_fused_adamw_compiles(one_chip):
         (8, D_MODEL, VOCAB),
         (32, D_MODEL, 92544),  # 723 x 128: no block of 256 divides it
         (8, D_MODEL, 1000),  # no multiple of 128 either
+        # openPangu-Ultra-MoE's unrolled layers at 64 slots, and a prefill
+        (64, 16384, 7680),
+        (64, 7680, 18432),
+        (64, 18432, 7680),
+        (64, 7680, 576),  # one block of 640
+        (64, 7680, 19200),
+        (2048, 7680, 18432),
+        (64, 2560, 200064),
+        (8, 4096, 32000),
     ],
     ids=["decode_up", "prefill_up", "decode_down", "decode_head",
-         "chat_head", "ragged_head"],
+         "chat_head", "ragged_head", "pangu_o", "pangu_up", "pangu_down",
+         "pangu_kv_a", "pangu_head", "pangu_prefill_up", "phi_head",
+         "long_head"],
 )
 def test_int8_matmul_compiles(one_chip, m, k, n):
     """A (k, n) weight: three operands, x first (the call of every
     unrolled layer and of the head). A ragged n is no reason for a copy:
     the weight reaches the kernel as the argument it is (before ISSUE 33
     the chat cell's 190 MB head was padded to 92,672 columns on every
-    chain)."""
+    chain). The N block is the call's own (ISSUE 35: 1 MB of int8 a grid
+    step): one too large for fast memory at a cell's shape fails here."""
     x = _sds((m, k), jnp.bfloat16)
     w = Int8Param(q=_sds((k, n), jnp.int8), scale=_sds((1, n), jnp.float32))
     hlo = _compile(

@@ -316,3 +316,97 @@ def test_int8_matmul_ragged_n_makes_no_copy_of_the_weight(n):
         scale=jnp.pad(w.scale, ((0, 0), (0, pad)), constant_values=1.0),
     )
     np.testing.assert_array_equal(got, np.asarray(fn(x, padded))[:, :n])
+
+
+def _result_block(fn, *args):
+    """``(grid, result block)`` of the one ``pallas_call`` in ``fn``'s
+    jaxpr: the result's block is ``(block_m, block_n)`` as the call took
+    them, whatever the form of the weight."""
+    (call,) = [
+        e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+        if e.primitive.name == "pallas_call"
+    ]
+    mapping = call.params["grid_mapping"]
+    block = tuple(d.block_size for d in mapping.block_mappings[-1].block_shape)
+    return tuple(mapping.grid), block
+
+
+# n: 1000 is no multiple of 128; 2944 = 23 x 128 is the chat head's 92,544 =
+# 723 x 128 scaled down (no block of 256 or of 2,048 divides it, and the
+# rule's block leaves a ragged second one); k of one K tile and of three
+@pytest.mark.parametrize("k", [128, 1536])
+@pytest.mark.parametrize("n", [1000, 2944])
+@pytest.mark.parametrize("m", [1, 8, 64, 300])
+def test_int8_matmul_n_block_is_no_arithmetic(m, n, k):
+    """A column's sum runs over the same K tiles in the same order
+    whatever columns share its block: the result on a (k, n) weight is the
+    same to the last bit at N blocks of 128, 256, the rule's own and n
+    itself."""
+    x = jnp.asarray(_w((m, k), seed=30))
+    w = quantize_int8(_w((k, n), seed=31))
+    ruled = np.asarray(int8_matmul(x, w))
+    np.testing.assert_allclose(
+        ruled, np.asarray(int8_matmul_reference(x, w)), rtol=2e-5, atol=1e-4
+    )
+    for block_n in (128, 256, n):
+        np.testing.assert_array_equal(
+            ruled, np.asarray(int8_matmul(x, w, block_n=block_n))
+        )
+
+
+# (m, k, n) -> (block_m, block_n)
+N_BLOCKS = [
+    # the serving cells' (k, n) calls: openPangu's decode products and head
+    # (kv_a is one block of 640), Phi's, the chat cell's and the long cell's
+    # heads
+    ((64, 7680, 19200), (64, 2048)),
+    ((64, 7680, 18432), (64, 2048)),
+    ((64, 18432, 7680), (64, 2048)),
+    ((64, 16384, 7680), (64, 2048)),
+    ((64, 7680, 1536), (64, 1536)),
+    ((64, 1536, 24576), (64, 2048)),
+    ((64, 7680, 576), (64, 640)),
+    ((64, 7680, 2048), (64, 2048)),
+    ((64, 2560, 200064), (64, 2048)),
+    ((32, 2048, 92544), (32, 2048)),
+    ((8, 4096, 32000), (8, 2048)),
+    # prefill buckets, and the stacked cells' up projections as PR 33 set them
+    ((2048, 7680, 18432), (256, 2048)),
+    ((1024, 512, 32768), (256, 2048)),
+    ((32, 2048, 8192), (32, 2048)),
+    ((4096, 4096, 14336), (256, 2048)),
+    # a K tile under 512 rows takes more columns for its megabyte, as far as
+    # the M tile leaves room in fast memory
+    ((64, 256, 5120), (64, 4096)),
+    ((2048, 256, 5120), (256, 2048)),
+    ((8, 128, 16384), (8, 8192)),
+    ((300, 128, 16384), (256, 2048)),
+    # a narrow n and a toy width clamp to n in whole lane tiles
+    ((8, 512, 200), (8, 256)),
+    ((5, 64, 96), (8, 128)),
+]
+
+
+@pytest.mark.parametrize("shape,blocks", N_BLOCKS, ids=lambda v: str(v))
+def test_int8_matmul_n_block_rule(shape, blocks):
+    """The rule itself, on the shapes the cells call: about 1 MB of int8 a
+    grid step (2,048 columns at the K tile of 512), inside fast memory,
+    clamped to n; and one rule: a (k, n) weight and a stack of them take
+    the same blocks for the same shapes. Shapes alone: nothing runs."""
+    m, k, n = shape
+    x = jax.ShapeDtypeStruct((m, k), jnp.float32)
+    flat = Int8Param(
+        q=jax.ShapeDtypeStruct((k, n), jnp.int8),
+        scale=jax.ShapeDtypeStruct((1, n), jnp.float32),
+    )
+    stack = Int8Param(
+        q=jax.ShapeDtypeStruct((2, k, n), jnp.int8),
+        scale=jax.ShapeDtypeStruct((2, 1, n), jnp.float32),
+    )
+    grid, got = _result_block(lambda x, w: int8_matmul(x, w), x, flat)
+    assert got == blocks
+    assert grid[1] == -(-n // blocks[1])
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    assert _result_block(
+        lambda x, w, l: int8_matmul(x, w, l), x, stack, layer
+    ) == (grid, got)
