@@ -20,6 +20,11 @@ ends the run with a non-zero exit code:
   (``mb_per_layer``: Phi-4-mini-flash-reasoning's widths, 8 of its 32
   layers: Mamba state, a window ring and one shared cache in a slot), its
   prompt longer than the attention window, against ``models.generate``.
+- ``serve_parallel``: three requests through two slots of a model whose
+  every block runs a Mamba-2 mixer beside attention (``mamba_n_heads``:
+  Falcon-H1-34B's widths, 2 of its 72 layers: a state of 4 MB and a KV
+  cache in one layer), so that a slot is refilled; ``ssd_update`` and
+  ``decode_attention`` on the carried stacks, against ``models.generate``.
 
 ``--chips 4`` runs, and runs only, the multi-chip phase: the ``train``
 model on device 0 alone, under ``DataParallel`` on ``create_mesh()`` and
@@ -56,6 +61,11 @@ REAL = dict(
     # between, one period of Gated Memory Unit and cross-attention)
     state=dict(d_model=2560, n_heads=40, n_kv_heads=20, d_ff=10240,
                n_layers=8, window=1024, ring=512, prompt_len=600),
+    # Falcon-H1-34B-Instruct's widths at a toy depth (2 of 72 layers)
+    parallel=dict(d_model=5120, n_heads=20, n_kv_heads=4, d_head=128,
+                  d_ff=21504, n_layers=2, mamba_n_heads=32, mamba_d_head=128,
+                  mamba_n_groups=2, mamba_d_state=256, mamba_chunk_size=128,
+                  window=1024, prompt_lens=[600, 130, 333]),
 )
 TOY = dict(
     vocab=256, d_model=64, n_heads=4, d_ff=128, n_layers=2,
@@ -64,6 +74,10 @@ TOY = dict(
     new_tokens=8, page_size=8, pool_pages=32,
     state=dict(d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, n_layers=8,
                window=64, ring=8, prompt_len=20),
+    parallel=dict(d_model=96, n_heads=4, n_kv_heads=2, d_head=16, d_ff=128,
+                  n_layers=2, mamba_n_heads=4, mamba_d_head=16,
+                  mamba_n_groups=2, mamba_d_state=8, mamba_chunk_size=8,
+                  window=64, prompt_lens=[20, 9, 31]),
 )
 DEPTH_CUT = (
     "one chip's 16 GB cannot hold 16 layers of f32 parameters with two "
@@ -705,6 +719,74 @@ def phase_serve_state(args, w: dict, log: CompileLog) -> None:
     )
 
 
+def phase_serve_parallel(args, w: dict, log: CompileLog) -> None:
+    """Three requests through two slots of a model with a Mamba-2 mixer
+    beside attention in every block (``mamba_n_heads``: a state and a KV
+    cache in one layer, the published multipliers' places taken), int8:
+    ``ServeEngine`` against ``models.generate``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_distributed_training_tutorials_tpu.models import (
+        TransformerConfig, TransformerLM,
+    )
+    from pytorch_distributed_training_tutorials_tpu.models.generate import (
+        generate,
+    )
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        quantize_lm_params,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+
+    pm = dict(w["parallel"])
+    window, lens = pm.pop("window"), pm.pop("prompt_lens")
+    cfg = TransformerConfig(
+        vocab_size=w["vocab"], max_seq_len=window, norm_eps=1e-5,
+        scan_layers=True, dtype=jnp.bfloat16, embedding_multiplier=2.0,
+        key_multiplier=0.5, attention_out_multiplier=0.5,
+        ssm_in_multiplier=0.5, ssm_out_multiplier=0.5,
+        ssm_multipliers=(0.5, 1.0, 0.5, 1.0, 0.5),
+        mlp_multipliers=(0.5, 0.25), **pm,
+    )
+    params = jax.jit(TransformerLM(cfg).init)(
+        jax.random.PRNGKey(args.seed), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    params = jax.jit(quantize_lm_params, donate_argnums=0)(params)
+    lm = TransformerLM(dataclasses.replace(cfg, quantized=True))
+    log.take()
+    engine = ServeEngine(lm, params, n_slots=2, tokens_per_launch=8)
+    prompts = prompts_for(w, lens, args.seed + 4)
+    t0 = time.perf_counter()
+    done = serve_requests(engine, prompts, w["new_tokens"])
+    wall_s = time.perf_counter() - t0
+    judge = GreedyJudge({"window": window}, lm, params)
+    gaps, equal = [], []
+    for prompt, c in zip(prompts, done):
+        ref = np.asarray(generate(
+            lm, params, np.asarray([prompt], np.int32), w["new_tokens"]
+        ))[0, len(prompt):].tolist()
+        gaps.append(judge.gap(prompt, c.tokens, ref))
+        equal.append(c.tokens == ref)
+    failed = []
+    if any(c.finish_reason != "length" for c in done):
+        failed.append(f"finish reasons {[c.finish_reason for c in done]}")
+    if not max(gaps) <= TOL_GREEDY_GAP:
+        failed.append("greedy decode differs from models.generate")
+    finish_phase(
+        {
+            "phase": "serve_parallel", "model": dict(pm, vocab=w["vocab"]),
+            "weights": "int8, made on the device from --seed",
+            "prompt_lens": lens, "new_tokens": w["new_tokens"],
+            "wall_s_with_builds": wall_s, "builds": log.take(),
+            "generate_tokens_equal": equal,
+            "generate_greedy_gap_rel": max(gaps), **engine.stats("slot"),
+            "peak_bytes_in_use": peak_bytes(jax.devices()[0]),
+        },
+        failed,
+    )
+
+
 # -- four chips ------------------------------------------------------------
 
 
@@ -845,6 +927,7 @@ def main() -> None:
         phase_train(args, w, log)
         phase_serve(args, w, log)
         phase_serve_state(args, w, log)
+        phase_serve_parallel(args, w, log)
     emit(phase="total", ok=True, wall_s=time.perf_counter() - t0)
     emit(ok=True, device=device)
 

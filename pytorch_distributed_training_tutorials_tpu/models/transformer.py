@@ -205,10 +205,63 @@ class TransformerConfig:
     # vocabulary row (a head column), and the embedding those values
     # dequantized, so the two stay one matrix (quantize_lm_params)
     tie_embeddings: bool = False
+    # The size of an attention head where the published configuration
+    # states it and it is not d_model // n_heads (Falcon-H1-34B: 20 heads
+    # of 128 under a hidden size of 5,120). None: the quotient.
+    d_head: int | None = None
+    # The type the lookup table is kept in (None: float32, cast to dtype
+    # after the lookup). A served model states its compute type here: the
+    # compiler turns a lookup of float32 rows that are cast afterwards into
+    # a lookup in a cast table, and casts the whole table on every launch.
+    embedding_dtype: "jnp.dtype | None" = None
+    # A Mamba-2 mixer beside attention in every block (models/mamba2.py; the
+    # published keys of Falcon-H1): mamba_n_heads > 0 makes the block's
+    # mixer two branches on ONE normed input, summed:
+    # x + ssm_out * Mamba2(ssm_in * u) + attention_out * Attn(attention_in * u),
+    # then the MLP. mamba_n_heads heads of mamba_d_head channels with a
+    # scalar decay a head, B and C of mamba_d_state states shared by
+    # mamba_n_groups groups of heads, one depthwise convolution of
+    # mamba_d_conv taps over x|B|C, a gated RMSNorm over each group's
+    # channels; prefill runs the chunked form at mamba_chunk_size. A layer
+    # then owns cached_key / cached_value AND ssm_state / conv_state.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 128
+    # The published scalar multipliers (Falcon-H1's maximal-update
+    # parametrization), applied by the model where the equations have them;
+    # 1.0 is the identity and adds no operation. ssm_multipliers scale the
+    # five parts of the Mamba-2 input projection's result (z, x, B, C, dt),
+    # mlp_multipliers the gate's input to silu and the down projection.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple = (1.0, 1.0)
 
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def recurrent(self) -> bool:
+        """The model keeps recurrent state in its cache (a Mamba state and
+        a convolution's tail): the one predicate behind every refusal of
+        what cuts, rewinds or moves a cache by position."""
+        return self.mb_per_layer > 0 or self.mamba_n_heads > 0
+
+    @property
+    def kv_heads_major(self) -> bool:
+        """K and V cached ``(B, KV, W, D)``, a KV head's rows together, and
+        not ``(B, W, KV, D)``: fewer KV heads than a sublane tile pad every
+        position's ``(KV, D)`` slab to one (4 heads of bfloat16: four times
+        the bytes). Only a model served from whole slots takes it: what
+        cuts a cache by position reads axis 1 as positions."""
+        return self.mamba_n_heads > 0 and self.kv_heads % 8 != 0
 
     @property
     def held_experts(self) -> int:
@@ -222,6 +275,8 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.d_head is not None:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -433,7 +488,8 @@ def _update_slice(var, val: jax.Array, start: tuple, layer) -> None:
 
 
 @jax.named_scope("kv_cache")
-def _store_decode_kv(var, val: jax.Array, pos: jax.Array, layer=None) -> None:
+def _store_decode_kv(var, val: jax.Array, pos: jax.Array, layer=None,
+                     heads_major: bool = False) -> None:
     """Write one decode chunk's per-row value ``val`` (B, S, ...) into cache
     variable ``var`` (B, max_seq_len, ...) at sequence positions
     ``pos + [0, S)`` — the one copy of the decode write used by K/V and
@@ -449,10 +505,27 @@ def _store_decode_kv(var, val: jax.Array, pos: jax.Array, layer=None) -> None:
     position ``pos[r] + s``; positions outside the cache window are
     DROPPED, which is what makes parked / finished slots AND bucket
     padding past the window safe — their writes vanish instead of
-    clamping onto (and corrupting) the last cache entry."""
+    clamping onto (and corrupting) the last cache entry.
+
+    ``heads_major``: ``var`` is ``(B, KV, max_seq_len, D)``
+    (``TransformerConfig.kv_heads_major``). One index a (row, KV head,
+    position), so that an update is one lane row of the cache as it lies: a
+    window over the KV heads makes the compiler relay the whole stack
+    around the scatter (models/sambay.py's rings, PR 34)."""
     val = val.astype(var.value.dtype)
     s = val.shape[1]
-    if pos.ndim == 0 and s == 1:
+    if heads_major:
+        b, kv = val.shape[0], val.shape[2]
+        at = (
+            jnp.arange(b)[:, None, None], jnp.arange(kv)[None, :, None],
+            jnp.broadcast_to(pos, (b,))[:, None, None] + jnp.arange(s),
+        )
+        if layer is not None:
+            at = (layer,) + at
+        var.value = var.value.at[at].set(
+            jnp.swapaxes(val, 1, 2), mode="drop"
+        )
+    elif pos.ndim == 0 and s == 1:
         _update_slice(var, val, (0, pos) + (0,) * (val.ndim - 2), layer)
     else:
         rows = jnp.arange(val.shape[0])[:, None]  # (B, 1)
@@ -704,13 +777,14 @@ class Attention(nn.Module):
         k_dtype, v_dtype, d_store, scale_dtype = _kv_storage(
             k_dtype, v_dtype, d
         )
+        shape = (b, cfg.max_seq_len, h, d_store)
+        if cfg.kv_heads_major:  # a KV head's rows together
+            shape = (b, h, cfg.max_seq_len, d_store)
         cached_k = self.variable(
-            "cache", "cached_key",
-            jnp.zeros, (b, cfg.max_seq_len, h, d_store), k_dtype,
+            "cache", "cached_key", jnp.zeros, shape, k_dtype,
         )
         cached_v = self.variable(
-            "cache", "cached_value",
-            jnp.zeros, (b, cfg.max_seq_len, h, d_store), v_dtype,
+            "cache", "cached_value", jnp.zeros, shape, v_dtype,
         )
         idx = self.variable(
             "cache", "cache_index",
@@ -834,6 +908,13 @@ class Attention(nn.Module):
         q_raw = proj("q_proj", h)(x)
         k_raw = proj("k_proj", kv)(x)  # GQA: only kv_heads cached/projected
         v = proj("v_proj", kv)(x)
+        # W (c u) = c (W u): the published scalars on the branch's input
+        # and on its keys multiply the projections' results
+        if cfg.attention_in_multiplier != 1.0:
+            q_raw = q_raw * cfg.attention_in_multiplier
+            v = v * cfg.attention_in_multiplier
+        if cfg.attention_in_multiplier * cfg.key_multiplier != 1.0:
+            k_raw = k_raw * (cfg.attention_in_multiplier * cfg.key_multiplier)
         if cfg.lora_adapters:
             # per-row LoRA deltas on the raw projections (id 0 / any
             # unregistered row adds an exact 0.0 — see LoRADelta)
@@ -942,8 +1023,9 @@ class Attention(nn.Module):
             k = apply_rope(k_raw, cfg.rope_theta, offset=pos)
             k_q, k_s = _encode_kv(k, quant)  # quantized: store q + scale
             v_q, v_s = _encode_kv(v, quant)
-            _store_decode_kv(cached_k, k_q, pos, layer)
-            _store_decode_kv(cached_v, v_q, pos, layer)
+            major = cfg.kv_heads_major
+            _store_decode_kv(cached_k, k_q, pos, layer, major)
+            _store_decode_kv(cached_v, v_q, pos, layer, major)
             if quant:
                 _store_decode_kv(k_scale, k_s, pos, layer)
                 _store_decode_kv(v_scale, v_s, pos, layer)
@@ -971,6 +1053,7 @@ class Attention(nn.Module):
                         v_all if layer is not None else v_all[None],
                         0 if layer is None else layer,
                         jnp.broadcast_to(pos, (b,)),
+                        heads_major=major,
                     )[:, None]
             else:
                 # everything else (a chunk of several positions, int8 and
@@ -988,6 +1071,14 @@ class Attention(nn.Module):
                         _layer_value(v_scale, layer) if quant else None,
                         quant, v.dtype,
                     )
+                    if major:
+                        # (B, KV, W, D) -> positions before heads, and in
+                        # float32: this is the path of toy widths (whole
+                        # tiles take the kernel), and the CPU backend has no
+                        # bfloat16 product of this form inside the layer loop
+                        k_read = jnp.swapaxes(k_read, 1, 2).astype(jnp.float32)
+                        v_read = jnp.swapaxes(v_read, 1, 2).astype(jnp.float32)
+                        q = q.astype(jnp.float32)
                 # attend over the whole cache: query token i (global
                 # position pos + i) masks positions beyond pos + i — same
                 # math as training/prefill (a masked-out cache column
@@ -1005,7 +1096,7 @@ class Attention(nn.Module):
                 out = grouped_masked_attention(
                     q, k_read, v_read,
                     valid[:, None, :, :],
-                )
+                ).astype(x.dtype)
         else:
             q = apply_rope(q_raw, cfg.rope_theta)
             k = apply_rope(k_raw, cfg.rope_theta)
@@ -1023,6 +1114,8 @@ class Attention(nn.Module):
                 quant = _kv_quant_mode(cfg.kv_cache_dtype)
                 k_q, k_s = _encode_kv(k, quant)  # quantized cache: q+scale
                 v_q, v_s = _encode_kv(v, quant)
+                if cfg.kv_heads_major:
+                    k_q, v_q = jnp.swapaxes(k_q, 1, 2), jnp.swapaxes(v_q, 1, 2)
                 _store_prefill_kv(cached_k, k_q, layer)
                 _store_prefill_kv(cached_v, v_q, layer)
                 if quant:
@@ -1062,6 +1155,8 @@ class Attention(nn.Module):
                 attn = causal_attention
             out = attn(q, k_attn, v_attn)
         y = out_proj(out)
+        if cfg.attention_out_multiplier != 1.0:
+            y = y * cfg.attention_out_multiplier
         if cfg.lora_adapters:
             # o_proj delta reads the flattened attention context — same
             # (H*D -> d_model) contraction as the base row-parallel matmul
@@ -1354,12 +1449,17 @@ class SwiGLU(nn.Module):
             up = up + lora(
                 "up_proj_lora", cfg.d_model, ff_dim
             )(x, adapter_ids)
+        gate_mult, down_mult = cfg.mlp_multipliers
+        if gate_mult != 1.0:
+            gate_pre = gate_pre * gate_mult
         hidden = nn.silu(gate_pre) * up
         y = dense(cfg.d_model, "down_proj", "row")(hidden)
         if cfg.lora_adapters:
             y = y + lora(
                 "down_proj_lora", ff_dim, cfg.d_model
             )(hidden, adapter_ids)
+        if down_mult != 1.0:
+            y = y * down_mult
         return y
 
 
@@ -1397,6 +1497,12 @@ def _check_new_block_fields(cfg: TransformerConfig) -> None:
             "(models/sambay.py); the other layouts keep an lm_head of "
             "their own"
         )
+    if cfg.mamba_n_heads and cfg.mb_per_layer:
+        raise ValueError(
+            "mamba_n_heads (a Mamba-2 mixer beside attention in every "
+            "block) and mb_per_layer (Mamba-1 and attention layers by "
+            "their place) are two layouts: set one"
+        )
     if cfg.mb_per_layer:
         if cfg.mb_per_layer != 2 or cfg.n_layers % 4 or cfg.n_layers < 8:
             raise ValueError(
@@ -1417,17 +1523,34 @@ def _check_new_block_fields(cfg: TransformerConfig) -> None:
                 "differential attention pairs neighbouring heads: n_heads "
                 f"{cfg.n_heads} and n_kv_heads {cfg.kv_heads} must be even"
             )
+    if cfg.mamba_n_heads:
+        if (cfg.mamba_d_head < 1 or cfg.mamba_n_groups < 1
+                or cfg.mamba_n_heads % cfg.mamba_n_groups
+                or cfg.mamba_chunk_size < 1 or cfg.mamba_d_conv < 2):
+            raise ValueError(
+                "mamba_n_heads needs mamba_d_head >= 1, mamba_n_groups "
+                "dividing it, mamba_chunk_size >= 1 and mamba_d_conv >= 2; "
+                f"got heads {cfg.mamba_n_heads} of {cfg.mamba_d_head}, "
+                f"{cfg.mamba_n_groups} groups, chunk {cfg.mamba_chunk_size}, "
+                f"{cfg.mamba_d_conv} taps"
+            )
+        if len(cfg.ssm_multipliers) != 5 or len(cfg.mlp_multipliers) != 2:
+            raise ValueError(
+                "ssm_multipliers are five (z, x, B, C, dt) and "
+                "mlp_multipliers two (gate, down)"
+            )
+    if cfg.recurrent:
         if not cfg.scan_layers:
             raise ValueError(
-                "the layout mb_per_layer gives always runs as two layer "
-                "scans with its cache stacked a layer (models/sambay.py): "
-                "say so with scan_layers=True, which is what "
-                "serve.slots.write_slot reads"
+                "a model with recurrent state (mb_per_layer, mamba_n_heads) "
+                "always runs its layers scanned with its cache stacked a "
+                "layer (models/sambay.py, models/mamba2.py): say so with "
+                "scan_layers=True, which is what serve.slots.write_slot reads"
             )
         if _kv_quant_mode(cfg.kv_cache_dtype):
             raise ValueError(
-                "a model with recurrent state keeps its rings and its "
-                "shared cache as floats: kv_cache_dtype "
+                "a model with recurrent state keeps its K and V (rings, "
+                "shared and whole caches) as floats: kv_cache_dtype "
                 f"{cfg.kv_cache_dtype!r} is not supported"
             )
         for field, what in (
@@ -1440,12 +1563,13 @@ def _check_new_block_fields(cfg: TransformerConfig) -> None:
             ("moe_experts", "the capacity-dropping MoEFFN"),
             ("n_routed_experts", "the dropless routed experts"),
             ("kv_lora_rank", "latent attention"),
-            ("remat", "remat (training through the selective scan)"),
+            ("remat", "remat (training through the selective scan, the "
+                      "chunked form or ssd_update)"),
         ):
             if getattr(cfg, field) not in (None, 0, False):
                 raise ValueError(
-                    f"layers with recurrent state (mb_per_layer) do not "
-                    f"run with {what} ({field})"
+                    f"layers with recurrent state (mb_per_layer, "
+                    f"mamba_n_heads) do not run with {what} ({field})"
                 )
     if cfg.latent:
         if not (cfg.q_lora_rank and cfg.qk_nope_head_dim
@@ -1514,16 +1638,29 @@ class Block(nn.Module):
     @nn.compact
     def __call__(
         self, x, decode: bool = False, prefill: bool = False,
-        adapter_ids=None, layer=None, stacks=None,
+        adapter_ids=None, layer=None, stacks=None, p_len=None,
     ):
+        # ``p_len`` ((B,), a prefill of a model with recurrent state): the
+        # rows' real lengths inside the right-padded bucket, where the
+        # state stops
         cfg = self.cfg
         stacks = stacks or {}  # by submodule, as Attention's by projection
         attention = LatentAttention if cfg.latent else Attention
+        u = RMSNorm(cfg.norm_eps, name="attn_norm")(x)
         y = attention(cfg, name="attn")(
-            RMSNorm(cfg.norm_eps, name="attn_norm")(x), decode=decode,
-            prefill=prefill, adapter_ids=adapter_ids, layer=layer,
-            stacks=stacks.get("attn"),
+            u, decode=decode, prefill=prefill, adapter_ids=adapter_ids,
+            layer=layer, stacks=stacks.get("attn"),
         )
+        if cfg.mamba_n_heads:
+            # the parallel mixer: a Mamba-2 branch on the same normed input
+            from pytorch_distributed_training_tutorials_tpu.models.mamba2 import (  # noqa: E501
+                Mamba2Mixer,
+            )
+
+            y = y + Mamba2Mixer(cfg, name="mamba")(
+                u, decode=decode, prefill=prefill, p_len=p_len, layer=layer,
+                stacks=stacks.get("mamba"),
+            )
         if cfg.sandwich_norm:
             y = RMSNorm(cfg.norm_eps, name="post_attn_norm")(y)
         x = x + y
@@ -1569,7 +1706,7 @@ class _ScanCell(nn.Module):
     routed: bool = False
 
     @nn.compact
-    def __call__(self, x, ids, stacks, layers):
+    def __call__(self, x, ids, stacks, layers, p_len=None):
         # ``ids`` is the scan's nn.broadcast input: the per-row adapter-id
         # vector handed WHOLE to every layer (None when lora is off — an
         # empty pytree, so the scanned program is unchanged). ``stacks`` is
@@ -1577,7 +1714,8 @@ class _ScanCell(nn.Module):
         # them, stacked (None unless quantized). ``layers`` is the scanned
         # layer index, once for the cache when the scan carries it and
         # once for the weights when there are ``stacks``, else None (also
-        # an empty pytree)
+        # an empty pytree). ``p_len`` is broadcast like ``ids`` (Block's;
+        # None but for a prefill of a model with recurrent state)
         layer, w_layer = layers
         if stacks is not None:
             from pytorch_distributed_training_tutorials_tpu.ops.quant import (
@@ -1590,7 +1728,7 @@ class _ScanCell(nn.Module):
             )
         return Block(self.cfg, self.routed, name="block")(
             x, decode=self.decode, prefill=self.prefill, adapter_ids=ids,
-            layer=layer, stacks=stacks,
+            layer=layer, stacks=stacks, p_len=p_len,
         ), None
 
 
@@ -1684,8 +1822,27 @@ class TransformerLM(nn.Module):
         else:
             ids = None
         x = nn.Embed(
-            cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="tok_emb"
+            cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+            param_dtype=cfg.embedding_dtype or jnp.float32, name="tok_emb",
         )(tokens)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        p_len = None
+        if cfg.mamba_n_heads:
+            if decode and tokens.shape[1] != 1:
+                raise ValueError(
+                    "a model with recurrent state steps one position at a "
+                    f"time: a decode chunk of {tokens.shape[1]} positions "
+                    "(suffix or chunked prefill, a speculative verify) has "
+                    "no state to rewind to"
+                )
+            if prefill:
+                # a right-padded bucket: the state stops at the row's real
+                # length (the last_pos a bucketed prefill is told)
+                p_len = jnp.broadcast_to(jnp.asarray(
+                    tokens.shape[1] if last_pos is None
+                    else jnp.asarray(last_pos) + 1, jnp.int32,
+                ), (tokens.shape[0],))
         # the layers from routed_from on have the routed experts for their
         # feed-forward, the leading ones the dense SwiGLU
         routed_from = cfg.n_dense_layers if cfg.n_routed_experts else cfg.n_layers
@@ -1729,7 +1886,7 @@ class TransformerLM(nn.Module):
                 variable_axes=axes,
                 variable_carry="cache" if carry_cache else False,
                 split_rngs={"params": True},
-                in_axes=(nn.broadcast, nn.broadcast, 0),
+                in_axes=(nn.broadcast, nn.broadcast, 0, nn.broadcast),
                 length=cfg.n_layers,
             )(cfg, decode, prefill, routed_from == 0, name="layers")
             # the scope marks what lax.scan itself does around the cell:
@@ -1746,7 +1903,7 @@ class TransformerLM(nn.Module):
                 )
                 x, _ = stack(
                     x, ids, stacks,
-                    (index(carry_cache), index(stacks is not None)),
+                    (index(carry_cache), index(stacks is not None)), p_len,
                 )
         else:
             # decode/prefill are Python bools steering cache behavior — they
@@ -1809,13 +1966,18 @@ class TransformerLM(nn.Module):
         if cfg.quantized:
             from pytorch_distributed_training_tutorials_tpu.ops.quant import Int8Dense
 
-            return Int8Dense(
+            logits = Int8Dense(
                 cfg.vocab_size, use_bias=False, name="lm_head",
                 mesh=cfg.tp_mesh, shard_kind="column",
             )(x)
-        return nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=cfg.dtype, name="lm_head"
-        )(x)
+        else:
+            logits = nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                name="lm_head",
+            )(x)
+        if cfg.lm_head_multiplier != 1.0:
+            logits = logits * jnp.asarray(cfg.lm_head_multiplier, logits.dtype)
+        return logits
 
 
 # Megatron-style tensor-parallel layout over the 'model' mesh axis:

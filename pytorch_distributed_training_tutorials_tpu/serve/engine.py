@@ -496,13 +496,15 @@ class ServeEngine:
                     ("kv_bits (a quantized cache)", kv_bits is not None),
                     ("an adapter bank", self._adapters),
                 ))
-        if getattr(model.cfg, "mb_per_layer", 0):
-            # recurrent state and rings of K and V beside the one shared
-            # cache (models/sambay.py): a slot holds them whole, and nothing
-            # that cuts a sequence's cache by position, rewinds it or moves
-            # it knows yet what a state or a ring is
+        if getattr(model.cfg, "recurrent", False):
+            # recurrent state beside K and V (models/sambay.py: rings and
+            # one shared cache; models/mamba2.py: a state and a whole cache
+            # in every layer): a slot holds them whole, and nothing that
+            # cuts a sequence's cache by position, rewinds it or moves it
+            # knows yet what a state or a ring is
             _whole_slots_only(
-                "a model with recurrent state (mb_per_layer > 0)", (
+                "a model with recurrent state (mb_per_layer or "
+                "mamba_n_heads > 0)", (
                     ("paged=True (a page holds heads of K and V at "
                      "absolute positions: neither a state nor a ring)",
                      paged),

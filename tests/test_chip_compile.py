@@ -819,3 +819,146 @@ def test_float_tp_serve_chain_compiles_on_a_mesh(topo, monkeypatch):
     assert "decode_attention" not in hlo and "tpu_custom_call" not in hlo
     # K and V stay split by head: the Megatron all-reduces and no gather
     assert "all-reduce" in hlo and "all-gather" not in hlo
+
+
+def test_ssd_update_compiles(one_chip):
+    """One Mamba-2 layer's decode step at Falcon-H1-34B's sizes (64 slots,
+    32 heads of 128 by 256 states in 2 groups, a stack of 6 layers): the
+    stack is donated into the result and nothing is made beside it."""
+    from pytorch_distributed_training_tutorials_tpu.ops.ssd import ssd_update
+
+    shapes = (
+        _sds((6, 64, 32, 256, 128), jnp.float32), _sds((), jnp.int32),
+        _sds((64, 32), jnp.float32), _sds((64, 32, 128), jnp.float32),
+        _sds((64, 2, 256), jnp.float32), _sds((64, 2, 256), jnp.float32),
+        _sds((64,), jnp.int32),
+    )
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes,
+    )
+    compiled = jax.jit(
+        lambda s, l, d, x, b, c, p: ssd_update(
+            s, l, d, x, b, c, p, 4096, interpret=False),
+        donate_argnums=0,
+    ).lower(*args).compile()
+    assert re.search(r"%ssd_update[\w.]* = ", compiled.as_text())
+    analysis = compiled.memory_analysis()
+    assert analysis.alias_size_in_bytes >= 6 * 64 * 32 * 256 * 128 * 4
+    assert analysis.temp_size_in_bytes < 1 << 26, analysis.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["_chain_fn", "_prefill_fn"])
+def test_parallel_block_programs_copy_no_cache(one_chip, monkeypatch, program):
+    """``ServeEngine``'s chain and its 2,048 prefill for a model with a
+    Mamba-2 mixer beside attention in every block, at Falcon-H1-34B's
+    published widths (6 of 72 layers, 64 slots x 4,096 positions, int8,
+    the 261,120-wide head): the K and V stacks (four KV heads with their
+    rows together: innermost, four bfloat16 heads pad a sublane tile to
+    four times the bytes) and the state stack ride the one layer scan as
+    carries, ``decode_attention`` and ``ssd_update`` read them where they
+    lie, every scanned product reads the stacked int8 weights at its layer
+    index (``in_proj`` in whole lane tiles, the 32 ``dt`` columns a matrix
+    of their own: at 9,248 columns the compiler relaid the 283 MB stack on
+    its way into every launch), and the whole fits a 16 GB chip beside the
+    1.34 GB int8 embedding the benchmark's reference reads (ISSUE 36).
+    Shapes only."""
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+    from pytorch_distributed_training_tutorials_tpu.serve import (
+        engine as engine_module,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve.slots import (
+        init_slot_state,
+        tree_nbytes,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        engine_module, "init_slot_state",
+        lambda model, params, *a, **kw: jax.eval_shape(
+            lambda p: init_slot_state(model, p, *a, **kw), params
+        ),
+    )
+    model = TransformerLM(TransformerConfig(
+        vocab_size=261120, d_model=5120, n_layers=6, n_heads=20,
+        n_kv_heads=4, d_head=128, d_ff=21504, max_seq_len=4096,
+        norm_eps=1e-5, rope_theta=1e11, mamba_n_heads=32, mamba_d_head=128,
+        mamba_n_groups=2, mamba_d_state=256, mamba_chunk_size=128,
+        embedding_multiplier=5.66, lm_head_multiplier=0.0078125,
+        attention_out_multiplier=0.0375, key_multiplier=0.011,
+        ssm_in_multiplier=0.25, ssm_out_multiplier=0.088,
+        ssm_multipliers=(0.354, 0.25, 0.177, 0.5, 0.354),
+        mlp_multipliers=(0.177, 0.0112), scan_layers=True, quantized=True,
+        dtype=jnp.bfloat16, kv_cache_dtype=jnp.bfloat16,
+        embedding_dtype=jnp.bfloat16,
+    ))
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0),
+    )
+    engine = ServeEngine(model, params, n_slots=64, tokens_per_launch=8)
+    cache = engine._state["cache"]
+    assert engine.stats("slot") == {
+        "slot_kv_bytes": 6 * 2 * 4096 * 4 * 128 * 2, "slot_ring_bytes": 0,
+        "slot_state_bytes": 6 * (32 * 256 * 128 + 3 * 5120) * 4,
+    }
+    args = (params, engine._state)
+    if program == "_prefill_fn":
+        i32 = _sds((), jnp.int32)
+        args += (_sds((1, 2048), jnp.int32), i32, i32, i32, i32)
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        args,
+    )
+    compiled = (
+        jax.jit(getattr(engine, program), donate_argnums=(1,))
+        .lower(*placed).compile()
+    )
+    hlo = compiled.as_text()
+    analysis = compiled.memory_analysis()
+    # the slot tree is donated into the result; beside it the chain makes
+    # 70 MB, the prefill a bucket's activations (0.56 GB); arguments and
+    # temporaries leave room for the reference's 1.34 GB under 16 GB
+    assert analysis.alias_size_in_bytes >= tree_nbytes(cache)
+    limit = (1 << 27) if program == "_chain_fn" else 1 << 30
+    assert analysis.temp_size_in_bytes < limit, analysis.temp_size_in_bytes
+    assert (analysis.argument_size_in_bytes + analysis.temp_size_in_bytes
+            + 261120 * 5120) < 15 << 30
+    # no int8 weight of more than a megabyte is made (the 32 dt columns'
+    # stack, 1 MB, is relaid: no whole lane tile)
+    made = [
+        (op, dims) for dims, layout, op in re.findall(
+            r"= s8\[([\d,]+)\](\S*) ([\w\-]+)\(", hlo)
+        if op not in ("parameter", "get-tuple-element", "bitcast", "copy-done")
+        and "S(1)" not in layout
+        and math.prod(int(n) for n in dims.split(",")) > 6 * 5120 * 32
+    ]
+    assert made == [], made
+    # every result the size of a stack IS a stack, updated in place
+    big = {
+        (kind, op, dims) for kind, dims, op in re.findall(
+            r"= (bf16|f32)\[([\d,]+)\]\S* ([\w\-]+)\(", hlo)
+        if math.prod(int(n) for n in dims.split(",")) >= 6 * 64 * 4 * 4096 * 128
+        and op not in ("parameter", "get-tuple-element", "bitcast", "while",
+                       "tuple")
+    }
+    stacks = {"6,64,4,4096,128", "6,64,32,256,128"}
+    assert all(
+        dims in stacks and op not in ("copy", "dynamic-slice", "transpose")
+        for _, op, dims in big
+    ), big
+    assert "261120,5120" not in "".join(
+        line for line in hlo.splitlines()
+        if " convert(" in line or " copy(" in line)
+    if program == "_chain_fn":
+        assert len(set(re.findall(r"%(ssd_update[\w.]*) = ", hlo))) >= 1
+        assert re.search(r"%decode_attention[\w.]* = bf16\[64,20,128\]", hlo)
+    stacked = re.findall(
+        r"%int8_matmul[\w.]* = f32\[[\d,]+\]\S* custom-call\(.*?"
+        r"operand_layout_constraints=\{(s32\[1\]\{0\}, .*?)\}, frontend", hlo)
+    # q, k, v, o; in_proj, dt_proj, out_proj; gate, up, down
+    assert len(stacked) == 10, len(stacked)

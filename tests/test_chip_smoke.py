@@ -31,7 +31,7 @@ def test_rehearsal_runs_every_phase_and_names_the_cpu():
     phases = [l["phase"] for l in lines[:-1]]
     assert phases == [
         "device", "train", "serve", "serve_paged_gather",
-        "serve_paged_kernel", "serve_state", "total",
+        "serve_paged_kernel", "serve_state", "serve_parallel", "total",
     ]
     assert all(l["ok"] is True for l in lines), lines
     assert lines[-1] == {
@@ -49,6 +49,10 @@ def test_rehearsal_runs_every_phase_and_names_the_cpu():
     assert state["prompt_len"] > state["model"]["ring"]
     assert state["generate_greedy_gap_rel"] <= 0.1
     assert state["slot_state_bytes"] > 0 and state["slot_ring_bytes"] > 0
+    both = by["serve_parallel"]  # a state and a KV cache in one layer
+    assert both["generate_greedy_gap_rel"] <= 0.1
+    assert both["slot_state_bytes"] > 0 and both["slot_kv_bytes"] > 0
+    assert len(both["prompt_lens"]) > 2  # more requests than slots: a refill
 
 
 def test_four_chip_rehearsal_runs_only_the_multichip_phase():

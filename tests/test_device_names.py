@@ -41,6 +41,7 @@ KERNELS = {
     "latent_decode_attention": "latent_attention.py",
     "decode_attention": "decode_attention.py",
     "selective_scan": "selective_scan.py",
+    "ssd_update": "ssd.py",
 }
 
 
@@ -290,4 +291,59 @@ def test_recurrent_state_writes_keep_their_paths(state_engine, which, path):
     ``kv_cache`` at their layer's path (a scan's body names its own from
     the scanned module down: ``layers_a/layers/...``)."""
     text = _lowered(which, state_engine, None).as_text(debug_info=True)
+    assert path in text, path
+
+
+@pytest.fixture(scope="module")
+def parallel_engine():
+    """A Mamba-2 mixer beside attention in every block, heads of 128 and a
+    state of whole tiles, int8, one layer scan: what the benchmark's
+    Falcon-H1 cell runs (ISSUE 36)."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=64, n_layers=2, n_heads=2, n_kv_heads=1,
+        d_head=128, d_ff=64, max_seq_len=128, scan_layers=True,
+        mamba_n_heads=2, mamba_d_head=128, mamba_n_groups=1,
+        mamba_d_state=16, mamba_chunk_size=8, quantized=True,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    return ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+
+
+@pytest.mark.parametrize("which", ["chain", "prefill"])
+@pytest.mark.parametrize("scope", [
+    "ssm_conv", "ssm_scan", "ssm_gate_norm", "attn", "mamba", "kv_cache",
+    "layer_scan", "layers", "mlp", "lm_head",
+])
+def test_parallel_block_scopes_are_in_the_lowered_text(
+        parallel_engine, which, scope):
+    """What ``ssm_share.serve`` reads, the gate and the grouped norm (the
+    by-scope table's), and the attention branch under the names it has in
+    every other model."""
+    text = _lowered(which, parallel_engine, None).as_text(debug_info=True)
+    assert re.search(rf'[/"(]{scope}\)*/', text), scope
+
+
+@pytest.mark.parametrize("which,path", [
+    # a step: the state's kernel, the attention branch's, its new rows
+    ("chain", "layers/block/mamba/ssm_scan/ssd_update/pallas_call"),
+    ("chain", "layers/block/attn/decode_attn/"),
+    ("chain", "layers/block/attn/kv_cache/scatter"),
+    ("chain", "layers/block/mamba/ssm_conv/dynamic_update_slice"),
+    # a prompt: its K and V, the chunked form's products, its state
+    ("prefill", "layers/block/attn/kv_cache/dynamic_update_slice"),
+    ("prefill", "layers/block/mamba/ssm_scan/"),
+    ("prefill", "layers/block/mamba/ssm_gate_norm/"),
+    # both branches' products read the stacked weights by name
+    ("chain", "layers/block/mamba/in_proj/int8_matmul/pallas_call"),
+    ("chain", "layers/block/mamba/dt_proj/int8_matmul/pallas_call"),
+    ("chain", "layers/block/attn/q_proj/int8_matmul/pallas_call"),
+])
+def test_parallel_block_keeps_its_paths(parallel_engine, which, path):
+    """``ssd_update`` under ``ssm_scan`` (``ssd_update_share.serve`` reads
+    the kernel's name, ``ssm_share.serve`` the scope), the attention branch
+    under ``attn/decode_attn`` and ``attn/kv_cache`` as in every model
+    (that no stack is copied is ``tests/test_chip_compile.py``'s)."""
+    text = _lowered(which, parallel_engine, None).as_text(debug_info=True)
     assert path in text, path

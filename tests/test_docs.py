@@ -104,3 +104,64 @@ def test_perf_md_has_every_cell(cell):
 )
 def test_perf_md_has_every_end_to_end_metric(metric):
     assert f"`{metric}`" in (REPO / "PERF.md").read_text()
+
+
+# The form the driver holds ``BENCHMARK.json`` to before any run (PR 36 was
+# refused for a ``why`` of 203 characters).
+_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
+_UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
+_KEYS = {
+    "configs": {"name", "source", "file", "reduced", "why"},
+    "workloads": {"name", "config", "traffic", "chips", "why"},
+    "end_to_end": {"name", "unit", "better", "bound", "source"},
+    "per_layer": {"name", "unit", "better", "source", "layer", "moves"},
+}
+
+
+def _one_line(text: str) -> bool:
+    return 1 <= len(text) <= 200 and text.isascii() and text.isprintable()
+
+
+@pytest.mark.parametrize("entry", [
+    pytest.param((group, e), id=f"{group}:{e['name']}")
+    for group in _KEYS for e in BENCHMARK[group]
+])
+def test_benchmark_json_entry_has_the_drivers_form(entry):
+    group, e = entry
+    assert set(e) - {"workloads"} == _KEYS[group]
+    names = [e["name"], *e.get("reduced", []), *e.get("workloads", [])]
+    names += [e[k] for k in ("config", "traffic", "moves") if k in e]
+    assert [n for n in names if not _NAME.fullmatch(n)] == []
+    lines = [e[k] for k in ("why", "source", "layer") if k in e]
+    assert [t for t in lines if not _one_line(t)] == []
+    if "unit" in e:
+        assert _UNIT.fullmatch(e["unit"]) and e["better"] in ("lower", "higher")
+    if "file" in e:
+        assert e["file"].startswith(tuple(p + "/" for p in BENCHMARK["paths"]))
+        assert (REPO / e["file"]).is_file() and len(e["reduced"]) <= 16
+
+
+def test_benchmark_json_as_a_whole_has_the_drivers_form():
+    assert (REPO / "BENCHMARK.json").stat().st_size <= 64 * 1024
+    assert all(_one_line(word) for word in BENCHMARK["command"])
+    cells = BENCHMARK["workloads"]
+    for group in _KEYS:
+        names = [e["name"] for e in BENCHMARK[group]]
+        assert len(names) == len(set(names)), group
+    pairs = [(w["config"], w["traffic"]) for w in cells]
+    assert len(pairs) == len(set(pairs))
+    configs = {c["name"] for c in BENCHMARK["configs"]}
+    assert {w["config"] for w in cells} == configs
+    files = [c["file"] for c in BENCHMARK["configs"]]
+    assert len(files) == len(set(files))
+    assert all(w["chips"] in (1, 4) for w in cells)
+    assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 4)
+    known = {w["name"] for w in cells}
+    ends = {m["name"] for m in BENCHMARK["end_to_end"]}
+    for m in BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]:
+        assert set(m.get("workloads", [])) <= known, m["name"]
+        assert m.get("moves", m["name"]) in ends, m["name"]
+    # 2 + 14 runs a cell of run_seconds + 60, 2 x 90 s a cell, 1200 s spare
+    runs = 2 + 14 * len(cells)
+    seconds = runs * (BENCHMARK["run_seconds"] + 60) + 180 * len(cells) + 1200
+    assert seconds <= 43200
