@@ -22,6 +22,7 @@ decoder (RMSNorm, rotary positions, SwiGLU) written for XLA:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from collections.abc import Callable
@@ -1127,11 +1128,6 @@ class Attention(nn.Module):
                 if cfg.attention_fn is not None
                 else causal_attention
             )
-            # GQA: attention_fns keep their (B, S, H, D) contract — K/V
-            # repeat up to the query head count here (the cache, when
-            # prefilling, stores the UN-repeated kv heads)
-            k_attn = _expand_kv(k, h)
-            v_attn = _expand_kv(v, h)
             div = getattr(attn, "requires_seq_divisible", 0)
             if not decode and not prefill:
                 # tag for remat_policy="dots_attn": saveable across the
@@ -1153,7 +1149,38 @@ class Attention(nn.Module):
                 # schedule and its memory bound; other custom fns (e.g.
                 # the Pallas flash kernel) handle any length. (ADVICE r3)
                 attn = causal_attention
-            out = attn(q, k_attn, v_attn)
+            from pytorch_distributed_training_tutorials_tpu.ops.flash_attention import (  # noqa: E501
+                flash_attention_forward,
+                prefill_takes_kernel,
+            )
+
+            # a prefill's attention sits under one scope on both forms, so
+            # that the device trace says which ran and what it cost
+            with (
+                jax.named_scope("prefill_attn") if prefill
+                else contextlib.nullcontext()
+            ):
+                if (
+                    prefill and cfg.attention_fn is None
+                    and cfg.tp_mesh is None
+                    and prefill_takes_kernel(x.shape[1], d)
+                ):
+                    # the model's own causal attention, no mesh, heads of
+                    # whole lane tiles, a length from which the kernel
+                    # wins: the flash forward over the K and V just
+                    # computed, at their stored head count (no score
+                    # matrix, no copy of K and V to the query heads). The
+                    # cache's storage is no condition: it was written
+                    # above either way
+                    out = flash_attention_forward(q, k, v)
+                else:
+                    # a user's attention_fn, a mesh, toy head widths, short
+                    # prompts, training: the dense form, the kernel's
+                    # reference. GQA: attention_fns keep their (B, S, H, D)
+                    # contract — K/V repeat up to the query head count here
+                    # (the cache, when prefilling, stores the UN-repeated
+                    # kv heads)
+                    out = attn(q, _expand_kv(k, h), _expand_kv(v, h))
         y = out_proj(out)
         if cfg.attention_out_multiplier != 1.0:
             y = y * cfg.attention_out_multiplier

@@ -27,6 +27,11 @@ kernel, so scores only ever exist as a ``(block_q, block_k)`` tile in VMEM.
 :class:`..models.transformer.TransformerConfig` — same (B, S, H, D)
 signature and causal semantics as ``causal_attention``, equivalence-tested
 in ``tests/test_flash_attention.py``.
+
+``flash_attention_forward`` is the forward kernel alone, for a serving
+prefill (``Attention`` picks it by :func:`prefill_takes_kernel`): K and V
+stay at their stored head count — the key and value blocks' index maps take
+the query head's group — and nothing is saved for a backward pass.
 """
 
 from __future__ import annotations
@@ -67,7 +72,10 @@ def _fwd_kernel(
         # preferred_element_type), and Mosaic is what decides the MXU
         # pass structure — measured identical on v5e with or without the
         # explicit upcast (it folds the convert into the op), so the
-        # native form is kept for clarity, not speed
+        # native form is kept for clarity, not speed. float32 operands it
+        # multiplies in ONE bfloat16 pass (v5e, PR 37: bit for bit the
+        # result of operands rounded to bfloat16 beforehand) — what XLA's
+        # default precision does in the dense form's products
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -239,8 +247,9 @@ def _block_sizes(
     return block_q, block_k, target - s
 
 
-def _fwd_impl(q, k, v, block_q, block_k, interpret):
+def _fwd_impl(q, k, v, block_q, block_k, interpret, out_dtype=None):
     b, s, h, d = q.shape
+    group = h // k.shape[2]  # query heads a KV head; 1: the old index map
     scale = 1.0 / (d ** 0.5)
     block_q, block_k, pad = _block_sizes(s, block_q, block_k, interpret)
     qf, kf, vf = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
@@ -259,7 +268,10 @@ def _fwd_impl(q, k, v, block_q, block_k, interpret):
         memory_space=pltpu.VMEM,
     )
     kspec = pl.BlockSpec(
-        (1, block_k, d), lambda bh, qi, kk: (bh, kk, 0),
+        (1, block_k, d),
+        (lambda bh, qi, kk: (bh, kk, 0)) if group == 1
+        # q's row b * H + head reads row b * KV + head // group of k and v
+        else (lambda bh, qi, kk: (bh // group, kk, 0)),
         memory_space=pltpu.VMEM,
     )
     out, lse = pl.pallas_call(
@@ -277,7 +289,7 @@ def _fwd_impl(q, k, v, block_q, block_k, interpret):
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sp, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sp, d), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((b * h, 8, sp), jnp.float32),
         ],
         scratch_shapes=[
@@ -419,6 +431,76 @@ def _flash_bwd(block_q, block_k, interpret, res, g):
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# The shortest prefill the kernel takes: the kernel alone against the dense
+# form (with its copy of K and V to the query heads), us a layer on one v5e
+# (PERF.md section 6, PR 37). Up to 512 positions the dense form is ahead by
+# x 1.6-2.1 (16 float32 heads over 8: 9 / 15 / 29-38 us against 17 / 30 /
+# 48-61 at 32 / 256 / 512: a grid step and the layout copies are a floor of
+# some 17 us). From 1,024 the kernel is level or ahead at every shape
+# measured: 82-91 against 86 (16 float32 heads over 8), 112 against 108 (20
+# bfloat16 heads over 4), 621 against 154 (32 float32 heads over 8: past
+# some 100 MB of scores the dense form falls off a cliff), and x 2.9-6.4 at
+# 2,048 and 4,096.
+_PREFILL_MIN_LEN = 1024
+
+
+def prefill_takes_kernel(s: int, d: int) -> bool:
+    """Whether a prefill of ``s`` positions at a head width of ``d`` attends
+    through :func:`flash_attention_forward`: ``d`` a whole number of 128-lane
+    tiles (toy widths stay dense: every tile of theirs is padded to 128), and
+    ``s`` from the length at which the kernel alone no longer lost to the
+    dense form on the chip. A rule on what the call can see, like
+    ``decode_block``: no flag, no model's name."""
+    return d % 128 == 0 and s >= _PREFILL_MIN_LEN
+
+
+def flash_attention_forward(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    block_q: int = 1024,
+    block_k: int = 1024,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """The causal forward alone: ``q`` (B, S, H, D) against ``k`` / ``v``
+    (B, S, KV, D) at their STORED head count, ``H`` a multiple of ``KV``; the
+    result (B, S, H, D) in ``q``'s dtype. Not differentiable
+    (:func:`flash_attention` is, at ``H == KV``).
+
+    Tiles of 1,024 x 1,024, clamped to the length (v5e, PR 37, 32 heads over
+    8 at 4,096 positions: 2.47 ms at 512 x 512, 2.23 at 1,024 x 512, 1.72 at
+    512 x 1,024, 1.59 at 1,024 x 1,024, 1.75 at 1,024 x 2,048; a tile of
+    512 x 512 is 0.7 us of MXU work under a rescale of the accumulator and a
+    grid step that cost as much, so the key block counts for more; 2,048
+    query rows pass the fast memory). The differentiable call keeps its
+    512 x 512: its backward kernels were measured there.
+
+    On the chip float32 operands are rounded to bfloat16 here, before the
+    kernel's DMA, and not in it: the MXU multiplies float32 operands in one
+    bfloat16 pass either way (the kernel's note), as it does for the dense
+    form under XLA's default precision, whose compiled program rounds q, k
+    and v where they are made. No product changes, a tile moves half the
+    bytes (1.44 ms for 1.59 above). Scores, softmax and the accumulator are
+    float32. Off the chip (the interpreter) nothing is rounded, as XLA's
+    default precision rounds nothing there."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, s, h, _ = q.shape
+    if h % k.shape[2]:
+        raise ValueError(
+            f"{h} query heads are no multiple of {k.shape[2]} KV heads"
+        )
+    out_dtype = q.dtype
+    if not interpret and out_dtype == jnp.float32:
+        q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    out, _, _, _, pad = _fwd_impl(
+        q, k, v, block_q, block_k, interpret, out_dtype
+    )
+    if pad:
+        out = out[:, :s, :]
+    return _from_bhsd(out, b, h)
 
 
 def make_flash_attention(

@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_training_tutorials_tpu.ops.flash_attention import (
     flash_attention,
+    flash_attention_forward,
 )
 from pytorch_distributed_training_tutorials_tpu.ops.fused_loss import (
     fused_cross_entropy,
@@ -99,6 +100,35 @@ def _compile(fn, one_chip, *shapes):
 
 def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize(
+    "s,h,kv,d,dtype",
+    [
+        (4096, 32, 8, 128, jnp.float32),  # the long cell's largest bucket
+        (1024, 16, 8, 128, jnp.float32),  # the chat cell's, one tile
+        (2048, 20, 4, 128, jnp.bfloat16),  # the Falcon-H1 cell's
+        (1536, 8, 2, 256, jnp.bfloat16),  # a padded tail, a wider head
+    ],
+    ids=["long", "chat", "falcon_h1", "ragged_d256"],
+)
+def test_flash_attention_forward_compiles(one_chip, s, h, kv, d, dtype):
+    """The forward alone at its tiles of 1,024 x 1,024 with K and V at
+    their stored head count: the kernel's K and V operands keep ``kv``
+    heads, float32 operands arrive rounded to bfloat16, the result is in
+    ``q``'s dtype."""
+    hlo = _compile(
+        functools.partial(flash_attention_forward, interpret=False),
+        one_chip, _sds((1, s, h, d), dtype), _sds((1, s, kv, d), dtype),
+        _sds((1, s, kv, d), dtype),
+    )
+    sp = -(-s // 1024) * 1024
+    out = "f32" if dtype == jnp.float32 else "bf16"
+    assert re.search(
+        rf"%flash_attention_fwd[\w.]* = \({out}\[{h},{sp},{d}\]\S*, .*?"
+        rf"operand_layout_constraints=\{{bf16\[{h},{sp},{d}\]\{{2,1,0\}}, "
+        rf"bf16\[{kv},{sp},{d}\]\{{2,1,0\}}, bf16\[{kv},{sp},{d}\]", hlo
+    ), "the kernel's operands are not (h, kv, kv) heads of bfloat16"
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
@@ -402,6 +432,45 @@ CHAT = dict(  # benchmark/configs/internlm2-1.8b.json, serve mode
 CHAT_SLOTS = 32
 
 
+def _int8_engine_of_shapes(monkeypatch, cfg: dict, n_slots: int, **options):
+    """``ServeEngine`` over the int8 form of a float model at ``cfg``, with
+    parameters and slot state as shapes: nothing is allocated at that size.
+    Returns the engine and its parameters."""
+    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+        quantize_lm_params,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
+    from pytorch_distributed_training_tutorials_tpu.serve import (
+        engine as engine_module,
+    )
+    from pytorch_distributed_training_tutorials_tpu.serve.slots import (
+        init_slot_state,
+    )
+
+    # int8_matmul asks the backend whether to interpret its kernel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the engine's own slot state, as shapes
+    monkeypatch.setattr(
+        engine_module, "init_slot_state",
+        lambda model, params, *a, **kw: jax.eval_shape(
+            lambda p: init_slot_state(model, p, *a, **kw), params
+        ),
+    )
+    float_model = TransformerLM(TransformerConfig(**cfg))
+    model = TransformerLM(TransformerConfig(**cfg, quantized=True))
+    params = jax.eval_shape(
+        lambda key: quantize_lm_params(
+            float_model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+        ),
+        jax.random.PRNGKey(0),
+    )
+    return ServeEngine(
+        model, params, n_slots=n_slots, tokens_per_launch=8, **options
+    ), params
+
+
 def _update_operands(hlo: str) -> dict[str, int]:
     """Shape -> element count of the update operand of every
     ``dynamic-update-slice`` in an optimized HLO text (operands are named,
@@ -453,38 +522,8 @@ def test_serve_chain_carries_the_cache_in_place(
     the paged pool and the speculative chain keep the plain path and its
     read copy. Nothing is allocated at that size: params and the engine's
     slot state are shapes."""
-    from pytorch_distributed_training_tutorials_tpu.models.transformer import (
-        TransformerConfig,
-        TransformerLM,
-        quantize_lm_params,
-    )
-    from pytorch_distributed_training_tutorials_tpu.serve import ServeEngine
-    from pytorch_distributed_training_tutorials_tpu.serve import (
-        engine as engine_module,
-    )
-    from pytorch_distributed_training_tutorials_tpu.serve.slots import (
-        init_slot_state,
-    )
-
-    # int8_matmul asks the backend whether to interpret its kernel
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # the engine's own 32-slot state, as shapes
-    monkeypatch.setattr(
-        engine_module, "init_slot_state",
-        lambda model, params, *a, **kw: jax.eval_shape(
-            lambda p: init_slot_state(model, p, *a, **kw), params
-        ),
-    )
-    float_model = TransformerLM(TransformerConfig(**CHAT))
-    model = TransformerLM(TransformerConfig(**CHAT, quantized=True))
-    params = jax.eval_shape(
-        lambda key: quantize_lm_params(
-            float_model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]
-        ),
-        jax.random.PRNGKey(0),
-    )
-    engine = ServeEngine(
-        model, params, n_slots=CHAT_SLOTS, tokens_per_launch=8, **options
+    engine, params = _int8_engine_of_shapes(
+        monkeypatch, CHAT, CHAT_SLOTS, **options
     )
     state = engine._state
     placed = jax.tree_util.tree_map(
@@ -962,3 +1001,67 @@ def test_parallel_block_programs_copy_no_cache(one_chip, monkeypatch, program):
         r"operand_layout_constraints=\{(s32\[1\]\{0\}, .*?)\}, frontend", hlo)
     # q, k, v, o; in_proj, dt_proj, out_proj; gate, up, down
     assert len(stacked) == 10, len(stacked)
+
+
+LONG = dict(  # benchmark/configs/mistral-7b-v0.1.json, serve mode
+    vocab_size=32000, d_model=4096, n_layers=32, n_heads=32, n_kv_heads=8,
+    d_ff=14336, max_seq_len=4096, rope_theta=1e4, norm_eps=1e-5,
+    dtype=jnp.float32, scan_layers=True, kv_cache_dtype=jnp.bfloat16,
+)
+LONG_SLOTS = 8
+
+
+def test_long_prefill_attends_through_the_flash_kernel(one_chip, monkeypatch):
+    """``ServeEngine``'s prefill at the long cell's widths (32 layers, 32
+    query heads over 8 KV heads of 128, float32 activations, 8 slots x
+    4,096 positions, int8 weights) at ``bucket`` 4,096: its causal attention
+    is ``flash_attention_fwd`` with K and V at their 8 stored heads, rounded
+    to bfloat16 where they are made (what the dense form's products did with
+    float32 operands under XLA's default precision). No ``f32[32,4096,4096]``
+    score matrix exists (three fusions over it were 0.57 s of a 3 s window
+    before ISSUE 37), nothing of its size does, and the program's temporaries
+    are half what they were (3.36 GB on the parent). Shapes only."""
+    engine, params = _int8_engine_of_shapes(monkeypatch, LONG, LONG_SLOTS)
+    i32 = _sds((), jnp.int32)
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (params, engine._state, _sds((1, 4096), jnp.int32), i32, i32, i32,
+         i32),
+    )
+    compiled = (
+        jax.jit(engine._prefill_fn, donate_argnums=(1,))
+        .lower(*placed).compile()
+    )
+    hlo = compiled.as_text()
+    call = re.search(
+        r"%flash_attention_fwd[\w.]* = \(f32\[32,4096,128\]\S*, "
+        r"f32\[32,8,4096\]\S*\) custom-call\(.*?"
+        r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", hlo)
+    assert call, "no flash_attention_fwd kernel in the prefill"
+    assert call.group(1) == (
+        "bf16[32,4096,128]{2,1,0}, bf16[8,4096,128]{2,1,0}, "
+        "bf16[8,4096,128]{2,1,0}"
+    )
+    assert "layers/block/attn/prefill_attn/flash_attention_fwd" in hlo
+    # no score matrix, nor anything of its size: the float (and mask)
+    # arrays larger than an MLP product of the 4,096 positions are the
+    # embedding, the slots' cache stacks and the new cache's
+    mlp = 4096 * LONG["d_ff"]
+    large = {
+        f"{dtype}[{dims}]"
+        for dtype, dims in re.findall(r"= (f32|bf16|pred)\[([\d,]+)\]", hlo)
+        if math.prod(int(n) for n in dims.split(",")) > mlp
+    }
+    assert large == {
+        "f32[32000,4096]", "bf16[32,8,4096,8,128]", "bf16[32,1,4096,8,128]",
+    }, large
+    # K and V enter the kernel at 8 heads (above) and no product is left
+    # under the scope beside it: nothing multiplies a 32-head copy of them
+    beside = [
+        line for line in hlo.splitlines()
+        if "/prefill_attn/" in line and "flash_attention_fwd" not in line
+    ]
+    assert beside and not any(
+        re.search(r" (convolution|dot)\(", line) for line in beside
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024**3

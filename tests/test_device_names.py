@@ -159,6 +159,40 @@ def test_decode_kernel_is_under_its_scope_in_the_chain():
     assert re.search(r'[/"(]layer_scan\)*/', text)
 
 
+@pytest.mark.parametrize("bucket,kernel", [(1024, True), (64, False)],
+                         ids=["kernel", "dense"])
+def test_prefill_attention_is_under_its_scope_on_both_forms(bucket, kernel):
+    """At ``head_dim`` 128 a prefill of a bucket from the threshold up
+    attends through ``flash_attention_fwd`` under
+    ``layers/block/attn/prefill_attn`` (``prefill_attention_share.*``), a
+    shorter one through the dense form under the same scope: the trace says
+    which ran and what it cost. The chain's program holds neither."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=256, n_layers=2, n_heads=2, n_kv_heads=1,
+        d_ff=64, max_seq_len=1024, scan_layers=True,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    engine = ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+    prefill = engine._prefill.lower(
+        engine.params, engine._state, jnp.zeros((1, bucket), jnp.int32),
+        5, 0, 0, 4).as_text(debug_info=True)
+    assert "module @jit__prefill_fn " in prefill
+    assert re.search(r'[/"(]layer_scan\)*/', prefill)
+    under = re.findall(r'"layers/block/attn/prefill_attn/([^"]*)"', prefill)
+    assert under
+    assert ("flash_attention_fwd" in prefill) is kernel
+    assert any("flash_attention_fwd" in u for u in under) is kernel
+    # the dense form's score product, or the kernel (whose own products
+    # the interpreter lowers under its name here)
+    assert any(u.startswith("bqhd,bkhd->bhqk") for u in under) is not kernel
+    assert '"layers/block/attn/kv_cache/dynamic_update_slice"' in prefill
+    chain = engine._chain.lower(engine.params, engine._state).as_text(
+        debug_info=True)
+    assert "flash_attention_fwd" not in chain and "prefill_attn" not in chain
+
+
 @pytest.mark.parametrize("which,module", [
     ("chain", "jit__chain_fn"), ("prefill", "jit__prefill_fn"),
     ("train", "jit_step_fn"),

@@ -17,10 +17,13 @@ from pytorch_distributed_training_tutorials_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
     causal_attention,
+    grouped_masked_attention,
 )
 from pytorch_distributed_training_tutorials_tpu.ops.flash_attention import (
     flash_attention,
+    flash_attention_forward,
     make_flash_attention,
+    prefill_takes_kernel,
 )
 
 from helpers import requires_pallas_interpret
@@ -63,6 +66,59 @@ def test_unequal_block_sizes():
         np.asarray(out), np.asarray(causal_attention(q, k, v)),
         atol=2e-5, rtol=2e-5,
     )
+
+
+# (s, block): a bucket of whole blocks (64, 512, 2048), shorter than one
+# block (40: the block clamps to the 8-aligned length), no whole number of
+# blocks (300 in blocks of 128: a padded tail)
+_GROUPED_LENGTHS = [(64, 32), (512, 128), (2048, 512), (40, 128), (300, 128)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("h,kv", [(4, 2), (32, 8), (20, 4)])
+@pytest.mark.parametrize("s,block", _GROUPED_LENGTHS)
+def test_grouped_forward_matches_grouped_dense(s, block, h, kv, dtype):
+    """K and V at their stored head count (``KV < H``): each query head
+    attends over its group's rows, as ``grouped_masked_attention`` under the
+    causal mask does, with no copy of K and V to ``H`` heads. Two sequences
+    a call (one at 2,048, for the suite's time), so that a batch row's
+    heads take their own row's K and V."""
+    b, d = (1, 16) if s > 512 else (2, 32)
+    keys = jax.random.split(jax.random.PRNGKey(s + h), 3)
+    q = jax.random.normal(keys[0], (b, s, h, d), dtype)
+    k = jax.random.normal(keys[1], (b, s, kv, d), dtype)
+    v = jax.random.normal(keys[2], (b, s, kv, d), dtype)
+    out = flash_attention_forward(q, k, v, block, block)
+    assert out.shape == q.shape and out.dtype == dtype
+    mask = jnp.tril(jnp.ones((s, s), jnp.bool_))[None, None]
+    ref = grouped_masked_attention(q, k, v, mask)
+    tol = 2e-5 if dtype == jnp.float32 else 0.05
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=tol, rtol=tol,
+    )
+
+
+def test_forward_alone_equals_the_differentiable_call_at_equal_heads():
+    """``H == KV``: the forward alone is ``flash_attention``'s own forward,
+    bit for bit (one kernel; the old index map)."""
+    q, k, v = _qkv(2, 192, 2, 32, seed=11)
+    assert (
+        flash_attention_forward(q, k, v, 64, 64)
+        == flash_attention(q, k, v, 64, 64)
+    ).all()
+    with pytest.raises(ValueError, match="no multiple"):
+        flash_attention_forward(q, jnp.repeat(k[:, :, :1], 3, axis=2), v)
+
+
+@pytest.mark.parametrize("s,d,takes", [
+    (4096, 128, True), (1024, 128, True), (2048, 256, True),
+    (4096, 64, False), (4096, 192, False), (8, 128, False), (512, 128, False),
+])
+def test_prefill_takes_kernel(s, d, takes):
+    """Whole lane tiles and a length from the measured threshold up."""
+    assert prefill_takes_kernel(s, d) is takes
 
 
 def test_gradients_match_dense():
