@@ -638,6 +638,14 @@ class ServeEngine:
         self._chunk = int(prefill_chunk)
         self._inflight: collections.deque[_InFlight] = collections.deque()
         self._pending: dict[int, _PendingPrefill] = {}
+        # the rows of a ring of K and V (a model whose window layers keep
+        # one: models/sambay.py), as its leaf in the cache lies (..., ring,
+        # D); 0: no ring. What _chain_rows counts a ring's reads by
+        self._ring = next((
+            leaf.shape[-2] for path, leaf in
+            jax.tree_util.tree_leaves_with_path(self._state["cache"])
+            if _leaf_name(path) == "window_key"
+        ), 0)
         self.n_chunks = 0
         if self._retain or self._chunk or role == "decode" or self._slo:
             # shape/dtype proto of the batch-1 decode cache — seed_cache
@@ -2068,7 +2076,8 @@ class ServeEngine:
             # chain — so the fetch below is the only place the host
             # waits.
             with annotate(
-                "chain_dispatch", chain=chain_id, occupancy=occupancy
+                "chain_dispatch", chain=chain_id, occupancy=occupancy,
+                **self._chain_rows(),
             ):
                 self._state, out = self._chain(*args)
             self.n_chains += 1
@@ -2086,6 +2095,49 @@ class ServeEngine:
         while len(self._inflight) > target:
             done.extend(self._collect_chain())
         return done
+
+    def _chain_rows(self) -> dict:
+        """The rows of K and V that one decode attention call a step of
+        the chain about to be dispatched reads, summed over its
+        ``tokens_per_launch`` (T) steps and the live slots: the fields of
+        ``prog:chain_dispatch`` that ``decode_attention_roofline.*``
+        charges the traced calls by (a call's shapes hold neither the
+        depths nor which slots are live).
+
+        A slot of depth ``d`` (the positions its cache holds) and budget
+        ``r`` lives ``n = min(T, r)`` steps; step ``j`` writes position
+        ``d + j`` and attends ``d + j + 1`` rows: ``kv_rows`` sums
+        ``n (d + 1) + n (n - 1) / 2`` (a full-length cache; a latent
+        cache's rows alike), ``ring_rows`` ``min(d + j + 1, R)`` where
+        the cache holds a ring of ``R`` rows. Host state alone, no fetch:
+        the ``_Active``s, advanced by the chains still in flight, which
+        the device runs past any EOS the host has not fetched yet. Paged
+        and speculative chains read through other kernels by other
+        counts: no field."""
+        if self._paged or self._spec:
+            return {}
+        t, ring, inflight = self.tokens_per_launch, self._ring, self._inflight
+        kv = rows = 0
+        for s, act in enumerate(self._slots):
+            if act is None:
+                continue
+            a = len(act.request.prompt) + len(act.tokens)  # d + 1
+            n = act.remaining
+            if inflight:
+                behind = sum(fl.view[s] is act for fl in inflight)
+                ahead = min(n, t * behind)
+                a, n = a + ahead, n - ahead
+            if n > t:  # comparisons, not min(): this runs every dispatch
+                n = t
+            if n <= 0:
+                continue
+            kv += n * (2 * a + n - 1) // 2
+            if ring:
+                u = ring - a + 1 if a <= ring else 0  # steps inside the ring
+                if u > n:
+                    u = n
+                rows += u * (2 * a + u - 1) // 2 + (n - u) * ring
+        return {"kv_rows": kv, "ring_rows": rows} if ring else {"kv_rows": kv}
 
     def _sentry_fetch(self, x):
         """The budgeted host fetch: every budgeted call site

@@ -381,3 +381,27 @@ def test_parallel_block_keeps_its_paths(parallel_engine, which, path):
     (that no stack is copied is ``tests/test_chip_compile.py``'s)."""
     text = _lowered(which, parallel_engine, None).as_text(debug_info=True)
     assert path in text, path
+
+
+def test_decode_kernel_is_under_its_cache_kind_in_the_chain():
+    """A model whose window layers keep a ring (``models/sambay.py``) at
+    widths the kernel takes (a KV pair of 2 x 64): the ring's calls under
+    ``window_attn/decode_attn``, the shared cache's under
+    ``shared_kv_attn/decode_attn``. ``decode_attention_roofline.serve``
+    charges a call under ``window_attn`` the chain's ``ring_rows`` and every
+    other its ``kv_rows`` (``benchmark/lib/decode_roofline.py``)."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=256, n_layers=8, n_heads=4, n_kv_heads=2,
+        d_ff=128, max_seq_len=256, mb_per_layer=2, sliding_window=128,
+        tie_embeddings=True, scan_layers=True,
+    )
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    engine = ServeEngine(model, params, n_slots=2, tokens_per_launch=2)
+    assert engine._ring == 128
+    text = _lowered("chain", engine, None).as_text(debug_info=True)
+    assert "module @jit__chain_fn " in text
+    assert "layers_a/layers/window_block/attn/window_attn/decode_attn/" in text
+    assert "layers_b/layers/cross_block/attn/shared_kv_attn/decode_attn/" in text
+    assert re.search(r'block_\d+/attn/shared_kv_attn/decode_attn/', text)

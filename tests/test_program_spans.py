@@ -11,7 +11,11 @@ under ``jax.profiler.start_trace``, the ``.xplane.pb`` is read back with
 - ``refill`` / ``prefill_fetch`` / ``complete`` of one request share its
   ``rid``; a chain has one ``chain_dispatch`` and one ``chain_fetch`` with
   equal ``chain``,
-- an engine with no profiler running serves byte-identical tokens.
+- an engine with no profiler running serves byte-identical tokens,
+- a plain chain's ``kv_rows`` (and ``ring_rows`` where the cache holds a
+  ring) equal the rows its decode attention really attended, recorded by
+  a test-only ``jax.debug.callback`` on the depths the attention was
+  handed (ISSUE 38); paged and speculative chains carry neither.
 """
 
 import glob
@@ -19,12 +23,16 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 import pytest
 
 from pytorch_distributed_training_tutorials_tpu import create_mesh
 from pytorch_distributed_training_tutorials_tpu.data import ShardedLoader
-from pytorch_distributed_training_tutorials_tpu.models import MLP
+from pytorch_distributed_training_tutorials_tpu.models import MLP, sambay
+from pytorch_distributed_training_tutorials_tpu.models import (
+    transformer as transformer_mod,
+)
 from pytorch_distributed_training_tutorials_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
@@ -33,6 +41,9 @@ from pytorch_distributed_training_tutorials_tpu.obs.flight import EVENT_KINDS
 from pytorch_distributed_training_tutorials_tpu.serve import (
     Request,
     ServeEngine,
+)
+from pytorch_distributed_training_tutorials_tpu.serve import (
+    engine as engine_mod,
 )
 from pytorch_distributed_training_tutorials_tpu.serve.slots import bucket_len
 from pytorch_distributed_training_tutorials_tpu.train import Trainer
@@ -55,7 +66,8 @@ ENGINE_SPANS = {
     # ``bucket`` sits where a prefill picked it: the padded length of the
     # launch whose first token this fetch waits for
     "prefill_fetch": {"rid", "bucket"},
-    "chain_dispatch": {"chain", "occupancy"},
+    # ``kv_rows``: the rows a chain's decode attention reads (ISSUE 38)
+    "chain_dispatch": {"chain", "occupancy", "kv_rows"},
     "chain_fetch": {"chain"},
     "distribute": {"chain", "tokens"},
     "complete": {"rid", "tokens"},
@@ -236,3 +248,183 @@ def test_trainer_spans_follow_the_loop(trainer_trace):
     assert order[-2:] == ["loader_next", "epoch_sync"]
     ends = [s[2] for s in spans]
     assert all(a <= b for a, b in zip(ends, [s[1] for s in spans][1:]))
+
+
+# -- kv_rows and ring_rows against the rows the attention saw (ISSUE 38) ----
+
+T = 4  # tokens_per_launch of every engine below
+
+
+@pytest.fixture
+def dispatched(monkeypatch):
+    """The fields of every ``prog:chain_dispatch`` the engine opens."""
+    out = []
+    real = engine_mod.annotate
+
+    def spy(name, **fields):
+        if name == "chain_dispatch":
+            out.append(fields)
+        return real(name, **fields)
+
+    monkeypatch.setattr(engine_mod, "annotate", spy)
+    return out
+
+
+@pytest.fixture
+def attended(monkeypatch):
+    """The depths the plain path's attention was handed, a (slots,) array
+    a step of a layer, in the order the device ran them: by cache kind,
+    ``kv`` (``Attention``'s cache: layer 0's step alone), ``window_attn``
+    (a ring) and ``shared_kv_attn`` (``models/sambay.py``: every layer's
+    call, with the rows its stack holds). Test-only ``jax.debug.callback``
+    spies on engines built after the patch."""
+    seen = {"kv": [], "window_attn": [], "shared_kv_attn": []}
+    store, cached = transformer_mod._store_decode_kv, sambay._cached_attention
+
+    def on_store(var, val, pos, layer=None, heads_major=False):
+        if var.name == "cached_key" and val.shape[1] == 1 and pos.ndim == 1:
+            def note(p, first):
+                if first:
+                    seen["kv"].append((np.array(p), CFG.max_seq_len))
+            jax.debug.callback(note, pos, jnp.asarray(
+                True if layer is None else layer == 0))
+        return store(var, val, pos, layer, heads_major)
+
+    def on_cached(q_pad, k_stack, v_stack, layer, depth, scope):
+        rows = k_stack.shape[3]
+        jax.debug.callback(
+            lambda d: seen[scope].append((np.array(d), rows)), depth)
+        return cached(q_pad, k_stack, v_stack, layer, depth, scope)
+
+    monkeypatch.setattr(transformer_mod, "_store_decode_kv", on_store)
+    monkeypatch.setattr(sambay, "_cached_attention", on_cached)
+    return seen
+
+
+def _attended_by_chain(records, engine):
+    """Rows attended a call of each step, summed over a chain's steps and
+    slots: the device runs the chains one after the other, each ``T``
+    steps of the same calls (a prefill's calls are of one row: left out);
+    a depth at or past the rows is a slot that holds nothing, which the
+    kernel reads no row of."""
+    n_chains = engine.n_chains
+    records = [r for r in records if len(r[0]) == engine.n_slots]
+    calls = len(records) // (n_chains * T)
+    assert calls and calls * n_chains * T == len(records)
+    out = []
+    for c in range(n_chains):
+        mine = records[c * T * calls:(c + 1) * T * calls]
+        rows = sum(int(d) + 1 for depth, w in mine for d in depth if d < w)
+        assert rows % calls == 0  # every call of a step saw the same depths
+        out.append(rows // calls)
+    return out
+
+
+def _drive(engine, reqs, stagger=False):
+    """``reqs`` are (prompt length, max_new, eos_token): all submitted at
+    once, or one before each step; completions by request."""
+    pending, out = list(reqs), {}
+    while pending or not engine.idle:
+        for p, n, eos in pending[:1] if stagger else pending:
+            engine.submit(Request(prompt=_prompt(200 + p, p),
+                                  max_new_tokens=n, eos_token=eos))
+        pending = pending[1:] if stagger else []
+        for c in engine.step():
+            out[c.request_id] = c
+    return out
+
+
+def _eos_mid_request(model, params):
+    """A token request 0 samples for the first time at its third token or
+    later: as its ``eos_token`` it parks the slot with budget left."""
+    first = _drive(ServeEngine(model, params, n_slots=2, tokens_per_launch=T),
+                   [(6, 12, None), (4, 10, None)])
+    toks = first[0].tokens
+    return next(t for k, t in enumerate(toks) if k >= 2 and t not in toks[:k])
+
+
+KV_CASES = {
+    # five depths through three slots: a budget that ends mid-chain (10, 6),
+    # one that ends at the first step (5, 2), refills as slots free
+    "depths_and_budgets": (
+        dict(n_slots=3), [(3, 9), (10, 6), (5, 2), (12, 7), (2, 11)], False),
+    # request 0 stops at an EOS with budget left; its slot is parked
+    "eos_parks_with_budget": (dict(n_slots=2), [(6, 12), (4, 10)], False),
+    # a request arrives before every step: refilled between two chains
+    "refill_between_chains": (
+        dict(n_slots=2), [(3, 6), (9, 5), (4, 9), (7, 3)], True),
+    # a chain dispatched before the last one is fetched: the depths the
+    # device reached in flight
+    "pipelined": (
+        dict(n_slots=2, pipeline_depth=2), [(3, 9), (8, 6), (5, 7)], False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KV_CASES))
+def test_kv_rows_are_the_rows_the_attention_attended(
+        case, model_params, dispatched, attended):
+    model, params = model_params
+    kw, reqs, stagger = KV_CASES[case]
+    eos = _eos_mid_request(model, params) if case.startswith("eos") else None
+    reqs = [(p, n, eos if i == 0 else None) for i, (p, n) in enumerate(reqs)]
+    attended["kv"].clear()
+    dispatched.clear()
+    engine = ServeEngine(model, params, tokens_per_launch=T, **kw)
+    done = _drive(engine, reqs, stagger)
+    assert len(done) == len(reqs)
+    assert [f["chain"] for f in dispatched] == list(range(engine.n_chains))
+    assert all(set(f) == {"chain", "occupancy", "kv_rows"} for f in dispatched)
+    want = _attended_by_chain(attended["kv"], engine)
+    assert [f["kv_rows"] for f in dispatched] == want
+    assert want[0] > 0
+    if eos is not None:
+        assert done[0].finish_reason == "eos"
+        assert len(done[0].tokens) < reqs[0][1]
+
+
+RING_CFG = TransformerConfig(
+    vocab_size=64, d_model=128, n_layers=8, n_heads=4, n_kv_heads=2,
+    d_ff=128, max_seq_len=32, mb_per_layer=2, sliding_window=8,
+    tie_embeddings=True, scan_layers=True,
+)
+
+
+@pytest.mark.parametrize("reqs", [
+    [(3, 9), (5, 8)],  # prompts inside the ring of 8, answers past it
+    [(12, 6), (20, 9)],  # prompts longer than the ring
+    [(3, 9), (12, 6), (7, 2)],  # both, and a refill
+], ids=["shorter", "longer", "both"])
+def test_ring_rows_are_the_rows_the_rings_attended(reqs, dispatched, attended):
+    """``models/sambay.py``: ``kv_rows`` counts the shared cache's reads
+    (layer ``half + 1`` and the cross layers), ``ring_rows`` a window
+    layer's; the ring's length is its leaf's, 8 rows."""
+    model = TransformerLM(RING_CFG)
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    engine = ServeEngine(model, params, n_slots=2, tokens_per_launch=T)
+    assert engine._ring == RING_CFG.sliding_window
+    done = _drive(engine, [(p, n, None) for p, n in reqs])
+    assert len(done) == len(reqs)
+    assert all(set(f) == {"chain", "occupancy", "kv_rows", "ring_rows"}
+               for f in dispatched)
+    assert [f["kv_rows"] for f in dispatched] == _attended_by_chain(
+        attended["shared_kv_attn"], engine)
+    ring = _attended_by_chain(attended["window_attn"], engine)
+    assert [f["ring_rows"] for f in dispatched] == ring
+    # a ring caps what a slot reads: fewer rows than the shared cache's
+    # once a sequence outgrows it
+    assert sum(ring) < sum(f["kv_rows"] for f in dispatched)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(paged=True, page_size=8, pool_pages=32),
+    dict(speculative_k=2),
+], ids=["paged", "speculative"])
+def test_other_chains_carry_no_rows(kw, model_params, dispatched):
+    """Paged and speculative chains read through other kernels by other
+    counts: their spans keep the fields they had."""
+    model, params = model_params
+    engine = ServeEngine(model, params, n_slots=2, tokens_per_launch=T, **kw)
+    assert len(_drive(engine, [(5, 9, None), (9, 6, None)])) == 2
+    assert dispatched
+    assert all(set(f) == {"chain", "occupancy"} for f in dispatched)
