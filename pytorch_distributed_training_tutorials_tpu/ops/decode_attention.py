@@ -8,17 +8,22 @@ and then reads the copy twice, over the whole window whatever the slots'
 depths: at 32 slots x 2,048 positions some 0.8 GB of traffic a layer where
 the live slots' rows are a twentieth of that.
 
-This kernel walks ``(slot, block of positions)`` with the layer index and
-the slots' depths as **scalar-prefetch** operands, as
-:mod:`.latent_attention` does for a latent cache: the K and V block index
-maps point into the stacks at ``[layer, slot, block]`` (no slice is
-copied), a block past a slot's depth is neither fetched nor computed, and
-the softmax runs as the streaming ``(m, l, acc)`` recurrence in float32.
-**A slot that holds no live sequence costs no rows**: the depth ``W`` (one
-past the last position, where a slot's writes already drop) means
-"nothing here", the slot's index map repeats a block the pipeline already
-holds and its result is zeros (``ServeEngine``'s chain pins the depth of a
-slot without budget there: ``park_cache_index``).
+This kernel's work is the live slots' live blocks and nothing else. The
+layer index and the slots' depths are **scalar-prefetch** operands; K and
+V stay in HBM where the stacks lie (no slice is copied), and the grid walks
+the slots in order, a step a slot, each over its blocks ``0 .. pos // rows``
+alone by its own DMAs into two buffers of fast memory (:func:`block_walk`
+is the schedule). While a block is computed the next one is already in
+flight: the slot's next block, or after its last the first block of the
+next live slot, so a slot boundary exposes no fetch. The softmax runs as
+the streaming ``(m, l, acc)`` recurrence in float32. **A slot that holds no
+live sequence costs no rows**: the depth ``W`` (one past the last position,
+where a slot's writes already drop) means "nothing here", the slot's step
+walks no block and moves no byte, and its result is zeros
+(``ServeEngine``'s chain pins the depth of a slot without budget there:
+``park_cache_index``). A grid step for every block of every slot, whatever
+the depths, cost 22 of a chat call's 26 us and 0.25 ms of a shared-cache
+call in the Phi cell, on a v5e (``PERF.md`` section 6).
 
 A block is taken as stored, ``(rows, KV, D)``: its ``(KV, D)`` slabs are the
 tiles the chip keeps (``(8, 128)`` in bfloat16), so reshaping or transposing
@@ -47,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float("-inf")  # plain float: no jax arrays at import time
 
 _BLOCK_BYTES = 1 << 20  # K and V, two buffers each: 4 MiB of VMEM
+_SCOPED_VMEM = 16 << 20  # what the compiler grants a kernel unasked (v5e)
 
 
 def decode_block(w: int, kv: int, d: int, dtype) -> int | None:
@@ -68,6 +74,7 @@ def decode_block(w: int, kv: int, d: int, dtype) -> int | None:
     return None
 
 
+# the index maps of latent_decode_attention's grid of (slot, block)
 def block_bounds(pos: jax.Array, w: int, block_w: int):
     """Per slot, the slot whose blocks it fetches and the last block index
     it may ask for: a live slot its own blocks up to ``pos // block_w``; a
@@ -83,6 +90,22 @@ def block_bounds(pos: jax.Array, w: int, block_w: int):
     src = jnp.where(prev >= 0, prev, jnp.where(nxt < b, nxt, 0))
     hi = jnp.where(prev >= 0, last[jnp.maximum(prev, 0)], 0)
     return src, hi
+
+
+def block_walk(pos: jax.Array, w: int, block_w: int):
+    """The walk over live blocks, per slot: how many blocks it reads
+    (``pos // block_w + 1`` where it is live, none where it is parked,
+    ``pos >= w``), how many the slots before it read (so which of the two
+    buffers its first block lands in), and the next live slot after it
+    (``b`` where none is), whose first block is fetched while the slot's
+    last is computed."""
+    b = pos.shape[0]
+    live = pos < w
+    count = jnp.where(live, pos // block_w + 1, 0)
+    idx = jnp.arange(b, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(live, idx, b), reverse=True)
+    after = jnp.concatenate([nxt[1:], jnp.full((1,), b, jnp.int32)])
+    return count, jnp.cumsum(count) - count, after
 
 
 def decode_attention(
@@ -131,35 +154,57 @@ def decode_attention(
     if block_w is None:
         block_w = decode_block(w, kv, d, k_stack.dtype)
     assert block_w and w % block_w == 0 and d % 128 == 0, (w, block_w, d)
-    n_blocks = w // block_w
     # a block as a matrix: row t * kv + c is KV head c at t (c * block_w + t
     # where the heads come first)
     n = block_w * kv
+    block = (kv, block_w, d) if heads_major else (block_w, kv, d)
     # the CPU backend has no bf16 x bf16 -> f32 product of this form: the
     # interpreter computes in float32 what the chip computes from bfloat16
     compute = jnp.float32 if interpret else k_stack.dtype
     sm_scale = d ** -0.5
     pos = pos.astype(jnp.int32)
-    src, hi = block_bounds(pos, w, block_w)
+    count, first, after = block_walk(pos, w, block_w)
 
-    def kernel(layer_ref, pos_ref, src_ref, hi_ref,
-               q_ref, k_ref, v_ref, o_ref, acc, m, l):
-        del layer_ref, src_ref, hi_ref
-        bb, j = pl.program_id(0), pl.program_id(1)
+    def kernel(layer_ref, pos_ref, count_ref, first_ref, after_ref,
+               q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc, m, l):
+        bb = pl.program_id(0)
         depth = pos_ref[bb]
 
-        @pl.when(j == 0)
-        def _init():
-            acc[:] = jnp.zeros_like(acc)
-            m[:] = jnp.full_like(m, NEG_INF)
-            l[:] = jnp.zeros_like(l)
+        def fetch(slot, blk, buf):
+            rows = pl.ds(pl.multiple_of(blk * block_w, block_w), block_w)
+            at = (
+                (layer_ref[0], slot, slice(None), rows) if heads_major
+                else (layer_ref[0], slot, rows)
+            )
+            return [
+                pltpu.make_async_copy(hbm.at[at], dst.at[buf], sem.at[i, buf])
+                for i, (hbm, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))
+            ]
 
-        @pl.when(jnp.logical_and(depth < w, j * block_w <= depth))
-        def _block():
-            k2 = k_ref[0, 0].reshape(n, d).astype(compute)
-            v2 = v_ref[0, 0].reshape(n, d).astype(compute)
+        # the first live slot's first block; every later block is started
+        # while the one before it is computed, across slots too
+        first_live = jnp.where(count_ref[0] > 0, 0, after_ref[0])
+
+        @pl.when(jnp.logical_and(bb == 0, first_live < b))
+        def _start():
+            for cp in fetch(first_live, 0, 0):
+                cp.start()
+
+        def step(j, carry):
+            buf = jax.lax.rem(first_ref[bb] + j, 2)
+            more = j + 1 < count_ref[bb]
+            slot = jnp.where(more, bb, after_ref[bb])
+
+            @pl.when(slot < b)
+            def _next():
+                for cp in fetch(slot, jnp.where(more, j + 1, 0), 1 - buf):
+                    cp.start()
+
+            k_cp, v_cp = fetch(bb, j, buf)
+            k_cp.wait()
+            k2 = k_buf[buf].reshape(n, d).astype(compute)
             scores = jax.lax.dot_general(
-                q_ref[0].astype(compute), k2, (((1,), (1,)), ((), ())),
+                q_ref[bb].astype(compute), k2, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * sm_scale  # (H, n)
             col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
@@ -171,44 +216,44 @@ def decode_attention(
             own = jnp.logical_and(c == head // grp, j * block_w + t <= depth)
             scores = jnp.where(own, scores, NEG_INF)
             m_prev = m[:, :1]
-            # a block that runs holds t = j * block_w <= depth for every head
+            # every block walked holds t = j * block_w <= depth for every head
             m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
             pexp = jnp.exp(scores - m_new)
             corr = jnp.exp(m_prev - m_new)
             l[:, :1] = l[:, :1] * corr + pexp.sum(axis=-1, keepdims=True)
+            v_cp.wait()
+            v2 = v_buf[buf].reshape(n, d).astype(compute)
             acc[:] = acc[:] * corr + jax.lax.dot(
                 pexp.astype(compute), v2, preferred_element_type=jnp.float32
             )
             m[:, :1] = m_new
+            return carry
 
-        @pl.when(j == n_blocks - 1)
-        def _flush():
-            lv = l[:, :1]
-            o_ref[0] = (
-                acc[:] / jnp.where(lv == 0.0, 1.0, lv)  # a dead slot: zeros
-            ).astype(o_ref.dtype)
+        acc[:] = jnp.zeros_like(acc)
+        m[:] = jnp.full_like(m, NEG_INF)
+        l[:] = jnp.zeros_like(l)
+        jax.lax.fori_loop(0, count_ref[bb], step, 0)  # a parked slot: none
+        lv = l[:, :1]
+        o_ref[bb] = (
+            acc[:] / jnp.where(lv == 0.0, 1.0, lv)  # a dead slot: zeros
+        ).astype(o_ref.dtype)
 
-    def rows_map(bb, j, layer_ref, pos_ref, src_ref, hi_ref):
-        # a block past the depth is the last one needed again, and a dead
-        # slot asks for that one alone: the pipeline fetches nothing for an
-        # index it already holds
-        last = hi_ref[bb]
-        blk = jnp.where(pos_ref[bb] < w, jnp.minimum(j, last), last)
-        if heads_major:
-            return (layer_ref[0], src_ref[bb], 0, blk, 0)
-        return (layer_ref[0], src_ref[bb], blk, 0, 0)
-
-    q_spec = pl.BlockSpec((1, h, d), lambda bb, j, *_: (bb, 0, 0))
-    rows_spec = pl.BlockSpec(
-        (1, 1, kv, block_w, d) if heads_major else (1, 1, block_w, kv, d),
-        rows_map,
-    )
+    # q and the result lie in VMEM whole: a parked slot's grid step moves
+    # no bytes. Past the compiler's default (a few hundred slots of many
+    # heads) the call asks for the fast memory that takes.
+    vmem = (2 * q.size * q.dtype.itemsize + 4 * n * d * k_stack.dtype.itemsize
+            + (h * d + 2 * h * 128) * 4 + (1 << 20))
+    q_spec = pl.BlockSpec((b, h, d), lambda bb, *_: (0, 0, 0))
+    hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, n_blocks),
-        in_specs=[q_spec, rows_spec, rows_spec],
+        num_scalar_prefetch=5,
+        grid=(b,),
+        in_specs=[q_spec, hbm_spec, hbm_spec],
         out_specs=q_spec,
         scratch_shapes=[
+            pltpu.VMEM((2, *block), k_stack.dtype),
+            pltpu.VMEM((2, *block), v_stack.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((h, d), jnp.float32),
             pltpu.VMEM((h, 128), jnp.float32),
             pltpu.VMEM((h, 128), jnp.float32),
@@ -218,9 +263,14 @@ def decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # a slot's last block starts the next live slot's first: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem if vmem > _SCOPED_VMEM else None,
+        ),
         name="decode_attention",
         interpret=interpret,
     )(
-        jnp.asarray(layer, jnp.int32).reshape(1), pos, src, hi,
+        jnp.asarray(layer, jnp.int32).reshape(1), pos, count, first, after,
         q, k_stack, v_stack,
     )

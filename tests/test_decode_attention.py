@@ -25,6 +25,7 @@ from pytorch_distributed_training_tutorials_tpu.models.transformer import (
 )
 from pytorch_distributed_training_tutorials_tpu.ops.decode_attention import (
     block_bounds,
+    block_walk,
     decode_attention,
     decode_block,
 )
@@ -90,6 +91,76 @@ def test_heads_major_stack_gives_the_same_result(h, kv):
     # the same scores and weights; a block's rows are summed in another order
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
     assert not np.asarray(got[2]).any()  # the dead slot
+
+
+P = W  # a parked slot's depth
+
+
+@pytest.mark.parametrize(
+    "pos, block_w, heads_major, layer",
+    [
+        # at and one row either side of every boundary of blocks of 128
+        ([127, 128, 129, 255, 256, 257, 383, 384, 385, 511], 128, False, 0),
+        ([P, P, P, P], BLOCK, False, 0),
+        ([P, P, P, 300], BLOCK, False, 0),
+        # runs of parked slots between the live ones: each live slot's first
+        # block is the one fetched while the live slot before it ends
+        ([P, 5, P, P, P, 300, P, P, 129, P, P], 128, False, 0),
+        # a ring of 512 rows at blocks of 256, a KV head's rows together
+        ([P, 511, 0, P, 256, 255, P], BLOCK, True, 0),
+        ([40, P, 400, 200], 128, False, 2),
+    ],
+    ids=["boundaries", "all_parked", "last_live", "parked_runs",
+         "heads_major_ring", "layer2"],
+)
+def test_kernel_walks_only_the_live_blocks(pos, block_w, heads_major, layer):
+    """The kernel walks each live slot's blocks up to its depth and no
+    block of a parked one, fetching the next live block while it computes
+    the one before: the plain path's result for every live slot, zeros for
+    every parked one, and the blocks it must not read (past the one that
+    holds a depth, every block of a parked slot) may hold NaN."""
+    pos = jnp.array(pos, jnp.int32)
+    b, h, kv = pos.shape[0], 8, 4
+    keys = jax.random.split(jax.random.PRNGKey(b), 3)
+    q = jax.random.normal(keys[0], (b, h, D), jnp.float32)
+    k = jax.random.normal(keys[1], (3, b, W, kv, D), jnp.float32)
+    v = jax.random.normal(keys[2], (3, b, W, kv, D), jnp.float32)
+    valid = jnp.arange(W)[None, :] <= pos[:, None]  # (B, W)
+    ref = grouped_masked_attention(
+        q[:, None], k[layer], v[layer], valid[:, None, None]
+    )[:, 0]
+    # a walked block is read whole (its rows past the depth are masked),
+    # the blocks after it and a parked slot's are never read
+    blk = jnp.arange(W)[None, :] // block_w
+    unread = (blk > pos[:, None] // block_w) | (pos[:, None] >= W)
+    k, v = (jnp.where(unread[None, :, :, None, None], jnp.nan, x)
+            for x in (k, v))
+    if heads_major:
+        k, v = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
+    out = decode_attention(
+        q, k, v, jnp.int32(layer), pos, block_w=block_w,
+        heads_major=heads_major,
+    )
+    live = np.asarray(pos < W)
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-6, rtol=0)
+    assert not np.asarray(out[~live]).any()
+
+
+def test_block_walk_names_each_live_slots_blocks_and_none_parked():
+    """``pos // block_w + 1`` blocks a live slot, none a parked one; the
+    blocks walked before each slot (so the buffer its first block lands
+    in) and the live slot after it, whose first block follows its last."""
+    pos = jnp.array([P, 0, 255, 256, P, P, 511, P], jnp.int32)
+    count, first, after = (
+        np.asarray(x).tolist() for x in block_walk(pos, W, BLOCK)
+    )
+    assert count == [0, 1, 1, 2, 0, 0, 2, 0]
+    assert first == [0, 0, 1, 2, 4, 4, 4, 6]
+    assert after == [1, 2, 3, 6, 6, 6, 8, 8]
+    count, first, after = (
+        np.asarray(x).tolist() for x in block_walk(jnp.full((3,), P), W, 128)
+    )
+    assert count == first == [0, 0, 0] and after == [3, 3, 3]
 
 
 def test_dead_slots_ask_for_blocks_already_held():
